@@ -18,12 +18,6 @@ except ImportError:  # bare checkout fallback
     sys.path.insert(0, __file__.rsplit("/examples/", 1)[0])
 sys.path.insert(0, __file__.rsplit("/nn_worker.py", 1)[0])
 
-if os.environ.get("PERSIA_FORCE_JAX_PLATFORM"):
-    import jax
-
-    jax.config.update("jax_platforms",
-                      os.environ["PERSIA_FORCE_JAX_PLATFORM"])
-
 import optax
 
 from persia_tpu.config import EmbeddingSchema, uniform_slots

@@ -37,7 +37,7 @@ from persia_tpu.embedding.optim import Adagrad
 from persia_tpu.logger import get_default_logger
 from persia_tpu.models import DNN
 from persia_tpu.ps.native import make_holder
-from persia_tpu.utils import roc_auc, setup_seed
+from persia_tpu.utils import enable_compile_cache, roc_auc, setup_seed
 from persia_tpu.worker.worker import EmbeddingWorker
 
 from data_generator import NUM_SLOTS, batches
@@ -146,6 +146,7 @@ if __name__ == "__main__":
     p.add_argument("--test-npz", default=None)
     p.add_argument("--epochs", type=int, default=5)
     args = p.parse_args()
+    enable_compile_cache()
     if args.train_npz:
         auc = main_npz(args.train_npz, args.test_npz or args.train_npz,
                        args.batch_size or 128, args.epochs)

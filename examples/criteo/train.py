@@ -42,7 +42,7 @@ from persia_tpu.logger import get_default_logger
 from persia_tpu.models import DCNv2, DeepFM, DLRM
 from persia_tpu.workloads.models import ZooDLRM
 from persia_tpu.ps.native import make_holder
-from persia_tpu.utils import roc_auc, setup_seed
+from persia_tpu.utils import enable_compile_cache, roc_auc, setup_seed
 from persia_tpu.worker.worker import EmbeddingWorker
 
 from criteo_data import (  # unique module name: examples share sys.path
@@ -225,5 +225,6 @@ if __name__ == "__main__":
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--log-every", type=int, default=50)
     args = p.parse_args()
+    enable_compile_cache()
     auc = main(args)
     print(f"AUC: {auc}")

@@ -49,7 +49,7 @@ from persia_tpu.embedding.optim import Adagrad
 from persia_tpu.logger import get_default_logger
 from persia_tpu.models import SequenceTower
 from persia_tpu.ps.native import make_holder
-from persia_tpu.utils import roc_auc, setup_seed
+from persia_tpu.utils import enable_compile_cache, roc_auc, setup_seed
 from persia_tpu.worker.worker import EmbeddingWorker
 from persia_tpu.workloads.generator import (
     SEQ_CLICKS_SLOT,
@@ -138,6 +138,7 @@ def main():
                    default="ring")
     p.add_argument("--attn-impl", choices=["xla", "pallas"], default="xla")
     args = p.parse_args()
+    enable_compile_cache()
 
     mesh = None
     if args.mesh:

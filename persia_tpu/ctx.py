@@ -234,6 +234,16 @@ class TrainCtx(EmbeddingCtx):
         # admission: None -> the PERSIA_TIER_ADMIT knob; "hotness"
         # selects the frequency-gated tier-ladder mapper
         self.device_cache_capacity = int(device_cache_capacity)
+        if self.device_cache_capacity and not (
+                hasattr(worker, "lookup_rows_with_state")
+                and hasattr(worker, "set_rows")):
+            raise TypeError(
+                f"device_cache_capacity={self.device_cache_capacity} needs "
+                "a worker with lookup_rows_with_state/set_rows (the cache "
+                "engine moves rows WITH their optimizer state); "
+                f"{type(worker).__name__} has neither RPC. Host the worker "
+                "in the trainer's process: EmbeddingWorker(schema, "
+                "[PsClient(a) for a in ps_addrs])")
         self.device_cache_admission = device_cache_admission
         self._cache_engine = None
         self._cached_step = None

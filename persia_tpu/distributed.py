@@ -60,30 +60,10 @@ class DistributedOption:
 
         # jax.process_count() would itself initialize the backend, which
         # jax.distributed.initialize refuses to run after — probe the
-        # distributed client state instead. jax < 0.5 has no
-        # jax.distributed.is_initialized; fall back to the internal
-        # client handle it would read.
-        is_init = getattr(jax.distributed, "is_initialized", None)
-        if is_init is None:
-            from jax._src import distributed as _jax_dist
-
-            def is_init():
-                return getattr(_jax_dist.global_state, "client",
-                               None) is not None
-        if self.multihost and not is_init():
-            # CPU pods (the cluster-in-a-box dev/CI recipe) need a
-            # cross-process collectives backend: jaxlib ships Gloo but
-            # jax 0.4.x leaves the CPU backend collective-less by
-            # default ("Multiprocess computations aren't implemented on
-            # the CPU backend"). Turn it on before the backend exists.
-            plats = str(getattr(jax.config, "jax_platforms", None)
-                        or os.environ.get("JAX_PLATFORMS", ""))
-            if "cpu" in plats.split(","):
-                try:
-                    jax.config.update(
-                        "jax_cpu_collectives_implementation", "gloo")
-                except (AttributeError, ValueError):
-                    pass  # newer jax: gloo is already the default
+        # distributed client state instead. (CPU pods, the
+        # cluster-in-a-box dev/CI recipe, get their cross-process
+        # collectives from Gloo, jax's default CPU implementation.)
+        if self.multihost and not jax.distributed.is_initialized():
             kwargs = {}
             if self.coordinator_address:
                 kwargs["coordinator_address"] = self.coordinator_address

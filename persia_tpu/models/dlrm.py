@@ -1,7 +1,7 @@
 """DLRM dense tower: bottom MLP + pairwise dot interactions + top MLP.
 
 The canonical benchmark model for this framework's north-star metric
-(BASELINE.md: Criteo DLRM samples/sec/chip). Interaction is the standard
+(Criteo DLRM samples/sec/chip; PERF.md). Interaction is the standard
 lower-triangle pairwise dot of field embeddings + the bottom-MLP output,
 computed as one batched matmul so it lands on the MXU.
 """

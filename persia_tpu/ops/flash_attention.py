@@ -15,9 +15,13 @@ persists across grid steps — see /opt/skills/guides/pallas_guide.md).
 
 Kernel shape rules: dh is the lane axis of every block (any dh ≤ 128
 works, full-axis blocks are padded internally; dh=128 is the sweet
-spot). T is padded to the k/q block size by the wrapper; padded KEY
-positions are masked via the static true-length, padded QUERY rows
-compute garbage that the wrapper slices off.
+spot). Block sizes are multiples of the 128-lane width (``_clamp_block``)
+so every tile — bf16 (16, 128) included — and every per-row lane vector
+(lse, delta, kv_mask ride as ``(.., 1, T)`` rows blocked ``(1, block)``)
+meets the TPU lowering's (8, 128) block rule. T is padded to the k/q
+block size by the wrapper; padded KEY positions are masked via the
+static true-length, padded QUERY rows compute garbage that the wrapper
+slices off.
 
 Backward: Pallas too (jax.custom_vjp). The forward saves (q, k, v,
 out, lse); `flash_attention_bwd_pallas` recomputes each softmax block
@@ -25,9 +29,8 @@ in VMEM from those residuals with the same schedule run twice — dq
 accumulates across the k-grid, dk/dv across the q-grid. delta
 (rowsum(dO·O)) is a cheap XLA reduce. Memory stays O(T) end to end.
 
-Measured on TPU v5e (B=4 H=8 T=8192 dh=128 bf16 causal): see
-BASELINE.md round-4 table — the motivation numbers above are from
-`bench.py --mode attn` on the scan implementation.
+The carry-bandwidth figures above are computed from shapes; kernel time
+and roofline share on the chip: not measured (see PERF.md).
 """
 
 import functools
@@ -38,6 +41,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30  # large-finite: -inf NaNs the m-update on all-masked rows
+_LANES = 128
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
@@ -108,18 +112,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
         o_ref[0] = (acc[...] / l).astype(o_ref.dtype)
         if with_lse:
             # logsumexp residual for the backward kernels, stored
-            # (BH, T) with T on lanes — a (T, 1) layout would be padded
-            # to 128 lanes on TPU, 128x the footprint
+            # (BH, 1, T) with T on lanes — a (T, 1) layout would be
+            # padded to 128 lanes on TPU, 128x the footprint
             lse_ref[...] = jnp.transpose(m_scr[...][:, :1] + jnp.log(l))
 
 
 def _clamp_block(block, t):
-    """Clamp a requested block size to the (padded) sequence length,
-    rounded up to a multiple of 8 so Pallas block shapes stay
-    sublane-aligned even for ragged T (e.g. t=100 → block 104, with
-    ``_pad_t`` padding T to 104). Mosaic rejects sublane-unaligned
-    blocks on real hardware even though interpret mode accepts them."""
-    return -(-min(block, max(t, 8)) // 8) * 8
+    """Clamp a requested block size to the sequence length, rounded up
+    to a multiple of the 128-lane width (e.g. t=100 → block 128, with
+    ``_pad_t`` padding T to 128; t=1000, block 512 → T padded to 1024).
+    The TPU lowering needs the lane-row blocks ``(1, block)`` divisible
+    by 128 and bf16 tiles sublane-aligned to 16; interpret mode accepts
+    anything, which is how an 8-aligned clamp once passed every CPU
+    test and still could not lower."""
+    return -(-min(block, max(t, 1)) // _LANES) * _LANES
 
 
 def _pad_t(x, block, axis=1):
@@ -132,12 +138,21 @@ def _pad_t(x, block, axis=1):
     return x
 
 
+def _mask_rows(kv_mask, block_k):
+    """(B, T_k) key-validity mask -> (B, 1, T_k padded) f32 0/1 rows."""
+    return _pad_t(kv_mask.astype(jnp.float32), block_k)[:, None, :]
+
+
 def _spec_family(block_q, block_k, dh, h, q_minor: bool):
     """The four block-spec shapes every kernel here uses, for one grid
-    order: q-tile, k-tile, per-q lane row (lse/delta), per-k lane row
-    (kv_mask, batch axis = bh // h). ``q_minor=True`` = grid (bh, qi,
-    ki); ``False`` = (bh, ki, qi). One definition so a layout change
-    cannot drift between the forward and the two backward calls."""
+    order: q-tile, k-tile, per-q lane row (lse/delta, arrays shaped
+    (BH, 1, T)), per-k lane row (kv_mask, (B, 1, T), batch axis =
+    bh // h). The row arrays carry a unit sublane axis so the block's
+    last two dims are (1 == full, block % 128 == 0) — a bare
+    ``(1, block)`` block over ``(BH, T)`` does not lower for TPU.
+    ``q_minor=True`` = grid (bh, qi, ki); ``False`` = (bh, ki, qi). One
+    definition so a layout change cannot drift between the forward and
+    the two backward calls."""
     if q_minor:
         def pos(bh, qi, ki):
             return qi, ki
@@ -147,8 +162,10 @@ def _spec_family(block_q, block_k, dh, h, q_minor: bool):
     return (
         pl.BlockSpec((1, block_q, dh), lambda *g: (g[0], pos(*g)[0], 0)),
         pl.BlockSpec((1, block_k, dh), lambda *g: (g[0], pos(*g)[1], 0)),
-        pl.BlockSpec((1, block_q), lambda *g: (g[0], pos(*g)[0])),
-        pl.BlockSpec((1, block_k), lambda *g, h=h: (g[0] // h, pos(*g)[1])),
+        pl.BlockSpec((None, 1, block_q),
+                     lambda *g: (g[0], 0, pos(*g)[0])),
+        pl.BlockSpec((None, 1, block_k),
+                     lambda *g, h=h: (g[0] // h, 0, pos(*g)[1])),
     )
 
 
@@ -182,13 +199,13 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool = False,
     if kv_mask is not None:
         # (B, T_k) f32 0/1; the grid's bh axis maps back to batch bh//h
         in_specs.append(krow_spec)
-        operands.append(_pad_t(kv_mask.astype(jnp.float32), block_k))
+        operands.append(_mask_rows(kv_mask, block_k))
     o_spec = q_spec
     o_shape = jax.ShapeDtypeStruct((b * h, n_q * block_q, dh), q.dtype)
     if return_lse:
         out_specs = (o_spec, qrow_spec)
         out_shape = (o_shape, jax.ShapeDtypeStruct(
-            (b * h, n_q * block_q), jnp.float32))
+            (b * h, 1, n_q * block_q), jnp.float32))
     else:  # serving path: no lse output, no wasted HBM write
         out_specs, out_shape = o_spec, o_shape
     res = pl.pallas_call(
@@ -199,15 +216,15 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool = False,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, dh), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
-            pltpu.VMEM((block_q, 128), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
+            pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
     )(*operands)
     if return_lse:
         out, lse = res
         return (out[:, :t_q].reshape(b, h, t_q, dh),
-                lse[:, :t_q].reshape(b, h, t_q))
+                lse[:, 0, :t_q].reshape(b, h, t_q))
     return res[:, :t_q].reshape(b, h, t_q, dh)
 
 
@@ -347,13 +364,12 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
     kp = _pad_t(k.reshape(b * h, t_k, dh), block_k)
     vp = _pad_t(v.reshape(b * h, t_k, dh), block_k)
     dop = _pad_t(do.reshape(b * h, t_q, dh), block_q)
-    lsep = _pad_t(lse.reshape(b * h, t_q), block_q)
-    deltap = _pad_t(delta.reshape(b * h, t_q), block_q)
+    lsep = _pad_t(lse.reshape(b * h, t_q), block_q)[:, None, :]
+    deltap = _pad_t(delta.reshape(b * h, t_q), block_q)[:, None, :]
     n_q = qp.shape[1] // block_q
     n_k = kp.shape[1] // block_k
 
-    maskp = (None if kv_mask is None
-             else _pad_t(kv_mask.astype(jnp.float32), block_k))
+    maskp = None if kv_mask is None else _mask_rows(kv_mask, block_k)
 
     q_spec, k_spec, col_spec, mask_spec = _spec_family(
         block_q, block_k, dh, h, q_minor=True)

@@ -28,18 +28,10 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.8 top-level API; the experimental path is deprecated
-    from jax import shard_map as _jax_shard_map
 
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _jax_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_vma=False)
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(f, mesh, in_specs, out_specs):
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
+def _shard_map(f, mesh, in_specs, out_specs):
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def reference_attention(q, k, v, causal: bool = False, kv_mask=None):

@@ -109,13 +109,16 @@ def _mesh_up(coord: CoordinatorClient, args):
     ``--rendezvous-key``; everyone else ``wait_kv``s it. Returns
     ``(jax, mesh)``. Must run before ANY other jax backend init."""
     if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        # CPU-mesh dev/CI recipe: the accelerator plugin would beat
-        # jax.distributed.initialize to backend init otherwise
+        # CPU-mesh dev/CI recipe: pin the CPU platform without touching
+        # the backend (jax.distributed.initialize must be its first init)
         from persia_tpu.utils import force_cpu_platform
 
         force_cpu_platform(1, verify=False)
     import jax  # noqa: F401  (deferred: heavyweight, mesh cells only)
 
+    from persia_tpu.utils import enable_compile_cache
+
+    enable_compile_cache()
     from persia_tpu.distributed import DistributedOption
 
     if args.process_count == 1:

@@ -39,6 +39,7 @@ from persia_tpu.service.coordinator import (
     CoordinatorClient,
 )
 from persia_tpu.service.ps_service import PsClient
+from persia_tpu.worker import mw_native
 from persia_tpu.worker.worker import EmbeddingWorker, ForwardBufferFull
 
 _logger = get_default_logger(__name__)
@@ -107,6 +108,9 @@ class WorkerService:
             doc["post_forward_buffer_depth"] = len(w._post_forward_buffer)
             doc["staleness"] = w.staleness
         doc["ps_replicas"] = w.replica_size
+        # which middleware kernels this worker runs (the numpy twins are
+        # a silent fallback when the native library is absent or stale)
+        doc["mw_kernels"] = "native" if mw_native.available() else "numpy"
         # elastic-tier observable: which routing epoch this worker
         # splits by (the fleet's /fleet/routing skew check reads it)
         doc["routing_epoch"] = w.routing_epoch

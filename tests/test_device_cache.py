@@ -535,3 +535,16 @@ def test_cached_multi_id_bags_on_mesh_with_eviction():
         for sign in tr:
             np.testing.assert_allclose(tc[sign], tr[sign], rtol=1e-3,
                                        atol=1e-3, err_msg=f"sign {sign}")
+
+
+def test_cache_over_remote_worker_fails_at_construction():
+    """RemoteEmbeddingWorker carries no lookup_rows_with_state/set_rows
+    RPC, so ServiceCtx -> remote_worker() -> device_cache_capacity= used
+    to die with an AttributeError inside the first train_step. It must
+    be refused where it is configured, and say what works instead."""
+    from persia_tpu.service.worker_service import RemoteEmbeddingWorker
+
+    remote = RemoteEmbeddingWorker(["127.0.0.1:1"])  # connects lazily
+    with pytest.raises(TypeError, match="lookup_rows_with_state"):
+        _make_ctx(remote, cache_capacity=64)
+    _make_ctx(remote, cache_capacity=0)  # uncached over RPC stays fine

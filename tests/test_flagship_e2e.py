@@ -5,9 +5,9 @@ binaries) + two Criteo data-loader replicas streaming learnable batches
 over the dataflow + an 8-device CPU-mesh DDP trainer in this process —
 the full distributed topology the reference runs on a GPU pod
 (`/root/reference/k8s/resources/example.yaml` roles), asserted to
-*learn* (AUC on held-out draws of the same hidden-weight task) with
-throughput printed for BASELINE.md. Point the same wiring at real TPU
-hardware and it is the production config-3 job.
+*learn* (AUC on held-out draws of the same hidden-weight task). Point
+the same wiring at real TPU hardware and it is the production config-3
+job.
 """
 
 import os
@@ -77,7 +77,7 @@ def _run_flagship():
             **os.environ,
             "PYTHONPATH": str(REPO),
             "PERSIA_COORDINATOR_ADDR": svc.coordinator_addr,
-            "PERSIA_FORCE_JAX_PLATFORM": "cpu",
+            "JAX_PLATFORMS": "cpu",
             "PERSIA_NUM_WORKERS": "2",
             "WORLD_SIZE": "1",
         }

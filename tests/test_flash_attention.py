@@ -1,6 +1,11 @@
 """Pallas flash attention: interpret-mode parity with the XLA blockwise
 implementation, gradient parity through the recompute backward, and the
-compiled-on-TPU gate (PERSIA_TEST_TPU=1)."""
+compiled-on-TPU gate (PERSIA_TEST_TPU=1).
+
+Block sizes are clamped to multiples of 128 (what lowers for TPU), so
+the multi-block cases here use T of a few hundred with block 128; that
+every variant also lowers and compiles for the chip is
+tests/test_tpu_lowering.py's job."""
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +32,8 @@ def _qkv(b=2, h=2, t=96, dh=16, t_k=None, seed=0, dtype=jnp.float32):
 
 
 @pytest.mark.parametrize("causal", [False, True])
-@pytest.mark.parametrize("t,block", [(96, 32), (128, 64), (100, 32)])
+@pytest.mark.parametrize("t,block", [(256, 128), (384, 128), (300, 128),
+                                     (100, 128)])  # last: T under a block
 def test_fwd_matches_reference(causal, t, block):
     q, k, v = _qkv(t=t)
     ref = reference_attention(q, k, v, causal=causal)
@@ -39,29 +45,29 @@ def test_fwd_matches_reference(causal, t, block):
 
 
 def test_fwd_cross_attention_lengths():
-    q, k, v = _qkv(t=64, t_k=160)
+    q, k, v = _qkv(t=200, t_k=420)
     ref = reference_attention(q, k, v, causal=False)
-    out = flash_attention_fwd_pallas(q, k, v, block_q=32, block_k=64,
+    out = flash_attention_fwd_pallas(q, k, v, block_q=128, block_k=256,
                                      interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
 
 def test_fwd_bf16_matches_scan_impl():
-    q, k, v = _qkv(t=128, dh=64, dtype=jnp.bfloat16)
+    q, k, v = _qkv(t=256, dh=64, dtype=jnp.bfloat16)
     scan = local_flash_attention(q, k, v, causal=True, chunk_size=64)
-    out = flash_attention_fwd_pallas(q, k, v, causal=True, block_q=64,
-                                     block_k=64, interpret=True)
+    out = flash_attention_fwd_pallas(q, k, v, causal=True, block_q=128,
+                                     block_k=128, interpret=True)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(scan, np.float32),
                                rtol=2e-2, atol=2e-2)
 
 
 def test_grad_matches_xla_blockwise():
-    q, k, v = _qkv(t=96)
+    q, k, v = _qkv(t=288)
 
     def loss_pallas(q, k, v):
-        return jnp.mean(flash_attention(q, k, v, True, 32, 32, True) ** 2)
+        return jnp.mean(flash_attention(q, k, v, True, 128, 128, True) ** 2)
 
     def loss_xla(q, k, v):
         return jnp.mean(
@@ -77,10 +83,11 @@ def test_grad_matches_xla_blockwise():
 @pytest.mark.parametrize("causal", [False, True])
 def test_grad_cross_attention_lengths(causal):
     """Pallas bwd with t_q != t_k and padding on both grids."""
-    q, k, v = _qkv(t=48, t_k=112)
+    q, k, v = _qkv(t=144, t_k=336)
 
     def loss_p(q, k, v):
-        return jnp.mean(flash_attention(q, k, v, causal, 32, 32, True) ** 2)
+        return jnp.mean(
+            flash_attention(q, k, v, causal, 128, 128, True) ** 2)
 
     def loss_r(q, k, v):
         return jnp.mean(
@@ -94,11 +101,11 @@ def test_grad_cross_attention_lengths(causal):
 
 
 def test_grad_bf16_finite_and_close():
-    q, k, v = _qkv(t=128, dh=64, dtype=jnp.bfloat16)
+    q, k, v = _qkv(t=256, dh=64, dtype=jnp.bfloat16)
 
     def loss_p(q, k, v):
         return jnp.mean(
-            flash_attention(q, k, v, True, 64, 64, True).astype(
+            flash_attention(q, k, v, True, 128, 128, True).astype(
                 jnp.float32) ** 2)
 
     gp = jax.grad(loss_p, argnums=(0, 1, 2))(q, k, v)
@@ -119,22 +126,23 @@ def test_kv_mask_fwd_and_grad(causal):
     including a fully-masked batch row (output and grads -> 0)."""
     from persia_tpu.ops.flash_attention import flash_attention_masked
 
-    q, k, v = _qkv(t=96)
+    q, k, v = _qkv(t=288)
     rng = np.random.default_rng(3)
-    kv_mask = jnp.asarray(rng.random((2, 96)) > 0.3)
+    kv_mask = jnp.asarray(rng.random((2, 288)) > 0.3)
     kv_mask = kv_mask.at[1, :].set(False)  # row 1: nothing valid
 
     def loss_p(q, k, v):
         return jnp.mean(flash_attention_masked(
-            q, k, v, kv_mask=kv_mask, causal=causal, block_q=32,
-            block_k=32, interpret=True) ** 2)
+            q, k, v, kv_mask=kv_mask, causal=causal, block_q=128,
+            block_k=128, interpret=True) ** 2)
 
     def loss_r(q, k, v):
         return jnp.mean(reference_attention(
             q, k, v, causal=causal, kv_mask=kv_mask) ** 2)
 
     out_p = flash_attention_masked(q, k, v, kv_mask=kv_mask, causal=causal,
-                                   block_q=32, block_k=32, interpret=True)
+                                   block_q=128, block_k=128,
+                                   interpret=True)
     out_r = reference_attention(q, k, v, causal=causal, kv_mask=kv_mask)
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_r),
                                rtol=2e-5, atol=2e-5)

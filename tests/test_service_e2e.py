@@ -353,7 +353,7 @@ def _run_four_role_deployment():
             **os.environ,
             "PYTHONPATH": repo,
             "PERSIA_COORDINATOR_ADDR": svc.coordinator_addr,
-            "PERSIA_FORCE_JAX_PLATFORM": "cpu",
+            "JAX_PLATFORMS": "cpu",
             "RANK": "0", "WORLD_SIZE": "1", "REPLICA_INDEX": "0",
             "REPLICA_SIZE": "1",
         }

@@ -1,0 +1,152 @@
+"""The attention kernel must lower and compile for TPU — checked on the
+CPU, with no chip.
+
+Interpret mode accepts block shapes the TPU lowering refuses, which is
+how 20 PRs of green CPU tests hid a kernel that could neither train nor
+take a mask on the chip. Two guards, both run here on the CPU:
+
+- cross-lowering with ``jax.export`` (platforms=["tpu"]): runs the
+  Pallas TPU lowering (block-shape rules, Mosaic MLIR emission);
+- ahead-of-time compilation against a compile-only v5e topology, in a
+  subprocess: runs libtpu's compiler, Mosaic included (VMEM budget,
+  unsupported layout changes). Skipped only where libtpu is not
+  installed; with libtpu, any failure to get the topology fails.
+
+Neither replaces the compiled-and-compared check on the chip
+(``chip_smoke.py`` kernel phase, ``test_compiled_on_tpu``): values only
+come from a run.
+"""
+
+import importlib.util
+import itertools
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+if __name__ == "__main__":  # the AOT subprocess: no conftest on the path
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+
+from persia_tpu.ops.flash_attention import (  # noqa: E402
+    _clamp_block,
+    flash_attention_masked,
+)
+
+# (T, dh, dtype): the smoke's two lengths at the full head width, plus
+# small ragged shapes the CPU tests use
+SHAPES = [
+    (4096, 128, jnp.bfloat16),
+    (1000, 128, jnp.bfloat16),
+    (200, 64, jnp.bfloat16),
+    (100, 8, jnp.float32),
+]
+VARIANTS = list(itertools.product([False, True], [False, True]))  # mask×grad
+
+
+def _attn_fn(masked: bool, grad: bool, causal: bool = False):
+    def fwd(q, k, v, m):
+        return flash_attention_masked(
+            q, k, v, kv_mask=m if masked else None, causal=causal,
+            interpret=False)
+
+    if not grad:
+        return fwd
+
+    def bwd(q, k, v, m):
+        return jax.grad(
+            lambda q, k, v: fwd(q, k, v, m).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2))(q, k, v)
+
+    return bwd
+
+
+def _avals(t, dh, dtype, sharding=None):
+    q = jax.ShapeDtypeStruct((4, 8, t, dh), dtype, sharding=sharding)
+    m = jax.ShapeDtypeStruct((4, t), jnp.bool_, sharding=sharding)
+    return q, q, q, m
+
+
+@pytest.mark.parametrize("masked,grad", VARIANTS)
+@pytest.mark.parametrize("t,dh,dtype", SHAPES)
+def test_attention_cross_lowers_for_tpu(t, dh, dtype, masked, grad):
+    exported = jax.export.export(
+        jax.jit(_attn_fn(masked, grad)), platforms=["tpu"])(
+            *_avals(t, dh, dtype))
+    assert "tpu_custom_call" in exported.mlir_module()
+
+
+def test_block_requests_clamp_to_the_lane_width():
+    """Ulysses passes chunk_size as the block. A request is clamped to
+    the sequence length and rounded UP to a multiple of 128, so a
+    sub-128 block does not exist on this kernel: asking for one gets
+    128."""
+    assert [_clamp_block(b, 300) for b in (32, 64, 128, 200, 512)] == [
+        128, 128, 128, 256, 384]
+    assert _clamp_block(512, 4096) == 512
+    assert _clamp_block(512, 1000) == 512   # T is padded to 1024
+    assert _clamp_block(512, 100) == 128    # T is padded to 128
+
+
+def test_custom_block_sizes_lower_for_tpu():
+    """The distinct blocks the clamp can yield at T=300 (128, 256, 384)
+    each lower, backward included."""
+    for block in (128, 200, 512):
+        def f(q, k, v, m, block=block):
+            return jax.grad(lambda q: flash_attention_masked(
+                q, k, v, kv_mask=m, block_q=block, block_k=block,
+                interpret=False).astype(jnp.float32).sum())(q)
+
+        exported = jax.export.export(jax.jit(f), platforms=["tpu"])(
+            *_avals(300, 128, jnp.bfloat16))
+        assert "tpu_custom_call" in exported.mlir_module()
+
+
+def _aot_compile_all() -> int:
+    """Subprocess body: compile every variant for a v5e with no chip."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    # with libtpu installed this must work; a failure here fails the test
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2")
+    sharding = SingleDeviceSharding(topo.devices[0])
+    failed = 0
+    for (t, dh, dtype), (masked, grad), causal in itertools.product(
+            SHAPES[:2], VARIANTS, (False, True)):
+        name = (f"T={t} dh={dh} {'masked' if masked else 'plain'} "
+                f"{'grad' if grad else 'fwd'} causal={causal}")
+        try:
+            compiled = jax.jit(_attn_fn(masked, grad, causal)).lower(
+                *_avals(t, dh, dtype, sharding)).compile()
+            assert "tpu_custom_call" in compiled.as_text()
+            print(f"COMPILED {name}")
+        except Exception as e:  # noqa: BLE001 — every variant reported
+            failed += 1
+            print(f"REFUSED {name}: {str(e)[:600]}")
+    return failed
+
+
+def test_attention_aot_compiles_for_v5e():
+    # the one legitimate skip: no TPU compiler in this installation.
+    # With libtpu present, a topology it cannot describe is a failure —
+    # a skip would put back the false green this file exists to end
+    if importlib.util.find_spec("libtpu") is None:
+        pytest.skip("libtpu is not installed: no TPU compiler to run")
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    # libtpu wants these named when there is no TPU metadata to read
+    env.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    env.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+    r = subprocess.run([sys.executable, os.path.abspath(__file__)],
+                       env=env, capture_output=True, text=True,
+                       timeout=600)
+    assert r.returncode == 0 and "REFUSED" not in r.stdout, (
+        r.stdout[-4000:] + r.stderr[-2000:])
+    assert r.stdout.count("COMPILED") == 16, r.stdout[-4000:]
+
+
+if __name__ == "__main__":
+    sys.exit(_aot_compile_all())

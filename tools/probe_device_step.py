@@ -22,8 +22,8 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 
 from persia_tpu.utils import arm_watchdog
 
-# chip-touching tool: in-process watchdog, never external kill
-# (round-4 wedged-claim lesson, BASELINE.md)
+# chip-touching tool: in-process watchdog
+# (a hung backend call must end the probe, not hang it)
 arm_watchdog(1200, label=__file__)
 
 from persia_tpu.models import DLRM

@@ -21,8 +21,8 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent))
 from persia_tpu.utils import arm_watchdog
 
 # chip-touching tool: in-process watchdog armed BEFORE the jax import so
-# even a hang during backend init self-exits; never external kill
-# (round-4 wedged-claim lesson, BASELINE.md)
+# even a hang during backend init self-exits
+# (a hung backend call must end the probe, not hang it)
 arm_watchdog(1200, label=__file__)
 
 import jax
