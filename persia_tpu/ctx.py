@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
+from persia_tpu import tracing
 from persia_tpu.config import EmbeddingSchema, GlobalConfig
 from persia_tpu.data.batch import PersiaBatch
 from persia_tpu.embedding import EmbeddingConfig, get_default_embedding_config
@@ -249,13 +250,12 @@ class TrainCtx(EmbeddingCtx):
         self._cached_step = None
         self._cache_multi_id = False
         # opt-in device profiler window (tracing.StepProfiler): a
-        # jax.profiler trace capture keyed to a step range, so the TPU
-        # timeline aligns with the host spans of exactly those steps.
-        # Defaults from PERSIA_PROFILE_DIR/_START_STEP/_NUM_STEPS.
-        from persia_tpu import tracing as _tracing
-
+        # jax.profiler trace capture keyed to a step range; the spans of
+        # exactly those steps land in the same xplane as the TPU
+        # timeline. Defaults from PERSIA_PROFILE_DIR/_START_STEP/
+        # _NUM_STEPS.
         self.profiler = (profiler if profiler is not None
-                         else _tracing.profiler_from_env())
+                         else tracing.profiler_from_env())
         self._step_count = 0
         # --- whole-job resume (persia_tpu/snapshot.py) -----------------
         # `resume_from` names one snapshot directory or a snapshot_dir
@@ -332,7 +332,9 @@ class TrainCtx(EmbeddingCtx):
 
         return batch_size % self.mesh.shape[DATA_AXIS] == 0
 
-    def _ensure_compiled(self, non_id, emb_inputs):
+    def _ensure_compiled(self, non_id, emb_inputs) -> bool:
+        """Builds the state and the step for this batch geometry where
+        they are missing; True when it built a step."""
         from persia_tpu.parallel.train import (
             create_train_state,
             make_eval_step,
@@ -385,6 +387,8 @@ class TrainCtx(EmbeddingCtx):
                     self.model, self.dense_optimizer, emb_shapes,
                     loss_fn=self.loss_fn, wire_dtype=self._wire_dtype(),
                 )
+            return True
+        return False
 
     def _prep_train_inputs(self, batch: PersiaBatch,
                            lookup: Dict[str, Any]) -> tuple:
@@ -404,38 +408,40 @@ class TrainCtx(EmbeddingCtx):
             pack_embedding_values_batch_major,
         )
 
-        non_id = [jnp.asarray(f.data) for f in batch.non_id_type_features]
-        labels = [jnp.asarray(l.data) for l in batch.labels]
-        emb_np: List[np.ndarray] = []
-        emb_indices: List[Any] = []
-        emb_inputs: List[Any] = []  # host-side, for model init/shapes only
-        for f in batch.id_type_features:
-            r = lookup[f.name]
-            if isinstance(r, SumEmbedding):
-                emb_np.append(r.embeddings)
-                emb_indices.append(None)
-                emb_inputs.append(r.embeddings)
-            elif isinstance(r, RawEmbedding):
-                idx = jnp.asarray(r.index)
-                emb_np.append(r.embeddings)
-                emb_indices.append(idx)
-                emb_inputs.append((r.embeddings, idx))
-            else:
-                raise TypeError(f"unexpected lookup result {type(r)}")
-        emb_shapes = tuple(tuple(v.shape) for v in emb_np)
-        if self._use_ddp_step(emb_indices, len(labels[0])):
-            from persia_tpu.parallel.mesh import batch_sharding
+        with tracing.span("trainer/prep_inputs"):
+            non_id = [jnp.asarray(f.data) for f in batch.non_id_type_features]
+            labels = [jnp.asarray(l.data) for l in batch.labels]
+            emb_np: List[np.ndarray] = []
+            emb_indices: List[Any] = []
+            emb_inputs: List[Any] = []  # host-side, for model init/shapes only
+            for f in batch.id_type_features:
+                r = lookup[f.name]
+                if isinstance(r, SumEmbedding):
+                    emb_np.append(r.embeddings)
+                    emb_indices.append(None)
+                    emb_inputs.append(r.embeddings)
+                elif isinstance(r, RawEmbedding):
+                    idx = jnp.asarray(r.index)
+                    emb_np.append(r.embeddings)
+                    emb_indices.append(idx)
+                    emb_inputs.append((r.embeddings, idx))
+                else:
+                    raise TypeError(f"unexpected lookup result {type(r)}")
+            emb_shapes = tuple(tuple(v.shape) for v in emb_np)
+            if self._use_ddp_step(emb_indices, len(labels[0])):
+                from persia_tpu.parallel.mesh import batch_sharding
 
-            flat_emb = jax.device_put(
-                pack_embedding_values_batch_major(emb_np,
-                                                  self._wire_dtype()),
-                batch_sharding(self.mesh),
-            )
-        else:
-            flat_emb = jnp.asarray(
-                pack_embedding_values(emb_np, self._wire_dtype())
-            )
-        return non_id, emb_inputs, emb_shapes, flat_emb, emb_indices, labels
+                flat_emb = jax.device_put(
+                    pack_embedding_values_batch_major(emb_np,
+                                                      self._wire_dtype()),
+                    batch_sharding(self.mesh),
+                )
+            else:
+                flat_emb = jnp.asarray(
+                    pack_embedding_values(emb_np, self._wire_dtype())
+                )
+            return (non_id, emb_inputs, emb_shapes, flat_emb, emb_indices,
+                    labels)
 
     def stage_batch(self, batch: PersiaBatch, lookup: Dict[str, Any]):
         """Host->device staging for one looked-up batch, run by the
@@ -467,9 +473,12 @@ class TrainCtx(EmbeddingCtx):
         Observability: each step runs under a ``trainer/train_step``
         span — joined to the batch's existing trace when it came through
         the pipeline (the prefetch worker's lookup opened the root), a
-        fresh root otherwise — and drives the opt-in
-        :class:`~persia_tpu.tracing.StepProfiler` window."""
-        from persia_tpu import tracing
+        fresh root otherwise — with one child span per phase the step
+        runs (``trainer/lookup_direct``, ``trainer/prep_inputs``,
+        ``trainer/place_batch``, ``trainer/dispatch``,
+        ``trainer/grad_submit``; ``cache/*`` on the cached path), and
+        drives the opt-in :class:`~persia_tpu.tracing.StepProfiler`
+        window."""
         from persia_tpu.pipeline import LookedUpBatch
 
         self._step_count += 1
@@ -506,13 +515,14 @@ class TrainCtx(EmbeddingCtx):
             staged = batch.staged
             batch = batch.batch
         else:
-            ref_id, lookup = self.worker.lookup_direct_training(
-                batch.id_type_features
-            )
+            with tracing.span("trainer/lookup_direct"):
+                ref_id, lookup = self.worker.lookup_direct_training(
+                    batch.id_type_features
+                )
         if staged is None:
             staged = self._prep_train_inputs(batch, lookup)
         non_id, emb_inputs, _emb_shapes, flat_emb, emb_indices, labels = staged
-        self._ensure_compiled(non_id, emb_inputs)
+        compiled = self._ensure_compiled(non_id, emb_inputs)
         if self.mesh is not None:
             from persia_tpu.parallel.mesh import shard_batch_pytree
 
@@ -522,40 +532,44 @@ class TrainCtx(EmbeddingCtx):
             non_id, emb_indices, label = placed["n"], placed["i"], placed["l"]
         else:
             label = labels[0]
-        if self._ddp:
-            if self._ef_state is not None:
-                (self.state, loss, flat_grads, pred,
-                 self._ef_state) = self._train_step(
-                    self.state, non_id, flat_emb, label, self._ef_state)
+        with tracing.span("trainer/dispatch", compiled=compiled):
+            if self._ddp:
+                if self._ef_state is not None:
+                    (self.state, loss, flat_grads, pred,
+                     self._ef_state) = self._train_step(
+                        self.state, non_id, flat_emb, label, self._ef_state)
+                else:
+                    self.state, loss, flat_grads, pred = self._train_step(
+                        self.state, non_id, flat_emb, label
+                    )
             else:
                 self.state, loss, flat_grads, pred = self._train_step(
-                    self.state, non_id, flat_emb, label
+                    self.state, non_id, flat_emb, emb_indices, label
                 )
-        else:
-            self.state, loss, flat_grads, pred = self._train_step(
-                self.state, non_id, flat_emb, emb_indices, label
-            )
         names = [f.name for f in batch.id_type_features]
         slot_dims = self._slot_dims if self._ddp else None
-        if engine is not None:
-            # the device->host gradient fetch happens in a backward worker
-            # thread, not here — on a slow host link a synchronous fetch
-            # would serialize every step on the d2h transfer
-            engine.backward.submit_packed(
-                ref_id, flat_grads, self._emb_shapes, names,
-                slot_dims=slot_dims)
-        else:
-            if self._ddp:
-                from persia_tpu.parallel.train import (
-                    unpack_embedding_grads_batch_major,
-                )
-
-                per_slot = unpack_embedding_grads_batch_major(
-                    flat_grads, slot_dims)
+        with tracing.span("trainer/grad_submit"):
+            if engine is not None:
+                # the device->host gradient fetch happens in a backward
+                # worker thread, not here — on a slow host link a
+                # synchronous fetch would serialize every step on the d2h
+                # transfer
+                engine.backward.submit_packed(
+                    ref_id, flat_grads, self._emb_shapes, names,
+                    slot_dims=slot_dims)
             else:
-                per_slot = unpack_embedding_grads(flat_grads,
-                                                  self._emb_shapes)
-            self.worker.update_gradients(ref_id, dict(zip(names, per_slot)))
+                if self._ddp:
+                    from persia_tpu.parallel.train import (
+                        unpack_embedding_grads_batch_major,
+                    )
+
+                    per_slot = unpack_embedding_grads_batch_major(
+                        flat_grads, slot_dims)
+                else:
+                    per_slot = unpack_embedding_grads(flat_grads,
+                                                      self._emb_shapes)
+                self.worker.update_gradients(
+                    ref_id, dict(zip(names, per_slot)))
         return loss, pred
 
     def _apply_model(self, non_id, emb_inputs):
@@ -604,9 +618,9 @@ class TrainCtx(EmbeddingCtx):
         sqrt_scaling parity). A mesh is supported — the cache becomes
         one GSPMD row-sharded array (cached_train._row_sharding).
         Anything outside the envelope raises with the reason rather
-        than silently degrading."""
+        than silently degrading. True when it built them."""
         if self._cache_engine is not None:
-            return
+            return False
         if jax.process_count() > 1:
             # Single-controller constraint: the engine's sign->slot map,
             # miss imports and eviction write-backs are host-side state
@@ -710,9 +724,10 @@ class TrainCtx(EmbeddingCtx):
             self.state = _ckpt.apply_dense_bytes(self.state,
                                                  self._pending_dense)
             self._pending_dense = None
+        return True
 
     def _cached_train_step(self, batch: PersiaBatch):
-        self._ensure_cache(batch)
+        compiled = self._ensure_cache(batch)
         eng = self._cache_engine
         non_id = [jnp.asarray(f.data) for f in batch.non_id_type_features]
         label = jnp.asarray(batch.labels[0].data)
@@ -720,23 +735,19 @@ class TrainCtx(EmbeddingCtx):
             (flat_slot_idx, seg, scale, cold_idx, cold_vals, cold_acc,
              evicted, evicted_mask, inverse,
              unique_slots) = eng.prepare_bags(batch.id_type_features)
-            (self.state, eng.cache_vals, eng.cache_acc, loss, pred,
-             ev_vals, ev_acc) = self._cached_step(
-                self.state, eng.cache_vals, eng.cache_acc, non_id,
-                jnp.asarray(flat_slot_idx), jnp.asarray(seg),
-                jnp.asarray(scale), jnp.asarray(cold_idx),
-                jnp.asarray(cold_vals), jnp.asarray(cold_acc),
-                jnp.asarray(inverse), jnp.asarray(unique_slots), label)
+            positions = (flat_slot_idx, seg, scale)
         else:
             (slot_idx, cold_idx, cold_vals, cold_acc, evicted,
              evicted_mask, inverse,
              unique_slots) = eng.prepare(batch.id_type_features)
+            positions = (slot_idx,)
+        with tracing.span("trainer/dispatch", compiled=compiled):
             (self.state, eng.cache_vals, eng.cache_acc, loss, pred,
              ev_vals, ev_acc) = self._cached_step(
                 self.state, eng.cache_vals, eng.cache_acc, non_id,
-                jnp.asarray(slot_idx), jnp.asarray(cold_idx),
-                jnp.asarray(cold_vals), jnp.asarray(cold_acc),
-                jnp.asarray(inverse), jnp.asarray(unique_slots), label)
+                *map(jnp.asarray, positions + (
+                    cold_idx, cold_vals, cold_acc, inverse, unique_slots)),
+                label)
         eng.finish(evicted, evicted_mask, ev_vals, ev_acc)
         return loss, pred
 

@@ -376,8 +376,11 @@ REGISTRY: Dict[str, Knob] = {k.name: k for k in [
        "(cold newcomers churn there; rows earn protected residency by "
        "out-counting the protected LRU victim)."),
     _k("PERSIA_TRACING", "bool", False,
-       "Cross-tier span capture. Frozen at import ON PURPOSE: the "
-       "disabled path must cost nothing, so the gate is a module "
+       "Cross-tier span capture, and the span context on the RPC "
+       "envelope. Spans also record, in the process alone, while a "
+       "jax.profiler session is live there (PERSIA_PROFILE_DIR or any "
+       "other), as events of that trace. Frozen at import ON PURPOSE: "
+       "the disabled path must cost nothing, so the gate is a module "
        "constant; tests toggle via subprocess env.",
        import_time_safe=True),
     _k("PERSIA_TRAINER_PROCESSES", "int", 1,

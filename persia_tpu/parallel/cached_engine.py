@@ -34,6 +34,7 @@ from typing import List, Tuple
 
 import numpy as np
 
+from persia_tpu import tracing
 from persia_tpu.logger import get_logger
 from persia_tpu.parallel.cached_train import pad_to_bucket
 from persia_tpu.worker.device_cache import VictimBuffer, make_sign_slot_map
@@ -138,6 +139,15 @@ class DeviceCacheEngine:
             self._m_promotions.inc(pr - pp)
         self._m_resident.set(len(m))
 
+    def _map(self, flat_signs):
+        """Signs -> cache slots through the mapper, counters published:
+        the mapper's part of a step's host time."""
+        with tracing.span("cache/map", signs=len(flat_signs)) as sp:
+            res = self.mapper.assign(flat_signs)
+            self._publish_counters()
+            sp.tag(unique=int(res.n_unique), misses=len(res.miss_pos))
+        return res
+
     # --- per-batch host work --------------------------------------------
 
     def prepare(self, id_type_features) -> Tuple[
@@ -156,8 +166,7 @@ class DeviceCacheEngine:
         signs = np.stack([f.signs for f in id_type_features], axis=1)
         batch, num_slots = signs.shape
         flat_signs = signs.reshape(-1)
-        res = self.mapper.assign(flat_signs)
-        self._publish_counters()
+        res = self._map(flat_signs)
         # tail past the distinct count is uninitialized: point it at the
         # dummy slot so the device update's pad rows are inert
         unique_slots = res.unique_slots
@@ -198,8 +207,7 @@ class DeviceCacheEngine:
         flat_signs = np.concatenate(sign_parts).astype(np.uint64)
         seg = np.concatenate(seg_parts)
         n = len(flat_signs)
-        res = self.mapper.assign(flat_signs)
-        self._publish_counters()
+        res = self._map(flat_signs)
         lpad = pad_to_bucket(max(n, 1), _BUCKETS)
         flat_slot_idx = np.full(lpad, self.capacity, np.int32)
         flat_slot_idx[:n] = res.slots
@@ -233,47 +241,49 @@ class DeviceCacheEngine:
         """Fetch this batch's miss rows (victim buffer first, then PS),
         bucket-padded. Returns (cold_idx, cold_vals, cold_acc,
         evicted_signs, evicted_mask, mpad)."""
-        slots, miss_pos, evicted, emask = (res.slots, res.miss_pos,
-                                           res.evicted_signs,
-                                           res.evicted_mask)
-        miss_signs = flat_signs[miss_pos]
-        m = len(miss_signs)
-        mpad = pad_to_bucket(max(m, 1), _BUCKETS)
-        cold_idx = np.full(mpad, self.capacity, np.int32)  # pad -> dummy
-        cold_vals = np.zeros((mpad, self.dim), np.float32)
-        cold_acc = np.full((mpad, self.dim), self.acc_init, np.float32)
-        evicted_signs = np.zeros(mpad, np.uint64)
-        evicted_mask = np.zeros(mpad, bool)
-        if m:
-            cold_idx[:m] = slots[miss_pos]
-            evicted_signs[:m] = evicted
-            evicted_mask[:m] = emask
-            # victim buffer first: an evicted row still in flight is the
-            # authoritative copy (the PS write-back may not have landed).
-            # Entries are (ev_vals, ev_acc, row) with possibly-device
-            # arrays; np.asarray blocks until the step that produced
-            # them finished, so the value read here is never stale.
-            need_ps = []
-            for i, s in enumerate(miss_signs):
-                v = self.victims.take(int(s))
-                if v is not None:
-                    vvals, vacc, row = v
-                    cold_vals[i] = np.asarray(vvals)[row]
-                    cold_acc[i] = np.asarray(vacc)[row]
-                else:
-                    need_ps.append(i)
-            if need_ps:
-                idx = np.asarray(need_ps)
-                vals, state = self.worker.lookup_rows_with_state(
-                    miss_signs[idx], self.dim,
-                    default_state=self.acc_init)
-                cold_vals[idx] = vals
-                if state.shape[1] == self.dim:
-                    cold_acc[idx] = state
-                # (space != dim would mean a non-matching optimizer; the
-                # ctx-level guard rejects that before the engine exists)
-        return (cold_idx, cold_vals, cold_acc, evicted_signs,
-                evicted_mask, mpad)
+        with tracing.span("cache/miss_import") as sp:
+            slots, miss_pos, evicted, emask = (res.slots, res.miss_pos,
+                                               res.evicted_signs,
+                                               res.evicted_mask)
+            miss_signs = flat_signs[miss_pos]
+            m = len(miss_signs)
+            mpad = pad_to_bucket(max(m, 1), _BUCKETS)
+            sp.tag(rows=m, bucket=mpad)
+            cold_idx = np.full(mpad, self.capacity, np.int32)  # pad -> dummy
+            cold_vals = np.zeros((mpad, self.dim), np.float32)
+            cold_acc = np.full((mpad, self.dim), self.acc_init, np.float32)
+            evicted_signs = np.zeros(mpad, np.uint64)
+            evicted_mask = np.zeros(mpad, bool)
+            if m:
+                cold_idx[:m] = slots[miss_pos]
+                evicted_signs[:m] = evicted
+                evicted_mask[:m] = emask
+                # victim buffer first: an evicted row still in flight is the
+                # authoritative copy (the PS write-back may not have landed).
+                # Entries are (ev_vals, ev_acc, row) with possibly-device
+                # arrays; np.asarray blocks until the step that produced
+                # them finished, so the value read here is never stale.
+                need_ps = []
+                for i, s in enumerate(miss_signs):
+                    v = self.victims.take(int(s))
+                    if v is not None:
+                        vvals, vacc, row = v
+                        cold_vals[i] = np.asarray(vvals)[row]
+                        cold_acc[i] = np.asarray(vacc)[row]
+                    else:
+                        need_ps.append(i)
+                if need_ps:
+                    idx = np.asarray(need_ps)
+                    vals, state = self.worker.lookup_rows_with_state(
+                        miss_signs[idx], self.dim,
+                        default_state=self.acc_init)
+                    cold_vals[idx] = vals
+                    if state.shape[1] == self.dim:
+                        cold_acc[idx] = state
+                    # (space != dim would mean a non-matching optimizer; the
+                    # ctx-level guard rejects that before the engine exists)
+            return (cold_idx, cold_vals, cold_acc, evicted_signs,
+                    evicted_mask, mpad)
 
     def finish(self, evicted_signs: np.ndarray, evicted_mask: np.ndarray,
                ev_vals, ev_acc) -> None:
@@ -284,18 +294,20 @@ class DeviceCacheEngine:
         if self._flush_err:
             raise self._flush_err[0]
         real = list(np.nonzero(evicted_mask)[0])
-        if not real:
-            return
-        self._flush_token += 1
-        token = self._flush_token
-        for i in real:
-            # the buffered entry holds the device arrays themselves: a
-            # miss racing the write-back materializes its row directly,
-            # so there is no window where the PS copy (stale) is the only
-            # readable one
-            self.victims.put(int(evicted_signs[i]),
-                             (ev_vals, ev_acc, i), token=token)
-        self._flush_q.put((token, evicted_signs, real, ev_vals, ev_acc))
+        with tracing.span("cache/finish", evicted=len(real)):
+            if not real:
+                return
+            self._flush_token += 1
+            token = self._flush_token
+            for i in real:
+                # the buffered entry holds the device arrays themselves:
+                # a miss racing the write-back materializes its row
+                # directly, so there is no window where the PS copy
+                # (stale) is the only readable one
+                self.victims.put(int(evicted_signs[i]),
+                                 (ev_vals, ev_acc, i), token=token)
+            self._flush_q.put((token, evicted_signs, real, ev_vals, ev_acc,
+                               tracing.current_context()))
 
     # --- write-back -------------------------------------------------------
 
@@ -312,33 +324,38 @@ class DeviceCacheEngine:
             finally:
                 self._flush_q.task_done()
 
-    def _flush_job(self, token, evicted_signs, real, ev_vals, ev_acc):
-        vals = np.asarray(ev_vals)  # d2h here, off the training thread
-        acc = np.asarray(ev_acc)
-        todo_signs, todo_vecs = [], []
-        for i in real:
-            sign = int(evicted_signs[i])
-            # token-matched PEEK (no removal yet): absent or different
-            # token => the miss path reclaimed the row (the cache copy is
-            # authoritative again) or a newer eviction owns the sign —
-            # either way writing our older value would clobber fresher
-            # state, so skip.
-            if self.victims.peek_if(sign, token) is None:
-                continue
-            todo_signs.append(sign)
-            todo_vecs.append(np.concatenate([vals[i], acc[i]]))
-        if todo_signs:
-            self.worker.set_rows(
-                np.asarray(todo_signs, np.uint64),
-                np.stack(todo_vecs), self.dim)
-            self._m_writebacks.inc(len(todo_signs))
-        # remove only AFTER the PS write landed: a miss racing the write
-        # must keep finding the pending entry, otherwise it would read
-        # the stale pre-write PS row. A miss that took the entry mid-
-        # write is also fine — the PS got the same value, and the cache
-        # copy stays authoritative.
-        for sign in todo_signs:
-            self.victims.take_if(sign, token)
+    def _flush_job(self, token, evicted_signs, real, ev_vals, ev_acc,
+                   tctx):
+        # joins the evicting step's trace where one was propagated
+        kw = {"ctx": tctx} if tctx is not None else {}
+        with tracing.span("cache/writeback", **kw) as sp:
+            vals = np.asarray(ev_vals)  # d2h here, off the training thread
+            acc = np.asarray(ev_acc)
+            todo_signs, todo_vecs = [], []
+            for i in real:
+                sign = int(evicted_signs[i])
+                # token-matched PEEK (no removal yet): absent or different
+                # token => the miss path reclaimed the row (the cache copy is
+                # authoritative again) or a newer eviction owns the sign —
+                # either way writing our older value would clobber fresher
+                # state, so skip.
+                if self.victims.peek_if(sign, token) is None:
+                    continue
+                todo_signs.append(sign)
+                todo_vecs.append(np.concatenate([vals[i], acc[i]]))
+            sp.tag(rows=len(todo_signs))
+            if todo_signs:
+                self.worker.set_rows(
+                    np.asarray(todo_signs, np.uint64),
+                    np.stack(todo_vecs), self.dim)
+                self._m_writebacks.inc(len(todo_signs))
+            # remove only AFTER the PS write landed: a miss racing the write
+            # must keep finding the pending entry, otherwise it would read
+            # the stale pre-write PS row. A miss that took the entry mid-
+            # write is also fine — the PS got the same value, and the cache
+            # copy stays authoritative.
+            for sign in todo_signs:
+                self.victims.take_if(sign, token)
 
     def flush_all(self) -> int:
         """Write every cached row (+ the victim buffer) back to the PS.

@@ -20,6 +20,10 @@ middleware's dedup+sum, and untouched rows keep their accumulator
 Host-side mapping/eviction policy lives in
 persia_tpu/worker/device_cache.py; the orchestration tying both to
 TrainCtx is persia_tpu/parallel/cached_engine.py.
+
+The steps' operations carry the scopes ``cache_import``, ``cache_gather``,
+``tower``, ``dense_update`` and ``row_adagrad`` in their metadata, for a
+trace to group them by.
 """
 
 from typing import Callable, Sequence
@@ -82,10 +86,11 @@ def _constrain_rows(mesh, cache_vals, cache_acc):
 def _import_cold(cache_vals, cache_acc, cold_idx, cold_vals, cold_acc):
     """Read the rows being evicted BEFORE their slots are reused, then
     write-allocate this batch's miss rows (pads target the dummy row)."""
-    evicted_vals = cache_vals[cold_idx]
-    evicted_acc = cache_acc[cold_idx]
-    cache_vals = cache_vals.at[cold_idx].set(cold_vals)
-    cache_acc = cache_acc.at[cold_idx].set(cold_acc)
+    with jax.named_scope("cache_import"):
+        evicted_vals = cache_vals[cold_idx]
+        evicted_acc = cache_acc[cold_idx]
+        cache_vals = cache_vals.at[cold_idx].set(cold_vals)
+        cache_acc = cache_acc.at[cold_idx].set(cold_acc)
     return cache_vals, cache_acc, evicted_vals, evicted_acc
 
 
@@ -100,13 +105,14 @@ def _forward_backward(model, loss_fn, state, non_id_tensors, label,
         variables = {"params": params}
         if state.batch_stats:
             variables["batch_stats"] = state.batch_stats
-        emb_values = emb_values_of(gathered)
-        emb_inputs = _rebuild_embedding_inputs(
-            emb_values, [None] * len(emb_values))
-        out = model.apply(
-            variables, non_id_tensors, emb_inputs, train=True,
-            mutable=["batch_stats"] if state.batch_stats else [],
-        )
+        with jax.named_scope("tower"):
+            emb_values = emb_values_of(gathered)
+            emb_inputs = _rebuild_embedding_inputs(
+                emb_values, [None] * len(emb_values))
+            out = model.apply(
+                variables, non_id_tensors, emb_inputs, train=True,
+                mutable=["batch_stats"] if state.batch_stats else [],
+            )
         pred, mutated = out if isinstance(out, tuple) else (out, {})
         return loss_fn(pred, label), (pred, mutated)
 
@@ -116,9 +122,10 @@ def _forward_backward(model, loss_fn, state, non_id_tensors, label,
 
 
 def _dense_update(optimizer, state, param_grads, mutated):
-    updates, new_opt_state = optimizer.update(
-        param_grads, state.opt_state, state.params)
-    new_params = optax.apply_updates(state.params, updates)
+    with jax.named_scope("dense_update"):
+        updates, new_opt_state = optimizer.update(
+            param_grads, state.opt_state, state.params)
+        new_params = optax.apply_updates(state.params, updates)
     return TrainState(
         params=new_params,
         batch_stats=mutated.get("batch_stats", state.batch_stats),
@@ -143,18 +150,19 @@ def _sparse_adagrad_update(cache_vals, cache_acc, unique_slots, inverse,
     (ps/optim.py apply_weight_bound; reference persia-simd
     lib.rs:231-251) — mirror of the PS math, or cached and uncached
     training diverge."""
-    valid = (unique_slots != dummy)[:, None]
-    gsum_u = jnp.zeros((inverse.shape[0], dim), jnp.float32).at[
-        inverse].add(pos_grad)
-    acc_u = cache_acc[unique_slots]
-    new_val_u = (cache_vals[unique_slots]
-                 - lr * gsum_u * jax.lax.rsqrt(acc_u + eps))
-    if weight_bound > 0:
-        new_val_u = jnp.clip(new_val_u, -weight_bound, weight_bound)
-    new_acc_u = jnp.where(
-        valid, acc_u * g_square_momentum + gsum_u * gsum_u, acc_u)
-    cache_vals = cache_vals.at[unique_slots].set(new_val_u)
-    cache_acc = cache_acc.at[unique_slots].set(new_acc_u)
+    with jax.named_scope("row_adagrad"):
+        valid = (unique_slots != dummy)[:, None]
+        gsum_u = jnp.zeros((inverse.shape[0], dim), jnp.float32).at[
+            inverse].add(pos_grad)
+        acc_u = cache_acc[unique_slots]
+        new_val_u = (cache_vals[unique_slots]
+                     - lr * gsum_u * jax.lax.rsqrt(acc_u + eps))
+        if weight_bound > 0:
+            new_val_u = jnp.clip(new_val_u, -weight_bound, weight_bound)
+        new_acc_u = jnp.where(
+            valid, acc_u * g_square_momentum + gsum_u * gsum_u, acc_u)
+        cache_vals = cache_vals.at[unique_slots].set(new_val_u)
+        cache_acc = cache_acc.at[unique_slots].set(new_acc_u)
     return cache_vals, cache_acc
 
 
@@ -201,7 +209,8 @@ def make_cached_train_step(
         cache_vals, cache_acc, evicted_vals, evicted_acc = _import_cold(
             cache_vals, cache_acc, cold_idx, cold_vals, cold_acc)
 
-        gathered = cache_vals[slot_idx]  # (B, S, D)
+        with jax.named_scope("cache_gather"):
+            gathered = cache_vals[slot_idx]  # (B, S, D)
         (loss, (pred, mutated)), (param_grads, emb_grad) = \
             _forward_backward(
                 model, loss_fn, state, non_id_tensors, label, gathered,
@@ -270,10 +279,12 @@ def make_cached_bag_train_step(
             cache_vals, cache_acc, cold_idx, cold_vals, cold_acc)
 
         batch = label.shape[0]
-        rows = cache_vals[flat_slot_idx]                   # (Lpad, D)
-        bags = jnp.zeros((batch * num_slots + 1, dim),
-                         jnp.float32).at[seg].add(rows)
-        gathered = bags[:batch * num_slots].reshape(batch, num_slots, dim)
+        with jax.named_scope("cache_gather"):
+            rows = cache_vals[flat_slot_idx]               # (Lpad, D)
+            bags = jnp.zeros((batch * num_slots + 1, dim),
+                             jnp.float32).at[seg].add(rows)
+            gathered = bags[:batch * num_slots].reshape(
+                batch, num_slots, dim)
 
         def emb_values_of(g):
             scaled = g * scale[:, :, None]

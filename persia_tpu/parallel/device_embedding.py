@@ -45,13 +45,16 @@ class DeviceEmbeddingBag(nn.Module):
             (self.vocab_size, self.dim),
             jnp.float32,
         )
-        gathered = jnp.take(table, hashed_ids, axis=0)  # (bs, sfs, dim)
-        gathered = gathered * mask[..., None].astype(gathered.dtype)
-        pooled = gathered.sum(axis=1)
-        if self.pooling == "mean":
-            denom = jnp.maximum(mask.sum(axis=1, keepdims=True), 1)
-            pooled = pooled / denom
-        return pooled.astype(self.compute_dtype)
+        # the name a trace knows table work by; the backward scatter
+        # carries it too, under transpose(jvp(...))
+        with jax.named_scope("tables_gather"):
+            gathered = jnp.take(table, hashed_ids, axis=0)  # (bs, sfs, dim)
+            gathered = gathered * mask[..., None].astype(gathered.dtype)
+            pooled = gathered.sum(axis=1)
+            if self.pooling == "mean":
+                denom = jnp.maximum(mask.sum(axis=1, keepdims=True), 1)
+                pooled = pooled / denom
+            return pooled.astype(self.compute_dtype)
 
 
 class DeviceEmbeddingCollection(nn.Module):

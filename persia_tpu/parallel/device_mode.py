@@ -42,7 +42,8 @@ class DeviceModeModel(nn.Module):
         )
 
         embs = DeviceEmbeddingCollection(slot_specs=self.slot_specs)(id_tensors)
-        return self.tower(non_id_tensors, embs, train=train)
+        with jax.named_scope("tower"):
+            return self.tower(non_id_tensors, embs, train=train)
 
 
 def make_device_mode_trainer(
@@ -60,6 +61,8 @@ def make_device_mode_trainer(
     ``step(params, opt_state, non_id, ids, label) ->
     (params, opt_state, loss)``. Parameter shardings come from the
     modules' ``with_partitioning`` metadata; everything else replicates.
+    The step's operations carry the scopes ``tables_gather``, ``tower``
+    and ``optimizer`` in their metadata, for a trace to group them by.
     """
     with mesh:
         variables = model.init(jax.random.key(seed), sample_non_id,
@@ -85,8 +88,9 @@ def make_device_mode_trainer(
             return loss_fn(pred, label)
 
         loss, grads = jax.value_and_grad(compute_loss)(params)
-        updates, opt_state2 = optimizer.update(grads, opt_state, params)
-        params2 = optax.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state2 = optimizer.update(grads, opt_state, params)
+            params2 = optax.apply_updates(params, updates)
         return params2, opt_state2, loss
 
     return params, opt_state, jax.jit(step, donate_argnums=(0, 1))

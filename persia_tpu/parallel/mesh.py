@@ -16,6 +16,8 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from persia_tpu import tracing
+
 DATA_AXIS = "data"
 MODEL_AXIS = "model"
 
@@ -61,6 +63,9 @@ def shard_batch_pytree(tree, mesh: Mesh):
     replicated instead — notably raw-slot distinct-embedding tensors of
     capacity batch*sample_fixed_size+1, which are indexed globally and
     must be visible to every data shard. Scalars are replicated.
+
+    Runs under a ``trainer/place_batch`` span: the host-to-device part
+    of a step's host time.
     """
     bsh = batch_sharding(mesh)
     rep = replicated(mesh)
@@ -71,4 +76,10 @@ def shard_batch_pytree(tree, mesh: Mesh):
             return jax.device_put(x, bsh)
         return jax.device_put(x, rep)
 
-    return jax.tree_util.tree_map(place, tree)
+    with tracing.span("trainer/place_batch") as sp:
+        placed = jax.tree_util.tree_map(place, tree)
+        if sp.ctx is not None:  # recording: count what was placed
+            leaves = jax.tree_util.tree_leaves(placed)
+            sp.tag(leaves=len(leaves),
+                   bytes=sum(x.nbytes for x in leaves))
+    return placed

@@ -23,13 +23,18 @@ Tracing is OFF by default (``PERSIA_TRACING=1`` or
 returns a shared no-op context manager, and the RPC client never probes
 ``__trace__`` — the disabled wire is byte-identical to the untraced one.
 
+Spans also record while a ``jax.profiler`` session is open in this
+process (:func:`profiler_live`), whoever opened it. Such a span is a
+``jax.profiler.TraceAnnotation`` too, so it lies in the profiler's
+xplane on the device operations' clock, and is marked ``profiled`` in
+the ring. That switch is local to the process: the RPC envelope follows
+``PERSIA_TRACING`` alone.
+
 :class:`StepProfiler` is the device-side companion: opt-in
-``jax.profiler`` start/stop keyed to a trainer step window, so a TPU
-device trace can be captured aligned with the host spans of the same
-steps.
+``jax.profiler`` start/stop keyed to a trainer step window; the spans of
+those steps land in the same xplane as the device operations.
 """
 
-import json
 import os
 import struct
 import sys
@@ -64,6 +69,26 @@ def tracing_enabled() -> bool:
     return _enabled
 
 
+# jax.profiler.TraceAnnotation, cached at first sight. Never imported
+# from here: the PS and worker children import no JAX, and a process
+# without it has no profiler session to ride.
+_annotation = None
+
+
+def profiler_live() -> bool:
+    """True while a ``jax.profiler`` session is open in this process,
+    whoever opened it (``TraceAnnotation.is_enabled`` is the profiler's
+    own switch for host events). False, without touching JAX, in a
+    process that has not imported it."""
+    global _annotation
+    if _annotation is None:
+        _annotation = getattr(sys.modules.get("jax.profiler"),
+                              "TraceAnnotation", None)
+        if _annotation is None:
+            return False
+    return _annotation.is_enabled()
+
+
 def enable_tracing(on: bool = True):
     """Flip span recording process-wide. Turn on BEFORE dialing RPC
     clients that should propagate context: the ``__trace__`` capability
@@ -94,7 +119,9 @@ def _rand64() -> int:
 def current_context() -> Optional[Tuple[int, int]]:
     """(trace_id, span_id) of the active span on THIS thread, or None.
     This is what the RPC client injects into the envelope and what
-    fan-out code captures before handing work to a pool thread."""
+    fan-out code captures before handing work to a pool thread. It
+    follows ``PERSIA_TRACING`` alone: a profiler session propagates
+    nothing."""
     if not _enabled:
         return None
     return getattr(_tls, "ctx", None)
@@ -132,15 +159,18 @@ class Span:
 
     Wall-clock start (``time.time_ns``) makes spans from different
     processes line up on one timeline; the duration is measured with
-    the monotonic perf counter so it never jumps with clock slew."""
+    the monotonic perf counter so it never jumps with clock slew. A
+    ``profiled`` span was opened under a live profiler session and is a
+    ``TraceAnnotation`` of the same name there, its tags the event's
+    stats."""
 
     __slots__ = ("name", "service", "trace_id", "span_id", "parent_id",
-                 "start_ns", "dur_ns", "tags", "pid", "tid", "_prev",
-                 "_t0")
+                 "start_ns", "dur_ns", "tags", "pid", "tid", "profiled",
+                 "_prev", "_t0", "_anno")
 
     def __init__(self, name: str, trace_id: int, span_id: int,
                  parent_id: int, tags: Optional[Dict] = None,
-                 service: Optional[str] = None):
+                 service: Optional[str] = None, profiled: bool = False):
         self.name = name
         self.service = service if service is not None else _service[0]
         self.trace_id = trace_id
@@ -151,6 +181,8 @@ class Span:
         self.tid = threading.current_thread().name
         self.start_ns = 0
         self.dur_ns = 0
+        self.profiled = profiled
+        self._anno = None
 
     @property
     def ctx(self) -> Tuple[int, int]:
@@ -161,6 +193,8 @@ class Span:
         if self.tags is None:
             self.tags = {}
         self.tags.update(kw)
+        if self._anno is not None:
+            self._anno.set_metadata(**kw)
         return self
 
     def __enter__(self):
@@ -168,13 +202,19 @@ class Span:
         _tls.ctx = (self.trace_id, self.span_id)
         self.start_ns = time.time_ns()
         self._t0 = time.perf_counter_ns()
+        if self.profiled:
+            self._anno = _annotation(self.name, **(self.tags or {}))
+            self._anno.__enter__()
         return self
 
     def __exit__(self, exc_type, exc_val, exc_tb):
-        self.dur_ns = time.perf_counter_ns() - self._t0
-        _tls.ctx = self._prev
         if exc_type is not None:
             self.tag(error=f"{exc_type.__name__}: {exc_val}")
+        if self._anno is not None:
+            self._anno.__exit__(exc_type, exc_val, exc_tb)
+            self._anno = None
+        self.dur_ns = time.perf_counter_ns() - self._t0
+        _tls.ctx = self._prev
         _collector.add(self)
         return False
 
@@ -192,6 +232,7 @@ class Span:
             "pid": self.pid,
             "tid": self.tid,
             "tags": self.tags,
+            "profiled": self.profiled,
         }
 
 
@@ -208,10 +249,12 @@ def span(name: str, ctx=_UNSET, root: bool = False, service: Optional[str] = Non
       stay untraced instead of spawning orphan roots.
     - ``root=True``: force a fresh trace id even under an active span
       (step boundaries).
+
+    Records when ``PERSIA_TRACING`` is on or a profiler session is live
+    (:func:`profiler_live`).
     """
-    if not _enabled:
-        return _NULL_SPAN
-    if ctx is None:
+    profiled = profiler_live()
+    if not (_enabled or profiled) or ctx is None:
         return _NULL_SPAN
     if root or ctx is _UNSET:
         cur = None if root else getattr(_tls, "ctx", None)
@@ -222,7 +265,7 @@ def span(name: str, ctx=_UNSET, root: bool = False, service: Optional[str] = Non
     else:
         trace_id, parent = ctx
     return Span(name, trace_id, _rand64(), parent, tags or None,
-                service=service)
+                service=service, profiled=profiled)
 
 
 # --- collector + export ---------------------------------------------------
@@ -313,12 +356,6 @@ def chrome_trace(spans=None) -> Dict:
             "args": args,
         })
     return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-
-def export_chrome_trace(path: str, spans=None) -> str:
-    with open(path, "w") as f:
-        json.dump(chrome_trace(spans), f)
-    return path
 
 
 # --- multi-process merge (library form of the bench's trace scrape) -------

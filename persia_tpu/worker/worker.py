@@ -1052,8 +1052,10 @@ class EmbeddingWorker:
             return list(self._fanout.map(
                 lambda rs: fetch_one(*rs), zip(replicas, groups)))
 
-        for sel, (found, vecs) in zip(groups,
-                                      self._with_ps_retry(fetch_all)):
+        with self._t_rpc.timer(), tracing.span(
+                "worker/rows_with_state", n=n, replicas=len(groups)):
+            results = self._with_ps_retry(fetch_all)
+        for sel, (found, vecs) in zip(groups, results):
             hit = np.nonzero(found)[0]
             vals[sel[hit]] = vecs[hit, :dim]
             state[sel[hit]] = vecs[hit, dim:]

@@ -468,8 +468,10 @@ def test_chrome_trace_export_validity(traced, tmp_path):
     with tracing.span("outer", root=True):
         with tracing.span("inner", k="v"):
             pass
-    path = tracing.export_chrome_trace(str(tmp_path / "trace.json"))
-    doc = json.loads(open(path).read())
+    path = tmp_path / "trace.json"
+    with open(path, "w") as f:
+        json.dump(tracing.chrome_trace(), f)
+    doc = json.loads(path.read_text())
     events = doc["traceEvents"]
     xs = [e for e in events if e["ph"] == "X"]
     assert {e["name"] for e in xs} == {"outer", "inner"}
