@@ -6,9 +6,23 @@ single jitted train step — dense DP allreduce and embedding-shard
 collectives are both XLA-inserted over ICI. It is the TPU-first
 alternative to the CPU parameter-server path and the configuration the
 multi-chip dry run exercises.
+
+The step updates only the rows a batch touches: it gathers them,
+differentiates with respect to them (not the tables), sums the gradients
+of equal ids, and runs the caller's optimizer over those rows and the
+same rows of its table-shaped state, written back in place
+(``device_embedding.update_touched_rows``); the tower's leaves go through
+the same ``optimizer.update`` call whole. That equals the whole-table
+step only for an optimizer that leaves a row without gradient alone
+(Adagrad, plain SGD). ``make_device_mode_trainer`` tries the optimizer it
+is given on a tiny table (``device_embedding.rows_suffice``) and, where
+the rows do not suffice (Adam, momentum, weight decay) or the model has
+no ``table_rows``, keeps the dense step: ``jax.value_and_grad`` over the
+whole tree and ``optimizer.update`` over every table. The gauges
+``device_mode_row_update_tables`` and ``device_mode_dense_update_tables``
+say which one a build took.
 """
 
-from functools import partial
 from typing import Any, Callable, Dict, Sequence, Tuple
 
 import jax
@@ -19,31 +33,48 @@ from flax import linen as nn
 from flax.core import meta
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from persia_tpu.models.dlrm import DLRM
-from persia_tpu.parallel.mesh import batch_sharding, make_mesh, replicated
+from persia_tpu import metrics, tracing
+from persia_tpu.parallel.device_embedding import (
+    DeviceEmbeddingCollection,
+    distinct_rows,
+    rows_suffice,
+    table_rows,
+    update_touched_rows,
+)
+from persia_tpu.parallel.mesh import replicated
 from persia_tpu.parallel.train import bce_loss
+
+# the collection's place in DeviceModeModel's parameter tree
+TABLES = "DeviceEmbeddingCollection_0"
 
 
 class DeviceModeModel(nn.Module):
     """Dense tower + device embedding tables as one module.
 
     ``slot_specs``: sequence of (name, vocab_size, dim) for the hashed
-    HBM tables; ``tower``: a model-zoo module instance.
+    HBM tables; ``tower``: a model-zoo module instance. ``rows``, if
+    given, mirrors the tables' place in the parameters
+    (:meth:`table_rows`) and holds their values already gathered.
     """
 
     slot_specs: Sequence[Any]
     tower: nn.Module
+    pooling: str = "sum"
 
     @nn.compact
     def __call__(self, non_id_tensors, id_tensors: Dict[str, jnp.ndarray],
-                 train: bool = False):
-        from persia_tpu.parallel.device_embedding import (
-            DeviceEmbeddingCollection,
-        )
-
-        embs = DeviceEmbeddingCollection(slot_specs=self.slot_specs)(id_tensors)
+                 train: bool = False, rows=None):
+        embs = DeviceEmbeddingCollection(
+            slot_specs=self.slot_specs, pooling=self.pooling, name=TABLES,
+        )(id_tensors, None if rows is None else rows[TABLES])
         with jax.named_scope("tower"):
             return self.tower(non_id_tensors, embs, train=train)
+
+    @nn.nowrap
+    def table_rows(self, id_tensors):
+        """The row of its table each id reads, as a tree that mirrors the
+        tables' leaves of this module's parameters."""
+        return {TABLES: table_rows(self.slot_specs, id_tensors)}
 
 
 def make_device_mode_trainer(
@@ -61,8 +92,11 @@ def make_device_mode_trainer(
     ``step(params, opt_state, non_id, ids, label) ->
     (params, opt_state, loss)``. Parameter shardings come from the
     modules' ``with_partitioning`` metadata; everything else replicates.
+    ``opt_state`` is ``optimizer.init(params)`` whichever step is built.
     The step's operations carry the scopes ``tables_gather``, ``tower``
-    and ``optimizer`` in their metadata, for a trace to group them by.
+    and ``optimizer`` in their metadata, for a trace to group them by;
+    the touched-rows step's table work inside ``optimizer`` carries
+    ``row_update`` besides.
     """
     with mesh:
         variables = model.init(jax.random.key(seed), sample_non_id,
@@ -82,18 +116,99 @@ def make_device_mode_trainer(
     params = jax.tree_util.tree_map(jax.device_put, params, shardings)
     opt_state = optimizer.init(params)
 
+    with tracing.span("trainer/build_device_step") as built:
+        flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+        paths = [path for path, _ in flat]
+        tables, in_rows = [], None
+        if hasattr(model, "table_rows"):
+            tables, in_rows = jax.tree_util.tree_flatten_with_path(
+                jax.eval_shape(model.table_rows, sample_ids))
+        by_row = bool(tables) and rows_suffice(optimizer)
+        counts = {"row_update_tables": len(tables) if by_row else 0,
+                  "dense_update_tables": 0 if by_row else len(tables)}
+        built.tag(**counts)
+        for name, n in counts.items():
+            metrics.default_registry().gauge(f"device_mode_{name}").set(n)
+    if not by_row:
+        def step(params, opt_state, non_id, ids, label):
+            def compute_loss(params):
+                pred = model.apply({"params": params}, non_id, ids,
+                                   train=True)
+                return loss_fn(pred, label)
+
+            loss, grads = jax.value_and_grad(compute_loss)(params)
+            with jax.named_scope("optimizer"):
+                updates, opt_state2 = optimizer.update(grads, opt_state,
+                                                       params)
+                params2 = optax.apply_updates(params, updates)
+            return params2, opt_state2, loss
+
+        return params, opt_state, jax.jit(step, donate_argnums=(0, 1))
+
+    # where in the flat parameters the tables are, and the other leaves
+    at_tables = [paths.index(path) for path, _ in tables]
+    at_dense = [i for i in range(len(paths)) if i not in at_tables]
+
     def step(params, opt_state, non_id, ids, label):
-        def compute_loss(params):
-            pred = model.apply({"params": params}, non_id, ids, train=True)
+        leaves = treedef.flatten_up_to(params)
+        index = in_rows.flatten_up_to(model.table_rows(ids))
+        with jax.named_scope("tables_gather"):
+            rows = [jnp.take(leaves[i], at, axis=0)
+                    for i, at in zip(at_tables, index)]
+
+        def tree_of(dense, at_the_tables):
+            full = list(leaves)     # the tables ride along where unnamed
+            for i, leaf in zip(at_dense + at_tables,
+                               list(dense) + list(at_the_tables)):
+                full[i] = leaf
+            return treedef.unflatten(full)
+
+        def compute_loss(dense, rows):
+            # the model reads the given rows, not the tables
+            pred = model.apply({"params": tree_of(dense, [])}, non_id, ids,
+                               train=True, rows=in_rows.unflatten(rows))
             return loss_fn(pred, label)
 
-        loss, grads = jax.value_and_grad(compute_loss)(params)
+        loss, (grads, row_grads) = jax.value_and_grad(
+            compute_loss, argnums=(0, 1))([leaves[i] for i in at_dense], rows)
         with jax.named_scope("optimizer"):
-            updates, opt_state2 = optimizer.update(grads, opt_state, params)
-            params2 = optax.apply_updates(params, updates)
+            with jax.named_scope("row_update"):
+                touched, summed = _distinct_and_summed(
+                    index, row_grads,
+                    [leaves[i].shape[0] for i in at_tables])
+            # every shard of a table sees all of the batch's rows and
+            # writes its own: left to itself on a (2, 2) mesh the
+            # partitioner scatters by data shard and all-reduces whole
+            # tables
+            touched, summed = jax.lax.with_sharding_constraint(
+                (touched, summed), replicated(mesh))
+            params2, opt_state2 = update_touched_rows(
+                optimizer, params, opt_state, tree_of(grads, summed),
+                tree_of([False] * len(at_dense), touched))
         return params2, opt_state2, loss
 
     return params, opt_state, jax.jit(step, donate_argnums=(0, 1))
+
+
+def _distinct_and_summed(index, row_grads, row_counts):
+    """Each table's distinct rows (``distinct_rows``' ``touched``) and one
+    summed gradient for each: the per-occurrence gradients of equal ids
+    add up, as the dense scatter-add adds them, before an optimizer
+    squares anything. Tables that read equally many ids share one batched
+    sort."""
+    touched, summed = [None] * len(index), [None] * len(index)
+    by_size = {}
+    for t, at in enumerate(index):
+        by_size.setdefault(at.size, []).append(t)
+    for size, group in by_size.items():
+        distinct, slot = distinct_rows(
+            jnp.stack([index[t].reshape(size) for t in group]),
+            jnp.asarray([row_counts[t] for t in group], jnp.int32))
+        for k, t in enumerate(group):
+            touched[t] = distinct[k]
+            summed[t] = jax.ops.segment_sum(
+                row_grads[t].reshape(size, -1), slot[k], num_segments=size)
+    return touched, summed
 
 
 def criteo_like_specs(num_slots: int = 26, vocab: int = 1 << 16,

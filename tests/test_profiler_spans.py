@@ -375,13 +375,21 @@ def test_device_mode_step_carries_its_scopes():
     params, opt_state, step = make_device_mode_trainer(
         DeviceModeModel(slot_specs=specs, tower=DLRM(embedding_dim=8)),
         optax.adagrad(0.05), mesh, non_id, ids)
-    names = ("tables_gather", "tower", "optimizer")
+    names = ("tables_gather", "tower", "optimizer", "row_update")
     with mesh:
         lowered = step.lower(params, opt_state, non_id, ids, label)
     assert _scopes_in(lowered, names) == set(names)
-    # the backward scatter carries the gather's name under transpose(...)
-    assert any("transpose(" in line and "/tables_gather/" in line
-               for line in lowered.as_text(debug_info=True).splitlines())
+    lines = lowered.as_text(debug_info=True).splitlines()
+    # the backward under the gather's name is the pooling's alone: the
+    # step differentiates with respect to the gathered rows, so no
+    # scatter into a table is anybody's transpose ...
+    backward = [line for line in lines
+                if "transpose(" in line and "/tables_gather/" in line]
+    assert backward and not any("scatter" in line for line in backward)
+    # ... and the touched rows' own work sits inside the optimizer's
+    assert any("/optimizer/row_update/" in line for line in lines)
+    assert not any("/row_update/" in line and "/optimizer/" not in line
+                   for line in lines)
 
 
 @pytest.mark.parametrize("bags", [False, True], ids=["single_id", "bags"])

@@ -4,8 +4,9 @@ Questions this answers on the real chip:
   1. per-step time with a hard sync every step (no async pipelining
      flattering the loop timing) vs the bench's end-sync loop;
   2. vocab scaling: if step time grows ~linearly with vocab the
-     embedding update is dense (scatter -> dense adagrad); if ~flat,
-     XLA fused it into a sparse row-wise update;
+     embedding update is dense (scatter -> dense adagrad); ~flat is what
+     the touched-rows step (device_mode.py, taken for Adagrad and plain
+     SGD) should read;
   3. fixed vs fresh ids per step (rules out cross-dispatch caching).
 """
 
