@@ -9,16 +9,22 @@ Which operations those are is ``trace_reduce.on_tables``'s rule, written
 after looking at a trace by hand (PERF.md, Findings): an operation counts
 when its HLO text has an operand or a result of a table's shape, which
 the gather, the gradient's scatter-add and the row optimizer have and
-nothing of the tower has. A cell whose placement names no table shapes
-has nothing to read here.
+nothing of the tower has. A cell whose placement names no table shapes,
+or no rows that a batch touches, has nothing to read here. The bytes are
+a DLRM configuration's (``embedding_dim``, ``table_cardinalities``,
+``compute_dtype``), which is why this metric lists its cells.
 """
+
+import costs
 
 
 def read(r):
-    if r.trace is None or r.peaks is None or not r.trace["steps"]:
+    if (r.trace is None or r.peaks is None or not r.trace["steps"]
+            or r.unique_rows is None):
         return None
     per_step = r.trace["table_s"] / r.trace["steps"]
     if per_step <= 0:
         return None
-    least = r.embed_min_bytes / r.peaks["hbm_bytes_per_s"]
+    least = (costs.embed_min_bytes(r.unique_rows, r.batch, r.config)
+             / r.peaks["hbm_bytes_per_s"])
     return 100.0 * least / per_step

@@ -5,16 +5,19 @@
         --seconds <s> --trace <0|1>
 
 A new process loads the cell's files (``BENCHMARK.json``, ``cells/``,
-``configs/``, ``mixes/``, ``placements/``, ``layer_metrics/``), builds the
-program through the cell's placement with weights made from the seed,
-drives the first steps that ``correct`` compares, warms up, measures for
-``--seconds``, frees the program's state, runs the plain reference over
-the same first steps, and prints one JSON object as its last line. It
-needs a TPU with as many chips as the cell asks for: otherwise it exits 2
-and prints no result. ``--rehearse`` is the builder's CPU rehearsal: the
-cell's ``rehearsal`` sizes, any platform, and no device metric printed.
+``configs/``, ``mixes/``, ``generators/``, ``placements/``,
+``layer_metrics/``), builds the program through the cell's placement
+with weights made from the seed, drives the first steps that ``correct``
+compares, warms up, measures for ``--seconds``, frees the program's
+state, runs the plain reference over the same first steps, and prints
+one JSON object as its last line. It needs a TPU with as many chips as
+the cell asks for: otherwise it exits 2 and prints no result.
+``--rehearse`` is the builder's CPU rehearsal: the cell's ``rehearsal``
+sizes, any platform, and no device metric printed.
 
-No cell, configuration, mix or metric is named in this file.
+No cell, configuration, mix or metric is named in this file, and no key
+of a configuration is read in it: what a configuration holds is between
+its generator, its placement and its readers.
 """
 
 import argparse
@@ -105,8 +108,7 @@ def load_env(workload, seed, rehearse, root):
         max_ind_range=sizes.get("max_ind_range"),
         limits=cell["rehearsal_limits" if rehearse else "limits"],
         mark=Marks())
-    env.stream = traffic.Stream(env.mix, config["table_cardinalities"],
-                                config["num_dense"], env.batch, env.seed)
+    env.stream = traffic.stream_for(env.mix, config, env.batch, env.seed)
     env.placement = load_module(
         man.path("placements", f"{cell['placement']}.py"))
     return env
@@ -270,6 +272,18 @@ def memory_peak(devices):
     return peaks[i], limits[i]
 
 
+def mean_unique_rows(env, runner, batches=3):
+    """Distinct table rows a batch touches, a mean over the first
+    batches; None where the placement has no tables to name rows of."""
+    row_ids = getattr(runner, "row_ids", None)
+    if row_ids is None:
+        return None
+    rows = [row_ids(env.stream.batch(i)) for i in range(batches)]
+    if any(r is None for r in rows):
+        return None
+    return statistics.mean(costs.unique_rows(r) for r in rows)
+
+
 def verdict(env, prog, window_losses):
     """Runs the plain reference over the first steps' batches and judges
     the program's side against it."""
@@ -358,9 +372,7 @@ def run(args, root, out=sys.stdout, wrap_runner=None, keep_trace=False):
         compiles = meter.compiles - compiles_before
         after = runner.counters()
         peak, limit = memory_peak(env.devices)
-        unique_rows = statistics.mean(
-            costs.unique_rows(runner.row_ids(env.stream.batch(i)))
-            for i in range(3)) if args.trace else None
+        unique_rows = mean_unique_rows(env, runner) if args.trace else None
     finally:
         pre.close()
         runner.close()
@@ -412,8 +424,7 @@ def run(args, root, out=sys.stdout, wrap_runner=None, keep_trace=False):
             compiles_in_window=compiles, late=win.late,
             counters={k: after[k] - before.get(k, 0) for k in after},
             trace=reduced, memory_peak=peak, memory_limit=limit,
-            embed_min_bytes=costs.embed_min_bytes(
-                unique_rows, env.batch, env.config),
+            unique_rows=unique_rows,
             peaks=(None if args.rehearse else costs.peaks_for(
                 env.manifest.bench_dir, device["kind"])))
         result["metrics"] = read_layer_metrics(env, reading)

@@ -10,7 +10,10 @@ tests rehearse it through a copy of the manifest with those put back."""
 import io
 import json
 import os
+import re
 import shutil
+import subprocess
+import sys
 
 import pytest
 
@@ -132,18 +135,18 @@ def _unchanged(runner):
 
     if hasattr(runner, "_step"):     # device: the jitted step itself
         runner._step = lambda p, o, *_: (p, o, jnp.float32(0.69))
-    else:                            # cached: nothing reaches the ctx
+    else:           # cached, or a later placement: nothing reaches it
         runner.step = lambda feed: jnp.float32(0.69)
 
 
 def _half_batch(runner):
-    """Half of the batch left out, the mean taken over the rest."""
+    """Half of the batch left out, the mean taken over the rest: every
+    array of the batch cut along its first axis, whatever its name."""
     whole = runner.convert
 
     def convert(b):
-        h = len(b["label"]) // 2
-        return whole(dict(b, ids=b["ids"][:h], dense=b["dense"][:h],
-                          label=b["label"][:h]))
+        return whole({k: v[:len(v) // 2] if hasattr(v, "shape") else v
+                      for k, v in b.items()})
 
     runner.convert = convert
 
@@ -252,4 +255,56 @@ def test_a_new_cell_is_new_files_only(tmp_path):
     (root / "BENCHMARK.json").write_text(json.dumps(doc))
     line = rehearse("dlrm-extra.device-hot", 1, root=str(root))
     assert line["correct"] is True and "late_batches" in line["read"]
+    assert all(p.read_bytes() == b for p, b in before.items())
+
+
+DLRM_KEYS = {"embedding_dim", "num_dense", "bottom_mlp", "top_mlp",
+             "table_cardinalities", "max_ind_range", "dense_optimizer",
+             "compute_dtype"}
+
+
+def test_a_cell_of_another_family_is_new_files_only(tmp_path):
+    """A later PR's cell of another tower family (``tests/fixtures/
+    other_family``: a generator kind, a configuration with none of DLRM's
+    keys, a placement with its own reference, two per-layer metrics)
+    laid as new files over a copy that has ``tests/`` too: the copy's own
+    tests that parametrise over cells pass for it, and no file that was
+    there changed."""
+    root = tmp_path / "repo"
+    bench = root / "benchmarks" / "chip"
+    shutil.copytree(BENCH_DIR, bench,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    for tree in ("persia_tpu", "native"):
+        os.symlink(os.path.join(ROOT, tree), root / tree)
+    before = {p: p.read_bytes() for p in bench.rglob("*") if p.is_file()}
+    fixture = bench / "tests" / "fixtures" / "other_family"
+    added = []
+    for src in fixture.rglob("*"):
+        rel = src.relative_to(fixture)
+        if src.is_file() and len(rel.parts) > 1:
+            assert not (bench / rel).exists(), rel
+            (bench / rel).parent.mkdir(exist_ok=True)
+            shutil.copy(src, bench / rel)
+            added.append(str(rel))
+    assert {os.path.dirname(a) for a in added} == {
+        "generators", "mixes", "configs", "placements", "cells",
+        "layer_metrics"}
+    entries = json.loads((fixture / "manifest_entries.json").read_text())
+    (cell,) = [w["name"] for w in entries["workloads"]]
+    for c in entries["configs"]:
+        assert not DLRM_KEYS & set(json.loads((root / c["file"]).read_text()))
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        doc = json.load(f)
+    for group, new in entries.items():
+        doc[group] += new
+    (root / "BENCHMARK.json").write_text(json.dumps(doc))
+    done = subprocess.run(
+        [sys.executable, "-m", "pytest", str(bench / "tests"), "-q",
+         "-p", "no:cacheprovider", "-k", f"{cell} or manifest_keeps"],
+        cwd=root, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=600)
+    tail = done.stdout[-3000:] + done.stderr[-1000:]
+    assert done.returncode == 0, tail
+    # traced and untraced rehearsal, two faults, calibrate, the manifest
+    assert re.search(r"\b6 passed", done.stdout), tail
     assert all(p.read_bytes() == b for p, b in before.items())

@@ -1,11 +1,14 @@
-"""The one general traffic generator: a mix file of parameters in, a
-seed-deterministic stream of DLRM batches out.
+"""Traffic: a mix file of parameters in, a seed-deterministic stream of
+batches out. A mix names its generator by ``kind``: ``dlrm_stream`` is
+the one in this file; any other kind is ``generators/<kind>.py``, found
+by name as placements and readers are.
 
-A batch is plain numpy: ``ids`` (batch, tables) int64, 0-based id per
-table (the popularity rank: id 0 is the hottest), ``dense`` (batch,
-num_dense) float32, ``label`` (batch, 1) float32. Batch ``i`` of seed
-``s`` is a pure function of (mix, cardinalities, s, i), so any thread may
-make any batch and the stream is the same.
+A ``dlrm_stream`` batch is plain numpy: ``ids`` (batch, tables) int64,
+0-based id per table (the popularity rank: id 0 is the hottest),
+``dense`` (batch, num_dense) float32, ``label`` (batch, 1) float32.
+Batch ``i`` of seed ``s`` is a pure function of (mix, cardinalities, s,
+i), so any thread may make any batch and the stream is the same. A
+generator of another kind promises the same of its own arrays.
 
 Copied from ``persia_tpu/workloads/generator.py`` (``zipf_cdf``,
 ``zipf_ranks``, ``hidden_weight``, ``dlrm_batches``) so that a later PR
@@ -15,21 +18,52 @@ listed in PERF.md.
 
 import collections
 import concurrent.futures
+import importlib.util
 import json
+import os
 
 import numpy as np
 
 _U64 = np.uint64
+OWN_KIND = "dlrm_stream"
+
+
+def generator_path(mix_path, kind):
+    """``<benchmark>/generators/<kind>.py`` for a mix under
+    ``<benchmark>/mixes/``."""
+    bench_dir = os.path.dirname(os.path.dirname(os.path.abspath(mix_path)))
+    return os.path.join(bench_dir, "generators", f"{kind}.py")
 
 
 def load_mix(path):
+    """The mix's parameters, with ``generator`` set to the file that
+    draws it where that is not this one."""
     with open(path) as f:
         mix = json.load(f)
-    if mix.get("kind") != "dlrm_stream":
-        raise ValueError(f"{path}: unknown mix kind {mix.get('kind')!r}")
-    if mix.get("id_law") != "zipf":     # alpha 0 is the uniform law
-        raise ValueError(f"{path}: unknown id_law {mix.get('id_law')!r}")
-    return mix
+    kind = mix.get("kind")
+    if kind == OWN_KIND:
+        if mix.get("id_law") != "zipf":     # alpha 0 is the uniform law
+            raise ValueError(f"{path}: unknown id_law {mix.get('id_law')!r}")
+        return mix
+    generator = generator_path(path, kind)
+    if not isinstance(kind, str) or not os.path.exists(generator):
+        raise ValueError(f"{path}: unknown mix kind {kind!r}: no generator "
+                         f"{generator}")
+    return dict(mix, generator=generator)
+
+
+def stream_for(mix, config, batch, seed):
+    """The mix's stream over a configuration: an object whose
+    ``batch(i)`` gives a dict with ``index`` and arrays whose first axis
+    is ``batch``, a pure function of (mix, config, batch, seed, i)."""
+    if mix["kind"] == OWN_KIND:
+        return Stream(mix, config["table_cardinalities"],
+                      config["num_dense"], batch, seed)
+    spec = importlib.util.spec_from_file_location(
+        "bench_generator_" + mix["kind"], mix["generator"])
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module.stream(mix, config, batch, seed)
 
 
 class RankLaw:
