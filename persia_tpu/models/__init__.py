@@ -16,6 +16,7 @@ from persia_tpu.models.dcn import DCNv2
 from persia_tpu.models.deepfm import DeepFM
 from persia_tpu.models.dlrm import DLRM
 from persia_tpu.models.dnn import DNN
+from persia_tpu.models.hybrid_seq import HybridSequenceTower
 from persia_tpu.models.seq import SequenceSelfAttention, SequenceTower
 from persia_tpu.models.wide_deep import WideAndDeep
 
@@ -25,6 +26,7 @@ __all__ = [
     "DLRM",
     "DCNv2",
     "DeepFM",
+    "HybridSequenceTower",
     "SequenceTower",
     "WideAndDeep",
     "SequenceSelfAttention",

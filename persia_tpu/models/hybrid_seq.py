@@ -1,0 +1,392 @@
+"""Hybrid sequence tower: state-space mixers, sparse experts beside a
+shared expert, and grouped-query attention over a history of item ids,
+with an item head for next-item prediction.
+
+A generative recommender: the history is the sequence, the item table
+(a ``DeviceEmbeddingCollection`` slot with ``pooling="none"``) is the
+vocabulary, and the tower returns a logit for every item at every
+position (``parallel.train.next_item_cross_entropy`` is the loss). It is
+driven like every other tower, through ``DeviceModeModel(slot_specs=[item
+slot], tower=HybridSequenceTower(...), pooling="none")`` and
+``make_device_mode_trainer``.
+
+Every layer is ``h + mixer(RMSNorm(h))``; ``pattern`` names the mixers,
+one letter a layer:
+
+``M``  a Mamba-2 mixer: one input projection to a gate ``z``, the
+       convolved stream ``xBC`` and a step size ``dt`` a head; a causal
+       depthwise convolution and SiLU; the selective scan
+       (``ops.ssm_scan``); ``GroupRMSNorm(y * silu(z))``; an output
+       projection.
+``E``  an expert layer (:class:`SparseExperts`).
+``*``  causal grouped-query attention through the Pallas flash kernel,
+       without a positional encoding: the state-space layers carry
+       position.
+
+Histories are left-aligned: padding, if any, lies at the tail, where
+under causal mixing it reaches no real position, so no mixer masks it;
+the loss leaves out positions without a target.
+
+**The expert layer's contract.** ``SparseExperts`` is told how many
+experts the model routes over (``experts_routed``) and which of them it
+holds (``experts_held``, ids). It scores every token against all routed
+experts in float32, takes the top ``per_token`` and their normalised
+weights as the whole model would, keeps the (token, expert) pairs whose
+expert is held, sorts them by expert and runs them through the grouped
+product over the held experts' matrices, and adds the shared expert for
+every token. What the experts held elsewhere would add is left out: in
+an expert-parallel job that part arrives by an exchange with the chips
+that hold them, and on one chip there is no exchange and nothing stands
+in for it. The buffer of pairs has the static size tokens x per_token,
+the most that can be routed here, so no pair is ever dropped whatever
+the imbalance; the grouped product visits only the tiles of rows that
+are in use.
+
+Recomputation: each layer is wrapped in ``nn.remat``, so the backward
+pass holds one (batch, T, hidden) input a layer and rebuilds a layer's
+internals when it reaches it (one forward more a step).
+
+``init`` declares every parameter and runs no mixer: a trainer that
+initialises eagerly (``make_device_mode_trainer``) would otherwise
+compile each layer's forward once more, op by op, for values it throws
+away (190 s of a cold start on a v5e). No parameter's shape depends on
+the input's length.
+"""
+
+from typing import Any, Sequence
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from flax import linen as nn
+from jax import lax
+
+from persia_tpu.ops.ssm_scan import ssm_scan
+
+F32 = jnp.float32
+
+
+def _dense(x, w, dtype, out=None):
+    return jnp.dot(x.astype(dtype), w.astype(dtype),
+                   preferred_element_type=out or dtype)
+
+
+def _rms(x, eps):
+    """x / sqrt(mean(x^2) + eps) over the last axis, float32."""
+    x = x.astype(F32)
+    return x * lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
+
+
+def _kernel_init(scale=1.0):
+    return nn.initializers.variance_scaling(scale, "fan_in", "normal")
+
+
+class RMSNorm(nn.Module):
+    eps: float = 1e-5
+    compute_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.param("weight", nn.initializers.ones, (x.shape[-1],), F32)
+        return (_rms(x, self.eps) * w).astype(self.compute_dtype)
+
+
+def _a_log_init(key, shape, dtype=F32):
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+def _dt_bias_init(dt_min, dt_max, dt_floor):
+    def init(key, shape, dtype=F32):
+        dt = jnp.exp(jax.random.uniform(key, shape, dtype)
+                     * (np.log(dt_max) - np.log(dt_min)) + np.log(dt_min))
+        dt = jnp.maximum(dt, dt_floor)
+        return dt + jnp.log(-jnp.expm1(-dt))    # softplus's inverse
+    return init
+
+
+class SSMMixer(nn.Module):
+    """Mamba-2 mixer over (batch, T, hidden)."""
+
+    heads: int = 64
+    head_dim: int = 64
+    groups: int = 8
+    state: int = 128
+    conv_kernel: int = 4
+    chunk: int = 128
+    eps: float = 1e-5
+    dt_limits: Sequence[float] = (1e-3, 1e-1, 1e-4)   # min, max, floor
+    out_scale: float = 1.0
+    compute_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        bs, t, hidden = u.shape
+        cd = self.compute_dtype
+        inner, gn = self.heads * self.head_dim, self.groups * self.state
+        conv_dim = inner + 2 * gn
+        w_in = self.param("in_proj", _kernel_init(),
+                          (hidden, inner + conv_dim + self.heads), F32)
+        conv_w = self.param(
+            "conv_w", nn.initializers.uniform(2.0 / np.sqrt(self.conv_kernel)),
+            (self.conv_kernel, conv_dim), F32)
+        conv_b = self.param("conv_b", nn.initializers.zeros, (conv_dim,), F32)
+        dt_bias = self.param("dt_bias", _dt_bias_init(*self.dt_limits),
+                             (self.heads,), F32)
+        a_log = self.param("A_log", _a_log_init, (self.heads,), F32)
+        d = self.param("D", nn.initializers.ones, (self.heads,), F32)
+        norm_w = self.param("norm_w", nn.initializers.ones, (inner,), F32)
+        w_out = self.param("out_proj", _kernel_init(self.out_scale),
+                           (inner, hidden), F32)
+        if self.is_initializing():
+            return jnp.zeros_like(u)
+
+        zxbcdt = _dense(u, w_in, cd)
+        z, xbc, dt = jnp.split(zxbcdt, [inner, inner + conv_dim], axis=-1)
+        # causal depthwise convolution: tap k reads position t - (K-1) + k
+        xbc = jnp.pad(xbc.astype(F32),
+                      ((0, 0), (self.conv_kernel - 1, 0), (0, 0)))
+        xbc = sum(conv_w[k] * xbc[:, k:k + t]
+                  for k in range(self.conv_kernel)) + conv_b
+        xbc = nn.silu(xbc).astype(cd)
+        x, b, c = jnp.split(xbc, [inner, inner + gn], axis=-1)
+        x = x.reshape(bs, t, self.heads, self.head_dim)
+        dt = jax.nn.softplus(dt.astype(F32) + dt_bias)
+        with jax.named_scope("ssm_scan"):
+            y = ssm_scan(x, dt, -jnp.exp(a_log),
+                         b.reshape(bs, t, self.groups, self.state),
+                         c.reshape(bs, t, self.groups, self.state),
+                         chunk=self.chunk, compute_dtype=cd)
+        y = y + d[:, None] * x.astype(F32)
+        y = y.reshape(bs, t, inner) * nn.silu(z.astype(F32))
+        y = _rms(y.reshape(bs, t, self.groups, inner // self.groups),
+                 self.eps).reshape(bs, t, inner) * norm_w
+        return _dense(y, w_out, cd)
+
+
+def route(scores, per_token, scaling):
+    """Top ``per_token`` of ``scores`` a token, and the weights ``scaling
+    * s_e / sum of the chosen s``: (experts, weights), both (tokens,
+    per_token). The published training adds a balancing bias to the
+    scores before the choice (never to the weights) and moves it by a
+    rule of its own; it is zero here and held constant."""
+    _, chosen = lax.top_k(scores, per_token)
+    s = jnp.take_along_axis(scores, chosen, axis=-1)
+    return chosen, scaling * s / jnp.sum(s, axis=-1, keepdims=True)
+
+
+class SparseExperts(nn.Module):
+    """The held experts' part of a routed expert layer, plus the shared
+    expert (module docstring: the contract)."""
+
+    experts_routed: int = 128
+    experts_held: Sequence[int] = tuple(range(8))
+    per_token: int = 6
+    expert_width: int = 1856
+    shared_width: int = 3712
+    scaling: float = 2.5
+    out_scale: float = 1.0
+    compute_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        bs, t, hidden = u.shape
+        cd, held = self.compute_dtype, len(self.experts_held)
+        w_r = self.param("router", _kernel_init(),
+                         (hidden, self.experts_routed), F32)
+        w1 = self.param("w1", _kernel_init(),
+                        (held, hidden, self.expert_width), F32)
+        w2 = self.param("w2", _kernel_init(self.out_scale),
+                        (held, self.expert_width, hidden), F32)
+        s1 = self.param("shared_w1", _kernel_init(),
+                        (hidden, self.shared_width), F32)
+        s2 = self.param("shared_w2", _kernel_init(self.out_scale),
+                        (self.shared_width, hidden), F32)
+        if self.is_initializing():
+            return jnp.zeros_like(u)
+        # Pallas is imported where a kernel is called: every tower of the
+        # zoo is imported together, and the others' start-up does not pay
+        # the two seconds it takes
+        from persia_tpu.ops.grouped_matmul import grouped_matmul
+
+        tokens = u.reshape(bs * t, hidden)
+        n = tokens.shape[0]
+
+        with jax.named_scope("experts_route"):
+            scores = jax.nn.sigmoid(jnp.dot(
+                tokens.astype(F32), w_r, precision=lax.Precision.HIGHEST))
+            chosen, weight = route(scores, self.per_token, self.scaling)
+            # where each chosen expert sits among the held ones; `held`
+            # itself for an expert that lives elsewhere
+            local = np.full((self.experts_routed,), held, np.int32)
+            local[list(self.experts_held)] = np.arange(held)
+            local = jnp.asarray(local)[chosen].reshape(n * self.per_token)
+            order = jnp.argsort(local, stable=True)
+            token_of = order // self.per_token      # of each sorted pair
+            sizes = jnp.bincount(local, length=held + 1).astype(jnp.int32)
+            self.sow("intermediates", "routed_rows", sizes[:held])
+
+        with jax.named_scope("experts_grouped"):
+            # rows past the held groups stay in the buffer (its size is
+            # static) but no tile of theirs is multiplied
+            rows = jnp.take(tokens.astype(cd), token_of, axis=0)
+            mid = grouped_matmul(rows, w1.astype(cd), sizes)
+            mid = jnp.square(nn.relu(mid))
+            out = grouped_matmul(mid, w2.astype(cd), sizes)
+            out = out.astype(F32) * weight.reshape(-1)[order][:, None]
+            routed = jax.ops.segment_sum(out, token_of, num_segments=n)
+
+        with jax.named_scope("experts_shared"):
+            shared = _dense(jnp.square(nn.relu(_dense(tokens, s1, cd))),
+                            s2, cd)
+        return (routed + shared.astype(F32)).astype(cd).reshape(
+            bs, t, hidden)
+
+
+class GroupedQueryAttention(nn.Module):
+    """Causal softmax attention, ``kv_heads`` key-value heads serving
+    ``heads`` query heads. The keys and values are repeated to the query
+    heads before the flash kernel and autodiff sums their gradients over
+    each group: the kernel streams a key block per (head, query block)
+    either way, and its block specs stay as every other caller has
+    them."""
+
+    heads: int = 32
+    kv_heads: int = 2
+    head_dim: int = 128
+    out_scale: float = 1.0
+    compute_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        bs, t, hidden = u.shape
+        cd, hd = self.compute_dtype, self.head_dim
+        wq = self.param("q_proj", _kernel_init(), (hidden, self.heads * hd),
+                        F32)
+        wk = self.param("k_proj", _kernel_init(),
+                        (hidden, self.kv_heads * hd), F32)
+        wv = self.param("v_proj", _kernel_init(),
+                        (hidden, self.kv_heads * hd), F32)
+        wo = self.param("o_proj", _kernel_init(self.out_scale),
+                        (self.heads * hd, hidden), F32)
+        if self.is_initializing():
+            return jnp.zeros_like(u)
+        from persia_tpu.ops.flash_attention import flash_attention_masked
+
+        def heads(y, n):    # (bs, t, n * hd) -> (bs, n, t, hd)
+            return y.reshape(bs, t, n, hd).transpose(0, 2, 1, 3)
+
+        q = heads(_dense(u, wq, cd), self.heads)
+        group = self.heads // self.kv_heads
+        k = jnp.repeat(heads(_dense(u, wk, cd), self.kv_heads), group, axis=1)
+        v = jnp.repeat(heads(_dense(u, wv, cd), self.kv_heads), group, axis=1)
+        with jax.named_scope("flash_attention"):    # the calls' name in a trace
+            out = flash_attention_masked(q, k, v, causal=True)
+        out = out.transpose(0, 2, 1, 3).reshape(bs, t, self.heads * hd)
+        return _dense(out, wo, cd)
+
+
+class _Layer(nn.Module):
+    """``h + mixer(RMSNorm(h))`` under the mixer's scope name."""
+
+    mixer: nn.Module
+    scope_name: str
+    eps: float
+    compute_dtype: Any
+
+    @nn.compact
+    def __call__(self, h):
+        with jax.named_scope(self.scope_name):
+            u = RMSNorm(self.eps, self.compute_dtype, name="norm")(h)
+            return h + self.mixer(u)
+
+
+class HybridSequenceTower(nn.Module):
+    """``pattern`` and every size are constructor data; the defaults are
+    one chip's share of a published 52-layer model (its first nine
+    layers, 8 of its 128 routed experts, an eighth of its vocabulary).
+    Called as every tower is, with the item slot's ``(sequence, mask)``
+    as the one embedding input; returns float32 logits (batch, T,
+    vocab) over the item table's rows."""
+
+    pattern: str = "MEMEM*EME"
+    hidden: int = 2688
+    vocab: int = 16384
+    eps: float = 1e-5
+    ssm_heads: int = 64
+    ssm_head_dim: int = 64
+    ssm_groups: int = 8
+    ssm_state: int = 128
+    conv_kernel: int = 4
+    chunk: int = 128
+    experts_routed: int = 128
+    experts_held: Sequence[int] = tuple(range(8))
+    experts_per_token: int = 6
+    expert_width: int = 1856
+    shared_width: int = 3712
+    routed_scaling: float = 2.5
+    attn_heads: int = 32
+    attn_kv_heads: int = 2
+    attn_head_dim: int = 128
+    compute_dtype: Any = jnp.bfloat16
+
+    SCOPES = {"M": "ssm_mixer", "E": "experts", "*": "attention"}
+
+    def step_tags(self):
+        """What ``make_device_mode_trainer`` tags its build with."""
+        return {"tower_layers": self.pattern,
+                "experts_held": tuple(self.experts_held),
+                "experts_routed": self.experts_routed}
+
+    def _mixer(self, kind, out_scale):
+        """Unbound, so that the layer it is handed to adopts it."""
+        cd = self.compute_dtype
+        if kind == "M":
+            return SSMMixer(self.ssm_heads, self.ssm_head_dim,
+                            self.ssm_groups, self.ssm_state,
+                            self.conv_kernel, self.chunk, self.eps,
+                            out_scale=out_scale, compute_dtype=cd,
+                            parent=None)
+        if kind == "E":
+            return SparseExperts(self.experts_routed,
+                                 tuple(self.experts_held),
+                                 self.experts_per_token, self.expert_width,
+                                 self.shared_width, self.routed_scaling,
+                                 out_scale, cd, parent=None)
+        if kind == "*":
+            return GroupedQueryAttention(self.attn_heads, self.attn_kv_heads,
+                                         self.attn_head_dim, out_scale, cd,
+                                         parent=None)
+        raise ValueError(f"pattern {self.pattern!r}: unknown layer {kind!r}")
+
+    @nn.compact
+    def __call__(self, non_id_tensors, embedding_tensors, train: bool = False):
+        (h, _mask), = embedding_tensors
+        h = h.astype(self.compute_dtype)
+        # output projections start smaller the deeper the stack
+        # (the published rescale_prenorm_residual)
+        out_scale = 1.0 / len(self.pattern)
+        layer = _Layer if self.is_initializing() else nn.remat(_Layer)
+        for i, kind in enumerate(self.pattern):
+            h = layer(self._mixer(kind, out_scale), self.SCOPES[kind],
+                      self.eps, self.compute_dtype, name=f"layer_{i}")(h)
+        with jax.named_scope("item_head"):
+            h = RMSNorm(self.eps, self.compute_dtype, name="final_norm")(h)
+            w = self.param("item_head", _kernel_init(),
+                           (self.hidden, self.vocab), F32)
+            return _dense(h, w, self.compute_dtype, out=F32)
+
+
+def routed_rows(model, params, non_id_tensors, id_tensors):
+    """For one batch, the rows routed to each held expert of each expert
+    layer of ``model`` (a ``DeviceModeModel`` over this tower), as a
+    (expert layers, held) int32 array in layer order."""
+    _, state = model.apply({"params": params}, non_id_tensors, id_tensors,
+                           train=False, mutable=["intermediates"])
+    found = jax.tree_util.tree_flatten_with_path(state["intermediates"])[0]
+
+    def layer_of(path):
+        return min(int(k.key.split("_")[1]) for k in path
+                   if getattr(k, "key", "").startswith("layer_"))
+
+    found = sorted(found, key=lambda kv: layer_of(kv[0]))
+    return jnp.stack([leaf for _, leaf in found])

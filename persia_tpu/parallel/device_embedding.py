@@ -46,19 +46,22 @@ def table_row_index(ids: jnp.ndarray, vocab_size: int):
 
 
 class DeviceEmbeddingBag(nn.Module):
-    """One hashed embedding table with sum/mean pooling.
+    """One hashed embedding table with sum/mean pooling, or none.
 
     ids enter as the worker's static-shape (bs, sample_fixed_size) index
     tensor of raw u64 signs hashed modulo ``vocab_size`` (0 rows are
     reserved for padding via the mask argument). ``rows`` is the
     (bs, sample_fixed_size, dim) the caller gathered at those ids itself;
-    the table is then not touched.
+    the table is then not touched. ``pooling="none"`` is the sequence
+    slot: the gathered rows as they stand, padding zeroed, and their
+    (bs, sample_fixed_size) mask, for a tower that reads a history
+    position by position.
     """
 
     vocab_size: int
     dim: int
     compute_dtype: Any = jnp.bfloat16
-    pooling: str = "sum"  # "sum" | "mean"
+    pooling: str = "sum"  # "sum" | "mean" | "none"
 
     @nn.compact
     def __call__(self, hashed_ids: jnp.ndarray, mask: jnp.ndarray,
@@ -78,6 +81,8 @@ class DeviceEmbeddingBag(nn.Module):
                 )
                 rows = jnp.take(table, hashed_ids, axis=0)  # (bs, sfs, dim)
             gathered = rows * mask[..., None].astype(rows.dtype)
+            if self.pooling == "none":
+                return gathered.astype(self.compute_dtype), mask
             pooled = gathered.sum(axis=1)
             if self.pooling == "mean":
                 denom = jnp.maximum(mask.sum(axis=1, keepdims=True), 1)

@@ -21,6 +21,13 @@ no ``table_rows``, keeps the dense step: ``jax.value_and_grad`` over the
 whole tree and ``optimizer.update`` over every table. The gauges
 ``device_mode_row_update_tables`` and ``device_mode_dense_update_tables``
 say which one a build took.
+
+A tower may describe its own build (optional; the DLRM-family towers do
+not): a method ``step_tags() -> {name: int or sequence}``. What it
+returns becomes tags of the span ``trainer/build_device_step`` as given,
+and gauges ``device_mode_<name>`` (an int as it is, a sequence by its
+length). The hybrid sequence tower gives ``tower_layers`` (its pattern),
+``experts_held`` and ``experts_routed``.
 """
 
 from typing import Any, Callable, Dict, Sequence, Tuple
@@ -52,8 +59,9 @@ class DeviceModeModel(nn.Module):
     """Dense tower + device embedding tables as one module.
 
     ``slot_specs``: sequence of (name, vocab_size, dim) for the hashed
-    HBM tables; ``tower``: a model-zoo module instance. ``rows``, if
-    given, mirrors the tables' place in the parameters
+    HBM tables; ``tower``: a model-zoo module instance, which may have
+    ``step_tags()`` (this module's docstring says what for). ``rows``,
+    if given, mirrors the tables' place in the parameters
     (:meth:`table_rows`) and holds their values already gathered.
     """
 
@@ -114,7 +122,12 @@ def make_device_mode_trainer(
         is_leaf=lambda x: isinstance(x, P) or x is None,
     )
     params = jax.tree_util.tree_map(jax.device_put, params, shardings)
-    opt_state = optimizer.init(params)
+    # a leaf the optimizer made from nothing (Adam's step count) lies on
+    # no mesh yet, while every output of the step does: placed now, the
+    # second call finds the first call's compilation
+    opt_state = jax.tree_util.tree_map(
+        lambda x: x if isinstance(x, jax.core.Tracer) or x.committed
+        else jax.device_put(x, replicated(mesh)), optimizer.init(params))
 
     with tracing.span("trainer/build_device_step") as built:
         flat, treedef = jax.tree_util.tree_flatten_with_path(params)
@@ -127,6 +140,14 @@ def make_device_mode_trainer(
         counts = {"row_update_tables": len(tables) if by_row else 0,
                   "dense_update_tables": 0 if by_row else len(tables)}
         built.tag(**counts)
+        # what the tower says of its own build, where it has step_tags()
+        # (optional: the module's docstring): tags as given, and as
+        # gauges the count of what a tag lists
+        tower = getattr(model, "tower", None)
+        described = tower.step_tags() if hasattr(tower, "step_tags") else {}
+        built.tag(**described)
+        counts.update({name: v if isinstance(v, int) else len(v)
+                       for name, v in described.items()})
         for name, n in counts.items():
             metrics.default_registry().gauge(f"device_mode_{name}").set(n)
     if not by_row:

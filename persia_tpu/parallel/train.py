@@ -36,6 +36,20 @@ def bce_loss(pred: jnp.ndarray, label: jnp.ndarray) -> jnp.ndarray:
     return -jnp.mean(label * jnp.log(pred) + (1.0 - label) * jnp.log(1.0 - pred))
 
 
+def next_item_cross_entropy(logits: jnp.ndarray,
+                            target: jnp.ndarray) -> jnp.ndarray:
+    """Softmax cross entropy of each position's (..., classes) logits
+    against the class the next event took, a mean over the positions
+    that have one (``target`` < 0: none, as the last position of a
+    history whose next event lies outside the batch). Float32
+    throughout."""
+    valid = target >= 0
+    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+    picked = jnp.take_along_axis(
+        logp, jnp.where(valid, target, 0)[..., None], axis=-1)[..., 0]
+    return -jnp.sum(picked * valid) / jnp.maximum(jnp.sum(valid), 1)
+
+
 def _rebuild_embedding_inputs(
     emb_values: Sequence[jnp.ndarray], emb_indices: Sequence[Optional[jnp.ndarray]]
 ) -> List[Any]:
