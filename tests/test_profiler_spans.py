@@ -283,6 +283,8 @@ def test_hybrid_step_yields_each_trainer_span_once(ring):
         assert _leads_to(s, root, everything)
     tags = spans["trainer/place_batch"][0].tags
     assert tags["leaves"] == 2 and tags["bytes"] == 64 * 13 * 4 + 64 * 4
+    # two leaves: a packed transfer and its unpack would be no fewer calls
+    assert tags["transfers"] == 2 and tags["packed_leaves"] == 0
     assert spans["trainer/dispatch"][0].tags == {"compiled": False}
     (built,) = [s for s in ring.recent() if s.name == "trainer/dispatch"
                 and s.trace_id == first.trace_id]
