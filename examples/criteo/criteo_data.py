@@ -1,6 +1,6 @@
 """Criteo click-logs loader (Kaggle DAC / Terabyte format).
 
-The BASELINE.json configs are all Criteo DLRM shapes; this loader feeds
+The reference's workloads are all Criteo DLRM shapes; this loader feeds
 them: each line is ``label \t I1..I13 \t C1..C26`` (ints may be empty,
 categoricals are 8-hex-digit strings or empty). Dense features use the
 standard log(1+x) transform; each categorical token parses to a u64

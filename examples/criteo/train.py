@@ -1,6 +1,6 @@
-"""Criteo DLRM training — the BASELINE.json workload.
+"""Criteo DLRM training — the reference's workload.
 
-Maps onto the baseline configs:
+Maps onto its configurations:
   1/2. single worker + in-process or remote PS:  default flags
   3.   multi-chip data-parallel dense:           --mesh data,model (e.g. 8,1)
   4.   alternate towers:                          --model dcnv2|deepfm

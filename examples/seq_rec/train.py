@@ -71,7 +71,7 @@ def make_batches(args, num_samples, batch_size, seed=0,
     history-cluster homogeneity; see
     persia_tpu/workloads/generator.py:seqrec_batches). This example
     reads the SAME stream through a different schema lens than
-    `bench.py --mode e2e --scenario seqrec`: recent_items stays a RAW
+    the registry's `seqrec` scenario: recent_items stays a RAW
     slot here so the attention tower sees the full sequence, while the
     clicks slot exercises worker-tier last-N pooling."""
     spec = SeqRecSpec(item_vocab=args.vocab, t_hist=args.t_hist)

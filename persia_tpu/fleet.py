@@ -15,8 +15,7 @@ federated view:
 - ``GET /fleet/status``  — JSON topology: role, addresses, up/ready,
   version (spot replica skew), uptime, last-scrape age per target.
 - ``GET /fleet/trace[?trace_id=...]`` — the multi-process Chrome-trace
-  merge, scraped live from every up target (the library form of what
-  ``bench.py --mode trace`` prototyped).
+  merge, scraped live from every up target.
 - ``GET /fleet/alerts`` — the SLO engine's judgement
   (:mod:`persia_tpu.slos`): every rule, per service, with firing state.
 - ``GET /fleet/breaches`` — the bounded breach-event log.

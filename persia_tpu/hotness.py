@@ -32,7 +32,7 @@ exact-associative (counts are integers, and integer sums in float64
 are exact), which is what lets one PS replica's per-shard summaries
 roll up into a table view, and the fleet monitor roll N replicas into
 one cross-shard coverage curve whose totals equal the sum of the
-parts (``bench.py --mode telemetry`` pins this).
+parts (``tests/test_telemetry.py::test_fleet_hotness_merge_totals``).
 
 **Lock discipline** (persialint-enforced): :class:`HotnessTracker`
 owns one lock per internal shard and is the only writer of its cells;

@@ -40,16 +40,17 @@ def ready_packets(inc_dir: str, applied: Set[str]):
     sort by dump timestamp). The one packet-discovery convention shared
     by the PS-side :class:`IncrementalUpdateLoader` and the serving-side
     delta subscriber (:mod:`persia_tpu.online`) — a packet is visible
-    only once its done-marker exists (the dumper renames the whole
-    directory into place, so a partially-written packet is never
-    listed)."""
+    only once its done-marker exists and its directory has been renamed
+    into place: a dumper SIGKILLed mid-packet leaves a ``.tmp``
+    directory behind, possibly with an empty marker, which is never
+    listed."""
     if not os.path.isdir(inc_dir):
         return
     for name in sorted(os.listdir(inc_dir)):
         pkt_dir = os.path.join(inc_dir, name)
         marker = os.path.join(pkt_dir, DONE_MARKER)
         if (name in applied or not name.startswith("inc_")
-                or not os.path.exists(marker)):
+                or name.endswith(".tmp") or not os.path.exists(marker)):
             continue
         with open(marker) as f:
             info = json.load(f)
@@ -85,27 +86,31 @@ class IncrementalUpdateDumper:
         self.replica_index = replica_index
         self._buffer: Set[int] = set()
         self._lock = threading.Lock()
+        # Packets replay in name (= seq) order, and a packet holds the
+        # rows as they read when it is DUMPED. So the seq is handed out
+        # inside the dump turn: of two handlers flushing at once, the
+        # later-named packet is the one whose rows were read later, and
+        # a restore never ends on an older row. Guards ``_seq``.
+        self._dump_turn = threading.Lock()
         self._seq = 0
         os.makedirs(inc_dir, exist_ok=True)
 
     def commit(self, signs: np.ndarray):
-        flush: Optional[Set[int]] = None
-        with self._lock:
-            self._buffer.update(int(s) for s in signs)
-            if len(self._buffer) >= self.buffer_size:
-                flush = self._buffer
-                self._buffer = set()
-                seq = self._seq = self._seq + 1
-        if flush:
-            self._dump_packet(flush, seq)
+        self._buffer_and_maybe_dump(signs, force=False)
 
     def flush(self):
+        self._buffer_and_maybe_dump((), force=True)
+
+    def _buffer_and_maybe_dump(self, signs, force: bool):
         with self._lock:
+            self._buffer.update(int(s) for s in signs)
+            if not self._buffer or not (
+                    force or len(self._buffer) >= self.buffer_size):
+                return
             flush, self._buffer = self._buffer, set()
-            if flush:
-                seq = self._seq = self._seq + 1
-        if flush:
-            self._dump_packet(flush, seq)
+        with self._dump_turn:
+            self._seq += 1
+            self._dump_packet(flush, self._seq)
 
     def _dump_packet(self, signs: Set[int], seq: int):
         import struct
@@ -128,7 +133,7 @@ class IncrementalUpdateDumper:
         # the update RPC that triggered the flush failed). A restarted
         # replica restarts seq at 1, so the pid suffix keeps a fresh
         # incarnation from colliding with its predecessor's packets.
-        # ``seq`` is allocated inside commit/flush's locked region:
+        # ``seq`` is allocated inside the dump turn:
         # concurrent update handlers (dispatch pool, shard-parallel)
         # both flushing used to race the unguarded `self._seq += 1`
         # here and could mint the SAME packet name within one second of

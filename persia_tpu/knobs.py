@@ -202,9 +202,7 @@ REGISTRY: Dict[str, Knob] = {k.name: k for k in [
        "Write-rate governor of the serving delta subscriber: a token "
        "bucket (1s burst) over rows upserted into the hot-row cache, "
        "so a training-tier flush burst spreads its applies instead of "
-       "convoying the predict path (the --mode online bench gates "
-       "serving p99 inflation at <= 3% with this armed). 0 = "
-       "unthrottled."),
+       "convoying the predict path. 0 = unthrottled."),
     _k("PERSIA_ONLINE_SCAN_SEC", "float", 2.0,
        "Scan interval of the serving delta subscriber over the "
        "incremental-update packet directory. Together with the "
@@ -226,8 +224,8 @@ REGISTRY: Dict[str, Knob] = {k.name: k for k in [
        "policy (negotiating down to the Python arena holder LOUDLY "
        "when an older .so lacks a capability), `native` requires it, "
        "`arena` forces the Python arena holder, `python-legacy` forces "
-       "the per-entry OrderedDict holder (A/B lever for bench.py "
-       "--mode mem). Replaces the retired PERSIA_FORCE_PYTHON_PS."),
+       "the per-entry OrderedDict holder (the reference the arena is "
+       "compared with). Replaces the retired PERSIA_FORCE_PYTHON_PS."),
     _k("PERSIA_PS_CIRCUIT_BREAKER", "bool", True,
        "Per-replica circuit breaker on every PsClient RPC (fail fast "
        "while a background TCP probe watches the address). `0` "
@@ -235,13 +233,6 @@ REGISTRY: Dict[str, Knob] = {k.name: k for k in [
     _k("PERSIA_PS_CONCURRENT_STREAMS", "int", 8,
        "PS per-connection dispatch-pool depth (1 = the legacy "
        "strictly-serial per-connection loop)."),
-    _k("PERSIA_PS_GC_TUNE", "bool", True,
-       "PS replica: freeze boot state and make full GC ~100x rarer "
-       "(a multi-million-entry store makes gen2 walks multi-hundred-ms "
-       "stalls). `0` restores interpreter defaults."),
-    _k("PERSIA_PS_LEGACY_FRAMES", "bool", False,
-       "Revert PS request framing to the concatenating pack_arrays "
-       "(pre-zero-copy A/B lever for the worker-cycle bench)."),
     _k("PERSIA_PS_ROW_DTYPE", "str", None,
        "Storage precision of the embedding slice of every PS row "
        "(fp32|fp16|bf16; optimizer state stays fp32). Served by every "

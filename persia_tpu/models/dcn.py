@@ -1,6 +1,6 @@
 """DCN-v2: deep & cross network with full-matrix cross layers.
 
-One of BASELINE.json's alternate dense towers. Cross layers compute
+One of the reference workload's alternate dense towers. Cross layers compute
 ``x_{l+1} = x0 * (W_l x_l + b_l) + x_l`` (the v2 formulation) with the
 matmul in bf16 on the MXU.
 """

@@ -24,8 +24,7 @@ slow batch can be followed across tiers without restarting anything:
   the process-local trace collector. ``chrome`` (default) is a
   Chrome-trace/Perfetto ``traceEvents`` JSON ready to load as-is;
   ``raw`` is ``{"spans": [...], "dropped_total": N}`` — the span-dict
-  window the fleet monitor and ``bench.py --mode trace`` merge into one
-  multi-process timeline, with the ring's eviction count so a consumer
+  window the fleet monitor merges into one multi-process timeline, with the ring's eviction count so a consumer
   knows whether the window is complete.
 - ``GET /flight`` — the flight-recorder snapshot: ONE JSON document
   bundling the health doc, the current metrics exposition, the recent

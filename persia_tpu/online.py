@@ -19,8 +19,7 @@ cache for up to ``cache_ttl_sec``. This module closes it directly:
 - A **write-rate governor** (token bucket over applied rows,
   ``PERSIA_ONLINE_APPLY_ROWS_PER_SEC``) bounds how hard a training
   burst can hammer the cache lock: a multi-million-row flush spreads
-  its applies instead of convoying the predict path — the bench's
-  serving-p99-inflation gate (<= 3%) is the contract.
+  its applies instead of convoying the predict path.
 - **Routing awareness** across reshard epochs (PR 11/12): each packet
   file names its dumping PS replica; with a routing view attached, a
   row only applies when that replica OWNS the row's slot under the
@@ -37,7 +36,8 @@ cache for up to ``cache_ttl_sec``. This module closes it directly:
 
 Off is free: a server that never attaches a subscriber runs exactly
 the PR-13 code — no thread, no extra RPCs, byte-identical wire
-(pinned by bench.py --mode online's served-request counts).
+(served-request counts pinned by
+``tests/test_online.py::test_serving_loop_split_oracle_freshness_and_idle_wire``).
 """
 
 import threading

@@ -2,10 +2,10 @@
 
 The per-entry :class:`~persia_tpu.ps.store.EmbeddingHolder` keeps every
 row as its own numpy object inside an OrderedDict — at 10^7..10^9 rows
-that is 10^7..10^9 tracked Python objects (the gen2 GC walks that forced
-the ``PERSIA_PS_GC_TUNE`` workaround), ~100 bytes of per-entry overhead
-on top of the data, and a per-sign interpreter loop on every batched
-call. This module stores rows the way "Tensor Casting" (PAPERS.md)
+that is 10^7..10^9 tracked Python objects (the gen2 GC walks that
+forced the PS binary's gc freeze-and-retune at boot), ~100 bytes of
+per-entry overhead on top of the data, and a per-sign interpreter loop
+on every batched call. This module stores rows the way "Tensor Casting" (PAPERS.md)
 treats embedding access — as a byte-addressed, layout-co-designed path:
 
 - **Record classes.** Rows live in fixed-stride records grouped per

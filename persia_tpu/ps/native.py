@@ -928,7 +928,7 @@ def make_holder(capacity: int, num_internal_shards: int,
       library is missing a needed capability).
     - ``arena``: force the Python arena holder.
     - ``python-legacy``: force the per-entry OrderedDict holder (the
-      bench's A/B baseline).
+      reference ``tests/test_arena.py`` compares the arena with).
 
     ``backend=None`` reads the ``PERSIA_PS_BACKEND`` knob;
     ``prefer_native=False`` maps ``auto`` to the Python arena holder.

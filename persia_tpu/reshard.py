@@ -41,8 +41,9 @@ final value replays to the target before the new epoch publishes — or
 the epoch lands. The target accepts no writes for the moved slots
 before the final replay completes (workers only route there under the
 new epoch, which publishes after), so replay can never clobber a
-post-cutover write. ``bench.py --mode reshard`` pins this with a
-counting optimizer over a live 2→4→3 dance.
+post-cutover write. ``tests/test_reshard.py`` pins this with a counting
+optimizer over a live 2→4→3 dance, and ``tests/test_chaos_reshard.py``
+with every actor killed at every state.
 """
 
 import json

@@ -410,7 +410,7 @@ class ShardParallelDispatcher:
 class PsService:
     def __init__(self, holder, host: str = "127.0.0.1", port: int = 0,
                  inc_dumper=None, shard_parallel: Optional[bool] = None,
-                 concurrent_streams: int = 8, legacy_frames: bool = False,
+                 concurrent_streams: int = 8,
                  http_port: Optional[int] = None, inc_loader=None):
         self.holder = holder
         self.inc_dumper = inc_dumper
@@ -426,10 +426,7 @@ class PsService:
                                 concurrent_streams=concurrent_streams)
         self._dispatch = ShardParallelDispatcher(holder,
                                                  enabled=shard_parallel)
-        # legacy_frames reverts responses to the concatenating
-        # pack_arrays — the pre-zero-copy plane, kept as the A/B lever
-        # for bench.py --mode worker's serialized baseline
-        self._pack = pack_arrays if legacy_frames else pack_arrays_sg
+        self._pack = pack_arrays_sg
         self.status = "Idle"  # Idle | Dumping | Loading | Failed (model mgr)
         self._status_lock = threading.Lock()
         s = self.server
@@ -1483,7 +1480,6 @@ class PsClient:
                 f"{sorted(cls._WIRE_CODECS)})") from None
 
     def __init__(self, addr: str, enable_tags: bool = True,
-                 legacy_frames: bool = False,
                  circuit_breaker=None, deadline: Optional[float] = None,
                  wire_codec: Optional[str] = None,
                  hotness: Optional[bool] = None,
@@ -1534,9 +1530,7 @@ class PsClient:
             self._ef = GradErrorFeedback()
         else:
             self._ef = None
-        # legacy_frames reverts request framing to the concatenating
-        # pack_arrays (pre-zero-copy A/B lever; see PsService)
-        self._pack = pack_arrays if legacy_frames else pack_arrays_sg
+        self._pack = pack_arrays_sg
         if circuit_breaker is None:
             circuit_breaker = (
                 knobs.get("PERSIA_PS_CIRCUIT_BREAKER"))
@@ -1729,8 +1723,8 @@ class PsClient:
 
     def wire_stats(self) -> dict:
         """Cumulative payload bytes this client sent/received (rpc.py
-        counters) — the bytes-on-wire accounting ``bench --mode mem``
-        diffs."""
+        counters) — the bytes-on-wire accounting
+        ``tests/test_precision.py`` holds the codec to."""
         return self.client.wire_stats()
 
     def __len__(self) -> int:
@@ -2000,23 +1994,19 @@ def main():
 
     start_deadlock_detection()
     set_service_name(f"ps{args.replica_index}")
-    if knobs.get("PERSIA_PS_GC_TUNE"):
-        # The LEGACY per-entry holder keeps millions of gc-tracked
-        # objects (per-entry tuples, dict nodes); CPython's default gen2
-        # cadence then walks the ENTIRE store every few seconds of
-        # traffic — multi-hundred-ms request stalls that scale with
-        # resident rows. The arena backends store rows in a handful of
-        # GC-invisible slab buffers, so since PR 10 this tune is no
-        # longer load-bearing for the default backends (bench --mode mem
-        # pins the full-GC pause without it); it stays harmless-on for
-        # the python-legacy A/B lever and frozen boot state.
-        # PERSIA_PS_GC_TUNE=0 restores the interpreter defaults.
-        # (aliased import: `gc` is this function's GlobalConfig below)
-        import gc as _gcmod
+    # Freeze boot state and make full collections rare. The per-entry
+    # holder keeps millions of gc-tracked objects (per-entry tuples,
+    # dict nodes); CPython's default gen2 cadence then walks the ENTIRE
+    # store every few seconds of traffic — multi-hundred-ms request
+    # stalls that scale with resident rows. The arena backends keep
+    # rows in a handful of GC-invisible slab buffers and do not need
+    # it, but every replica has always run with it.
+    # (aliased import: `gc` is this function's GlobalConfig below)
+    import gc as _gcmod
 
-        _gcmod.collect()
-        _gcmod.freeze()
-        _gcmod.set_threshold(50_000, 25, 100)
+    _gcmod.collect()
+    _gcmod.freeze()
+    _gcmod.set_threshold(50_000, 25, 100)
 
     gc = GlobalConfig.load(args.global_config) if args.global_config else GlobalConfig()
     # replicas share one spill_dir config; each keeps its packets in
@@ -2056,8 +2046,6 @@ def main():
         holder, args.host, args.port, inc_dumper=inc_dumper,
         inc_loader=inc_loader,
         concurrent_streams=args.concurrent_streams,
-        # A/B lever for the worker-cycle bench's serialized baseline
-        legacy_frames=knobs.get("PERSIA_PS_LEGACY_FRAMES"),
         http_port=obs_http.port_from_args(args))
     if args.initial_checkpoint or args.replay_inc_dir:
         # restore BEFORE registering with the coordinator, so workers

@@ -1,6 +1,6 @@
 """Supervised trainer driver — the nn-worker leg of whole-job crash
-safety (reference: persia/e2e trainer entrypoints; chaos harness in
-bench.py --mode chaos).
+safety (reference: persia/e2e trainer entrypoints; kill matrix in
+tests/test_chaos_job.py).
 
 This binary is what ``ServiceCtx(supervise_trainer=True)`` respawns
 after a trainer SIGKILL. It runs the counting workload the chaos cells
@@ -40,9 +40,7 @@ a real ``jax.distributed`` global mesh through the fleet coordinator's
 KV store (process 0 binds a port and publishes ``host:port`` under
 ``PERSIA_TRAINER_RENDEZVOUS_KEY``; the rest ``wait_kv`` it), then syncs
 a dense tower through the int8-EF all-reduce every
-``--dense-sync-every`` local steps. ``--device-step-ms`` models the
-TPU-resident dense step (device-occupancy sleep between lookup and
-update) so scaling cells measure the hybrid overlap, not just host RPC.
+``--dense-sync-every`` local steps.
 
 Multi-process crash-safety is CURSOR-ONLY: each process checkpoints its
 shard cursor (``cursor_p<i>.json``) and a restart resumes its own shard
@@ -223,9 +221,6 @@ def main(argv=None):
                         "scenario name (dlrm/seqrec/multitask): same "
                         "lookup/update data plane, production-shaped "
                         "slot layout")
-    p.add_argument("--device-step-ms", type=float, default=0.0,
-                   help="modeled TPU dense-step occupancy between "
-                        "lookup and update (0 = RPC-only loop)")
     p.add_argument("--jax-mesh", action="store_true",
                    help="rendezvous a jax.distributed global mesh over "
                         "the coordinator KV store")
@@ -387,7 +382,6 @@ def main(argv=None):
         dense_sync = _dense_rider(jax, mesh, args.process_count, args.seed)
 
     status["model_manager_status"] = "Training"
-    device_step = args.device_step_ms / 1000.0
     ships = 0
     step = start  # LOCAL step counter (this shard's batches)
     t_loop = time.monotonic()
@@ -405,11 +399,6 @@ def main(argv=None):
             if die_at == "mid_step" and step == die_step:
                 arm_kill()
                 _die_now()
-            if device_step:
-                # modeled TPU occupancy: the dense fwd/bwd holds the
-                # accelerator here while the NEXT batch's lookup could
-                # already be in flight on other trainer hosts
-                time.sleep(device_step)
             with tracing.span("trainer/update"):
                 worker.update_gradients(ref, {
                     k: np.ones_like(v.embeddings) for k, v in out.items()})
@@ -482,7 +471,6 @@ def main(argv=None):
             "samples": (step - start) * args.batch_size,
             "ships": ships,
             "group_ships": group_ships,
-            "device_step_ms": args.device_step_ms,
             "mesh_shape": status["mesh_shape"],
             "dense_syncs": dense_syncs,
             "dense_loss": dense_loss,

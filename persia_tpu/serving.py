@@ -61,7 +61,7 @@ docs/ARCHITECTURE.md "Online learning loop & variant serving"):
 
 Serving counters use the reference's ``*_time_cost_sec`` metric style
 and are exported through :mod:`persia_tpu.metrics` (labeled per server
-port) plus a ``stats`` RPC for scrapers and ``bench.py --mode infer``.
+port) plus a ``stats`` RPC for scrapers.
 
 Typical wiring::
 

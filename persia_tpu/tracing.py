@@ -403,23 +403,6 @@ def promote_remote_parents(spans: List[Dict]) -> List[Dict]:
     return out
 
 
-def validate_span_dicts(spans: List[Dict]) -> Dict:
-    """Structural validation of a merged capture: trace-id population,
-    unresolvable parents, services and span names present. The bench
-    acceptance checks (one trace_id, no orphan parents, every tier
-    present) read this instead of re-deriving it."""
-    by_id = {s["span_id"]: s for s in spans}
-    orphans = [s["name"] for s in spans
-               if s.get("parent_id") and s["parent_id"] not in by_id]
-    return {
-        "n_spans": len(spans),
-        "trace_ids": sorted({s["trace_id"] for s in spans}),
-        "orphans": orphans,
-        "services": sorted({s["service"] for s in spans}),
-        "names": sorted({s["name"] for s in spans}),
-    }
-
-
 # --- device profiler hooks ------------------------------------------------
 
 

@@ -39,8 +39,8 @@ def enable_compile_cache() -> Optional[str]:
     ``site-packages``, possibly read-only — so there nothing is set and
     None is returned: a deployment names its cache with
     ``JAX_COMPILATION_CACHE_DIR`` (docs/DEPLOY.md). Called by the
-    process entry points that initialise JAX (chip_smoke.py, bench.py,
-    the trainer service, the serving binary, the example trainers),
+    process entry points that initialise JAX (chip_smoke.py, the
+    benchmark's run.py, the trainer service, the serving binary, the example trainers),
     before their first compilation; never at import time.
 
     JAX caches only compilations slower than 1 s by default. Model init
@@ -125,19 +125,16 @@ def run_command(cmd: List[str], env: Optional[dict] = None) -> subprocess.Popen:
     return subprocess.Popen(cmd, env=full_env)
 
 
-def arm_watchdog(max_seconds: int, label: str = "tool", on_fire=None):
+def arm_watchdog(max_seconds: int, label: str = "tool"):
     """Two-tier in-process watchdog for the chip-touching tools.
 
     A backend call that hangs inside native code cannot be interrupted
     from Python. Tier 1 (threading.Timer) dumps stacks and exits
     non-zero with a diagnostic — but needs the GIL, which a stuck
     native call may hold. Tier 2 (faulthandler's pure-C watchdog) needs
-    no GIL and hard-exits 60s later as the backstop. Used by bench.py,
-    the probes, and the PERSIA_TEST_TPU pytest runs (conftest).
-
-    ``on_fire``: optional callable run by tier 1 instead of the default
-    exit (bench.py passes its JSON-diagnostic emitter); it must
-    terminate the process itself. Returns a zero-arg ``cancel``.
+    no GIL and hard-exits 60s later as the backstop. Used by
+    chip_smoke.py and the PERSIA_TEST_TPU pytest runs (conftest).
+    Returns a zero-arg ``cancel``.
     """
     import faulthandler
     import sys
@@ -148,8 +145,6 @@ def arm_watchdog(max_seconds: int, label: str = "tool", on_fire=None):
               "dumping stacks and exiting non-zero",
               file=sys.stderr, flush=True)
         faulthandler.dump_traceback(file=sys.stderr)
-        if on_fire is not None:
-            on_fire()
         # raising in a timer thread wouldn't stop the main thread
         os._exit(17)
 

@@ -13,7 +13,8 @@ layer: a thread-safe registry of named variants with
   lands on the same variant on every serving replica, because the
   split is a pure function of ``(key, live weights)`` — no RNG, no
   per-replica state, so per-variant request counts are exactly
-  reproducible (bench.py --mode online pins them),
+  reproducible (``tests/test_online.py`` pins them against
+  ``expected_split``),
 - per-variant **status** (``live`` | ``draining``): a draining
   variant takes no new split traffic but still answers explicit
   requests (pinned sessions finish), which is what a safe rollback

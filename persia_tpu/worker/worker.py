@@ -635,12 +635,18 @@ class EmbeddingWorker:
         routing = self._routing.table
         groups = fwd_groups if fwd_groups is not None else mw.shard_split(
             feats, self.schema, routing.num_replicas, routing=routing)
-        # a group is shippable once its LAST feature (feature_idx is
-        # nondecreasing) has aggregated
-        by_last: Dict[int, list] = {}
-        for g in groups:
-            last_fi = int(g.feature_idx[-1]) if len(g.feature_idx) else 0
-            by_last.setdefault(last_fi, []).append(g)
+
+        def group_by_last(groups):
+            # a group is shippable once its LAST feature (feature_idx
+            # is nondecreasing) has aggregated
+            by_last: Dict[int, list] = {}
+            for g in groups:
+                last_fi = (int(g.feature_idx[-1]) if len(g.feature_idx)
+                           else 0)
+                by_last.setdefault(last_fi, []).append(g)
+            return by_last
+
+        by_last = group_by_last(groups)
         if len(by_last) <= 1:
             # uniform-dim schema: every group waits for the last feature
             # anyway, so "streaming" would only interleave gather with
@@ -654,6 +660,10 @@ class EmbeddingWorker:
             # runs inside the worker/update_stream span — capture it so
             # the fan-out ship threads parent their spans to it
             tctx = tracing.current_context()
+            live = self._live_table_if_moved(routing)
+            ship = by_last if live is None else group_by_last(
+                mw.shard_split(feats, self.schema, live.num_replicas,
+                               routing=live))
             futures = []
             per_feature: list = [None] * len(feats)
             agg_sec = 0.0
@@ -663,7 +673,7 @@ class EmbeddingWorker:
                     feat, self.schema.get_slot(feat.name), grads[feat.name],
                     loss_scale)
                 ready = [(g, mw.gather_group_grads(g, per_feature))
-                         for g in by_last.get(fi, ())]
+                         for g in ship.get(fi, ())]
                 agg_sec += time.perf_counter() - t0
                 # ship already-aggregated groups while the remaining
                 # features are still aggregating (fan-out threads do the
@@ -850,20 +860,43 @@ class EmbeddingWorker:
             # runs inside the worker/ship span — capture it so fan-out
             # threads parent their per-shard spans to it
             tctx = tracing.current_context()
-            if self._fanout is None or len(shard_groups) <= 1:
-                for shard, dim, signs, g in shard_groups:
+            live = self._live_table_if_moved(routing)
+            ship = shard_groups if live is None else mw.shard_gradients(
+                feats, self.schema, per_feature, live.num_replicas,
+                routing=live)
+            if self._fanout is None or len(ship) <= 1:
+                for shard, dim, signs, g in ship:
                     self._ship_group(shard, signs, g, dim, tctx)
                 return
             futures = [
                 self._fanout.submit(self._ship_group, shard, signs, g, dim,
                                     tctx)
-                for shard, dim, signs, g in shard_groups
+                for shard, dim, signs, g in ship
             ]
             for f in futures:
                 f.result()
 
         with self._t_ship.timer(), tracing.span("worker/ship"):
             self._with_ps_retry(do_update)
+
+    def _live_table_if_moved(self, split_by):
+        """The live routing table when it is no longer ``split_by``, the
+        table an update's shard groups were split by; else None. Asked
+        at the top of every fan-out attempt: a shipment that gives up in
+        :meth:`_settle_stale` (one call's retry ladder against a dead
+        replica can outlast the whole stale-retry budget) comes back
+        through :meth:`_with_ps_retry`, and a migration may have cut
+        over and finalized meanwhile. Groups split by the retired table
+        would land the moved signs on the donor's disarmed, unreachable
+        copies, acked: lost updates (the reshard kill matrix's donor
+        cells)."""
+        live = self._routing.table
+        if live.epoch == split_by.epoch:
+            return None
+        _logger.info("update fan-out retried across routing epochs "
+                     "(%d -> %d); re-splitting by the live table",
+                     split_by.epoch, live.epoch)
+        return live
 
     def _with_ps_retry(self, fn):
         """Run a PS fan-out, recovering from replica failures

@@ -3,8 +3,8 @@ as first-class bench drivers (ROADMAP item 5).
 
 See :mod:`persia_tpu.workloads.generator` for the data layer,
 :mod:`persia_tpu.workloads.models` for the dense towers, and
-:mod:`persia_tpu.workloads.registry` for the scenario registry that
-``bench.py --mode e2e --scenario {dlrm,seqrec,multitask}`` resolves.
+:mod:`persia_tpu.workloads.registry` for the scenario registry
+(``dlrm``, ``seqrec``, ``multitask``).
 """
 
 from persia_tpu.workloads.registry import (

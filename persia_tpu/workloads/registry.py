@@ -1,13 +1,13 @@
 """Scenario registry: production-shaped workloads as first-class bench
 drivers.
 
-A :class:`Scenario` bundles everything a bench or example needs to run
+A :class:`Scenario` bundles everything a test or example needs to run
 one zoo workload end to end on the existing stack — the embedding
 schema (dims, pooling modes), the dense tower, the deterministic batch
-generator, the loss, and the convergence gate the e2e smoke enforces.
-``bench.py --mode e2e --scenario {dlrm,seqrec,multitask}`` resolves
-through :func:`get_scenario`; examples import the same factories so
-tests, benches and the examples all train the ONE shared workload
+generator, the loss, and the AUC floor
+``tests/test_workloads.py::test_scenario_trains_through_hybrid_stack``
+holds it to. Tests, the trainer service and the examples resolve
+through :func:`get_scenario`, so all train the ONE shared workload
 definition.
 
 Scenario knobs: ``PERSIA_WORKLOAD_ALPHA`` (zipf skew) and

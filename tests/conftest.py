@@ -26,7 +26,7 @@ if os.environ.get("PERSIA_TEST_TPU") != "1":
     force_cpu_platform(8)
 else:
     # Chip-touching pytest runs get the same two-tier in-process
-    # watchdog as bench.py: a hung compile exits non-zero with stacks
+    # watchdog as chip_smoke.py: a hung compile exits non-zero with stacks
     # dumped instead of hanging the harness.
     #
     # The watchdog is RE-ARMED before every test rather than armed once

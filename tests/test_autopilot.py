@@ -410,3 +410,305 @@ def test_recommend_pilot_is_wire_neutral_against_live_ps():
         mon.stop()
         side.stop()
         svc.stop()
+
+
+# --- the closed loop against a live fleet ----------------------------------
+
+
+class _OneServerRegistry:
+    """Render view of the process registry restricted to one PS
+    server's labeled series. The replicas below run in-process and share
+    the process-wide registry; each sidecar must expose only ITS
+    replica's series (what separate processes would serve) or the fleet
+    sum and the per-replica share breakdown would count every replica
+    four times."""
+
+    def __init__(self, base, server_label):
+        self._base = base
+        self._needle = f'server="{server_label}"'
+
+    def histogram(self, *a, **kw):
+        return self._base.histogram(*a, **kw)
+
+    def render(self):
+        keep = [line for line in self._base.render().splitlines()
+                if line.startswith("#") or self._needle in line]
+        return "\n".join(keep) + "\n"
+
+
+def test_scripted_ramp_drives_live_fleet_through_three_actions(tmp_path):
+    """Telemetry → planner → operator, unattended: a scripted load/skew
+    ramp drives a live counting-optimizer PS fleet (4 in-process
+    replicas, each behind its own sidecar) while an ENFORCE pilot and a
+    shadow RECOMMEND pilot tick over the same fleet monitor. The pilot
+    acts through the k8s operator's drivers with a live
+    ReshardController doing the slot migration. Held to:
+
+    - exactly scale_out (2→3) → rebalance → scale_in (3→2), every
+      action's deferred verification ``improved``, none regressed or
+      failed, the fleet back at 2 replicas;
+    - zero lost updates across all three (the counting identity);
+    - the shadow pilot, stepped at the same (now, alerts) instants,
+      decides (policy, kind, action) exactly as enforce did;
+    - the on-disk journal re-reads one decision per action with the
+      history excerpt (and, for scale actions, the firing rules).
+
+    Thresholds are fractions of this machine's own measured unpaced row
+    rate, so the ramp crosses the same hysteresis bands on a loaded
+    runner as on a fast workstation."""
+    import threading
+    import time
+
+    import numpy as np
+
+    from persia_tpu.config import EmbeddingSchema, uniform_slots
+    from persia_tpu.data.batch import IDTypeFeature
+    from persia_tpu.fleet import FleetMonitor
+    from persia_tpu.k8s_operator import FakeKubeApi, Operator
+    from persia_tpu.metrics import default_registry
+    from persia_tpu.obs_http import ObservabilityServer
+    from persia_tpu.ps.store import EmbeddingHolder
+    from persia_tpu.reshard import ReshardController
+    from persia_tpu.routing import RoutingTable
+    from persia_tpu.service.ps_service import PsClient, PsService
+    from persia_tpu.slos import default_rules
+    from persia_tpu.worker.worker import EmbeddingWorker
+    from tests.fleet_support import (
+        arm_counting,
+        owner_filtered_applied,
+        time_limit,
+        unit_update,
+    )
+
+    SCRAPE, WINDOW = 0.2, 1.6
+    dim, n_feats, n_threads, bs = 8, 2, 2, 256
+    job, sign_space = "bench", 1 << 20
+    schema = EmbeddingSchema(slots_config=uniform_slots(
+        [f"slot_{i}" for i in range(n_feats)], dim=dim))
+
+    holders, services, clients, sidecars = [], [], [], []
+    for i in range(4):
+        h = EmbeddingHolder(capacity=2_000_000, hotness=True)
+        svc = PsService(h, port=0)
+        svc.server.serve_background()
+        c = PsClient(svc.addr, circuit_breaker=False)
+        arm_counting(c)
+        sidecars.append(ObservabilityServer(
+            port=0,
+            registry=_OneServerRegistry(default_registry(),
+                                        svc.addr.rsplit(":", 1)[1]),
+            health_fn=svc._health, service=f"ps{i}",
+            refresh_fn=svc._refresh_mem_gauges,
+            hotness_fn=svc._hotness_snapshot).start())
+        holders.append(h)
+        services.append(svc)
+        clients.append(c)
+
+    table = RoutingTable.uniform(2)
+    worker = EmbeddingWorker(schema, clients[:2], routing=table)
+    controller = ReshardController(clients[:2], table, workers=[worker],
+                                   replay_settle_rows=64, drain_sec=0.25)
+    last_table = [table]
+    jdir = str(tmp_path / "journal")
+    monitor = FleetMonitor(
+        targets=[{"service": f"ps{i}", "http_addr": s.addr,
+                  "role": "ps", "replica": i}
+                 for i, s in enumerate(sidecars)],
+        scrape_interval=SCRAPE, scrape_timeout=1.0, flight_interval=4.0,
+        slo_engine=SloEngine(default_rules()),
+        postmortem_dir=str(tmp_path / "postmortems"))
+
+    def reshard_driver(job_name, old, new, phase, spec):
+        if phase == "resume":
+            return
+        if phase == "rebalance":
+            plan = monitor.hotness_plan(old, current_table=last_table[0])
+            last_table[0] = controller.reshard_to(
+                old, slot_weights=np.asarray(plan["slot_weights"],
+                                             np.float64))
+        elif phase == "scale_out":
+            last_table[0] = controller.reshard_to(
+                new, new_ps_clients=clients[:new])
+        else:  # scale_in
+            last_table[0] = controller.reshard_to(new)
+
+    operator = Operator(FakeKubeApi(), [{
+        "jobName": job,
+        "image": "persia-tpu-runtime:bench",
+        "embeddingConfigPath": "/config/embedding_config.yml",
+        "roles": {
+            "embeddingParameterServer": {"replicas": 2},
+            "embeddingWorker": {"replicas": 1},
+            "nnWorker": {"replicas": 1, "entry": "train.py"},
+        },
+    }], interval=60.0, reshard_driver=reshard_driver)
+
+    # paced trainer threads: the offered load the script ramps
+    ships = [0]
+    s_lock = threading.Lock()
+    stop = threading.Event()
+    errors = []
+    period_box = [0.0]  # per-thread seconds/cycle; 0 = unpaced
+    hot_box = [np.zeros(0, dtype=np.uint64)]
+
+    def draws(rng):
+        if len(hot_box[0]):
+            n_hot = int(bs * 0.75)
+            return [np.concatenate([
+                rng.choice(hot_box[0], size=n_hot),
+                rng.integers(0, sign_space, bs - n_hot, dtype=np.uint64)])
+                for _ in range(n_feats)]
+        return [rng.integers(0, sign_space, bs, dtype=np.uint64)
+                for _ in range(n_feats)]
+
+    def train(seed):
+        rng = np.random.default_rng(seed)
+        while not stop.is_set():
+            feats = [IDTypeFeature(f"slot_{i}", [r])
+                     for i, r in enumerate(draws(rng))]
+            t0 = time.perf_counter()
+            try:
+                unit_update(worker, feats)
+            except Exception as e:  # noqa: BLE001
+                errors.append(e)
+                return
+            with s_lock:
+                ships[0] += n_feats * bs
+            spare = period_box[0] - (time.perf_counter() - t0)
+            if spare > 0:
+                time.sleep(spare)
+
+    threads = [threading.Thread(target=train, args=(s,))
+               for s in range(n_threads)]
+    for t in threads:
+        t.start()
+    enf_decisions, rec_decisions = [], []
+    try:
+        with time_limit(120, "autopilot scripted ramp"):
+            # calibration: this machine's unpaced row rate
+            t_cal0, ships0 = time.monotonic(), ships[0]
+            while time.monotonic() - t_cal0 < 1.0:
+                time.sleep(SCRAPE)
+                monitor.scrape_once()
+            cal_sec = time.monotonic() - t_cal0
+            m_cycles = max(
+                (ships[0] - ships0) / (n_feats * bs) / cal_sec, 1.0)
+            m_rows = monitor.history.avg_over(
+                "ps_lookup_row_rate", 0.8, r"^ps", time.monotonic())
+            assert m_rows and m_rows > 0, \
+                "calibration saw no ps_lookup_row_rate"
+
+            def mk_pilot(mode, journal_dir=None):
+                # the cooldown holds a repeat of the SAME (policy, kind)
+                # and never delays the next kind: longer than the whole
+                # script, so the near-idle tail (3-5 s after the
+                # rebalance) cannot draw a second rebalance from noise
+                return Autopilot(
+                    monitor, operator, job,
+                    policies=[
+                        PsScalePolicy(job, scale_out_at=0.30 * m_rows,
+                                      scale_in_below=0.15 * m_rows,
+                                      window_sec=WINDOW, min_replicas=2,
+                                      max_replicas=3, verify_sec=1.6),
+                        RebalancePolicy(job, share_threshold=0.60,
+                                        hold_sec=0.8, min_gain=0.05,
+                                        window_sec=1.2, verify_sec=1.6)],
+                    mode=mode, journal_dir=journal_dir, cooldown_sec=30.0,
+                    max_actions_per_hour=6,
+                    table_fn=lambda: last_table[0])
+
+            # shadow FIRST each tick: it must read the world as enforce
+            # will the instant before enforcement mutates it
+            shadow = mk_pilot("recommend")
+            pilot = mk_pilot("enforce", journal_dir=jdir)
+
+            def journaled(kind):
+                return [r for r in pilot.journal.tail(256)
+                        if r["kind"] == kind]
+
+            def executed_kinds():
+                return [r["action_kind"] for r in journaled("executed")]
+
+            def drive(frac, done_fn, max_sec, label):
+                """One script phase: pace the trainers at ``frac`` of
+                the calibrated rate, scrape + tick both pilots every
+                round, until ``done_fn`` (or, without one, for
+                ``max_sec``)."""
+                period_box[0] = n_threads / (frac * m_cycles)
+                t_end = time.monotonic() + max_sec
+                while time.monotonic() < t_end:
+                    time.sleep(SCRAPE)
+                    assert not errors, \
+                        f"trainer thread died during {label}: {errors[0]!r}"
+                    monitor.scrape_once()
+                    now = time.monotonic()
+                    alerts = monitor.engine.evaluate(now)
+                    rec_decisions.extend(shadow.tick(now, alerts))
+                    enf_decisions.extend(pilot.tick(now, alerts))
+                    if done_fn is not None and done_fn():
+                        return
+                assert done_fn is None, (
+                    f"autopilot script never reached '{label}' within "
+                    f"{max_sec:.0f}s (executed so far: "
+                    f"{executed_kinds()})")
+
+            # 1. quiet warm-up fills the sustained() windows; the low
+            # rule fires but 2 replicas is already the floor
+            drive(0.10, None, 1.8, "warmup")
+            assert not executed_kinds(), "acted during quiet warm-up"
+            # 2. sustained surge -> scale_out 2→3
+            drive(0.55, lambda: "scale_out" in executed_kinds(), 15.0,
+                  "scale_out")
+            # 3. hot-key skew on replica 0 -> rebalance at 3
+            cand = np.random.default_rng(7).integers(
+                0, sign_space, 8192, dtype=np.uint64)
+            hot_box[0] = cand[last_table[0].replica_of(cand) == 0][:512]
+            drive(0.25, lambda: "rebalance" in executed_kinds(), 18.0,
+                  "rebalance")
+            # 4. sustained calm -> scale_in 3→2
+            hot_box[0] = np.zeros(0, dtype=np.uint64)
+            drive(0.05, lambda: "scale_in" in executed_kinds(), 15.0,
+                  "scale_in")
+            # 5. settle until every deferred verification lands
+            drive(0.05, lambda: len(journaled("outcome")) >= 3, 10.0,
+                  "outcome verification")
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(timeout=60)
+    try:
+        assert not errors, f"trainer thread died: {errors[0]!r}"
+        assert not any(t.is_alive() for t in threads)
+        controller.finalize(drain_sec=0.0)
+        applied = owner_filtered_applied(holders, last_table[0], dim)
+        assert abs(ships[0] - applied) <= 1e-3, (ships[0], applied)
+
+        by_kind = {}
+        for r in ActionJournal(jdir).records():
+            by_kind.setdefault(r["kind"], []).append(r)
+        assert [r["action_kind"] for r in by_kind.get("executed", [])] \
+            == ["scale_out", "rebalance", "scale_in"]
+        assert len([r for r in by_kind.get("outcome", [])
+                    if r.get("improved")]) >= 3
+        assert not by_kind.get("regressed")
+        assert not by_kind.get("action_failed")
+        assert operator.ps_replicas(job) == 2
+
+        def key(ds):
+            return [(d["policy"], d["kind"], d["action"]) for d in ds]
+
+        assert key(rec_decisions) == key(enf_decisions)
+
+        decisions = [r["decision"] for r in by_kind.get("decision", [])]
+        assert len(decisions) == 3
+        for d in decisions:
+            ev = d.get("evidence", {})
+            assert ev.get("history"), d
+            if d["kind"] in ("scale_out", "scale_in"):
+                assert ev.get("firing_rules"), d
+    finally:
+        worker.close()
+        for s in services:
+            s.stop()
+        for side in sidecars:
+            side.stop()

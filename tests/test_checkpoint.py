@@ -105,6 +105,51 @@ def test_incremental_update_roundtrip(tmp_path):
                                       train_h.get_entry(int(s))[1])
     # idempotent: second scan loads nothing new
     assert loader.scan_once() == 0
+    # a dumper SIGKILLed mid-packet leaves a .tmp directory whose
+    # marker may exist and be empty: a restore must pass over it
+    torn = tmp_path / "inc" / "inc_29991231235959_000001_r0_p1.tmp"
+    torn.mkdir()
+    (torn / "inc_update_done").write_text("")
+    (torn / "0.inc").write_bytes(b"")
+    assert loader.scan_once() == 0
+
+
+def test_concurrent_flushes_replay_in_the_order_their_rows_were_read(
+        tmp_path):
+    """Packets replay in name order and hold the rows as they read at
+    dump time. With several update handlers flushing at once, a packet
+    named later must never carry an OLDER row than one named earlier,
+    or a restore ends on the old value and an acked update is lost.
+    Counting arm: a row only ever decreases."""
+    import threading
+
+    h = EmbeddingHolder(capacity=10_000, num_internal_shards=2)
+    h.configure("bounded_uniform", {"lower": 0.0, "upper": 0.0},
+                1.0, 1e9, False)
+    h.register_optimizer({"type": "sgd", "lr": 1.0, "wd": 0.0})
+    inc = str(tmp_path / "inc")
+    dumper = IncrementalUpdateDumper(h, inc, buffer_size=1)
+    signs = np.arange(1, 33, dtype=np.uint64)
+    h.lookup(signs, 4, training=True)
+
+    def handler():
+        for _ in range(100):
+            h.update_gradients(signs, np.ones((32, 4), np.float32), 4)
+            dumper.commit(signs)
+
+    threads = [threading.Thread(target=handler) for _ in range(4)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    replayed = {}
+    for name in sorted(os.listdir(inc)):
+        for sign, _d, vec in iter_psd_entries(
+                os.path.join(inc, name, "0.inc")):
+            assert vec[0] <= replayed.get(sign, 0.0), (name, sign)
+            replayed[sign] = vec[0]
+    for s in signs:
+        assert replayed[int(s)] == h.get_entry(int(s))[1][0] == -400.0
 
 
 def test_metrics_registry_render():

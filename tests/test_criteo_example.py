@@ -1,5 +1,5 @@
 """Criteo example: format parsing + end-to-end training smoke
-(the BASELINE.json workload's entry point)."""
+(the reference workload's entry point)."""
 
 import importlib.util
 import pathlib

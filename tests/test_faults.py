@@ -118,7 +118,7 @@ def test_injected_corrupt_frame_fails_request_not_connection():
 
 def test_remote_fault_control_rpc(monkeypatch):
     """__faults__ control surface (PERSIA_FAULTS_RPC=1): a peer can arm
-    and clear rules in a live server process — how the chaos bench
+    and clear rules in a live server process — how a chaos test
     slows one shard of a running PS without restarting it."""
     monkeypatch.setenv("PERSIA_FAULTS_RPC", "1")
     srv = RpcServer()
@@ -254,8 +254,12 @@ def test_circuit_breaker_probe_cadence_is_jittered():
     waiting, and the probe flips to success after a few rounds so the
     loop terminates deterministically."""
     rounds = []
+    looked = threading.Event()
 
     def probe():
+        # with no real sleeps the probe thread would reach half_open
+        # before this test has looked at the open state
+        looked.wait(5.0)
         rounds.append(1)
         return len(rounds) > 4  # fail 4 probes, then recover
 
@@ -265,6 +269,7 @@ def test_circuit_breaker_probe_cadence_is_jittered():
     br._sleep = sleeps.append  # fake clock: record, don't wait
     br.record_failure()
     assert br.state == "open"
+    looked.set()
     deadline = time.monotonic() + 5.0
     while br.state != "half_open" and time.monotonic() < deadline:
         time.sleep(0.01)
@@ -568,21 +573,12 @@ def test_supervised_ps_kill_restart_restores_checkpoint_plus_inc(
         # crash postmortem bundle: written before the respawn, from the
         # last observed flight snapshot
         bundle = events[0].get("postmortem")
-        assert bundle and os.path.isdir(bundle), events[0]
+        from tests.fleet_support import validate_postmortem
+
+        health = validate_postmortem(bundle)
+        assert health["model_manager_status"] == "Idle"
         import json
 
-        with open(os.path.join(bundle, "health.json")) as f:
-            health = json.load(f)
-        assert health["model_manager_status"] == "Idle"
-        with open(os.path.join(bundle, "trace.json")) as f:
-            trace = json.load(f)
-        xs = [e for e in trace["traceEvents"] if e["ph"] == "X"]
-        assert xs, "postmortem trace is empty"
-        ids = {e["args"]["span_id"] for e in xs}
-        assert all(not e["args"].get("parent_id")
-                   or e["args"]["parent_id"] in ids
-                   for e in xs), "orphan parents in postmortem trace"
-        assert os.path.getsize(os.path.join(bundle, "metrics.prom")) > 0
         with open(os.path.join(bundle, "reason.json")) as f:
             assert json.load(f)["service"] == "ps1"
         for _ in range(4):

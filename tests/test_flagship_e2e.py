@@ -1,4 +1,4 @@
-"""Flagship service-mode e2e: the BASELINE config-3 shape end to end.
+"""Flagship service-mode e2e: the sharded-PS, data-parallel-dense shape end to end.
 
 ServiceCtx cluster (2 embedding workers + 2 C++ `persia-embedding-ps`
 binaries) + two Criteo data-loader replicas streaming learnable batches

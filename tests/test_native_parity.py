@@ -131,6 +131,26 @@ def test_native_lru_eviction():
     assert len(cc) == 8
 
 
+def test_native_eviction_drops_cold_rows_and_keeps_recent_ones():
+    """Pushed 20 % past capacity, the store stays at capacity, rows not
+    touched since the fill are gone (an eval lookup zero-fills them) and
+    recently updated rows keep their values."""
+    capacity, dim = 8192, 4
+    cc = NativeEmbeddingHolder(capacity=capacity, num_internal_shards=4)
+    cc.configure("bounded_uniform", {"lower": -0.01, "upper": 0.01})
+    cc.register_optimizer({"type": "sgd", "lr": 0.1})
+    cc.lookup(np.arange(1, capacity + 1, dtype=np.uint64), dim, True)
+    cold = np.arange(1, 513, dtype=np.uint64)
+    recent = np.arange(capacity - 511, capacity + 1, dtype=np.uint64)
+    cc.update_gradients(recent, np.full((512, dim), 5.0, np.float32), dim)
+    before = cc.lookup(recent, dim, False).copy()
+    cc.lookup(np.arange(capacity + 1, capacity + 1 + capacity // 5,
+                        dtype=np.uint64), dim, True)
+    assert len(cc) <= capacity
+    assert (cc.lookup(cold, dim, False) == 0).all()
+    np.testing.assert_array_equal(cc.lookup(recent, dim, False), before)
+
+
 def test_native_update_missing_sign_counts():
     _, cc = _pair()
     cc.lookup(np.array([1], dtype=np.uint64), 4, True)

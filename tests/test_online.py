@@ -688,3 +688,145 @@ def test_obs_http_variants_endpoint(serving_world):
         assert hz["variants"][0]["name"] == "prod"
     finally:
         server.stop()
+
+
+# --- the serving loop over real PS sockets --------------------------------
+
+
+def test_serving_loop_split_oracle_freshness_and_idle_wire(tmp_path):
+    """The online loop's pass/fail gates over real PS services (sockets,
+    inc-dumper armed), one in-process worker over PsClients:
+
+    - a trained probe update becomes servable through the delta
+      subscriber alone (TTL effectively infinite), and the subscriber
+      accounts for it;
+    - a two-variant weighted split serves per-variant request counts
+      EXACTLY as the deterministic oracle predicts, the server's own
+      counters agree, explicit canary traffic never moves the base
+      counter, and each variant bit-matches its single-model server;
+    - with the subsystem off (no subscriber, one variant) the predict
+      response meta is empty and cache-hot predicts plus an idle window
+      add ZERO PS RPCs; a subscriber scan adds none either (the packet
+      stream is disk, not RPC)."""
+    import jax
+
+    from persia_tpu.models import DLRM
+    from persia_tpu.rpc import unpack_arrays
+    from persia_tpu.service.ps_service import PsClient, PsService
+    from persia_tpu.serving import (
+        InferenceClient,
+        InferenceServer,
+        build_state_template,
+    )
+    from tests.fleet_support import time_limit, unit_update, wait_until
+
+    inc_dir = str(tmp_path / "inc")
+    os.makedirs(inc_dir)
+    scan_sec = 0.15
+    schema = EmbeddingSchema(slots_config=uniform_slots(
+        [f"slot_{s}" for s in range(N_SLOTS)], dim=DIM))
+    holders = [EmbeddingHolder(2_000_000, 8) for _ in range(2)]
+    # huge buffer: the test controls flush timing
+    dumpers = [IncrementalUpdateDumper(h, inc_dir, buffer_size=1 << 30,
+                                       replica_index=i)
+               for i, h in enumerate(holders)]
+    services = [PsService(h, port=0, inc_dumper=d)
+                for h, d in zip(holders, dumpers)]
+    for s in services:
+        s.server.serve_background()
+    worker = EmbeddingWorker(
+        schema, [PsClient(s.addr, circuit_breaker=False)
+                 for s in services])
+    worker.configure_parameter_servers(
+        "bounded_uniform", {"lower": -0.01, "upper": 0.01}, 1.0, 1e9)
+    worker.register_optimizer({"type": "sgd", "lr": 0.1, "wd": 0.0})
+    model = DLRM(embedding_dim=DIM)
+    state = build_state_template(model, schema, N_DENSE)
+    probe = _request(8, 1)
+    hot = [_request(32, 100 + i) for i in range(4)]
+    for b in [probe] + hot:  # create every row the test will read
+        worker.lookup_direct(b.id_type_features, training=True)
+    probe_blob = probe.to_bytes()
+    hot_blobs = [b.to_bytes() for b in hot]
+    servers = []
+
+    def serve(st=state, **kw):
+        s = InferenceServer(model, st, schema, worker=worker, **kw)
+        s.serve_background()
+        servers.append(s)
+        return s, InferenceClient(s.addr)
+
+    def ps_served():
+        return [s.server.health()["served_rpcs"] for s in services]
+
+    try:
+        with time_limit(120, "online serving loop"):
+            # --- freshness through the subscriber alone ----------------
+            online, oc = serve(cache_rows=500_000, cache_ttl_sec=3600.0)
+            online.attach_delta_subscriber(inc_dir,
+                                           scan_interval_sec=scan_sec)
+            before = oc.predict_bytes(probe_blob).tobytes()
+            unit_update(worker, probe.id_type_features)
+            for d in dumpers:
+                d.flush()
+            wait_until(
+                lambda: oc.predict_bytes(probe_blob).tobytes() != before,
+                30, "probe update never became servable", interval=0.02)
+            sub = online.online
+            assert sub.packets_applied > 0 and sub.rows_applied > 0
+
+            # --- two-variant weighted split ----------------------------
+            var_server, vc = serve(cache_rows=200_000, cache_ttl_sec=600.0,
+                                   variant_name="base")
+            # the canary: same architecture, perturbed dense params, so
+            # its predictions differ and the bit-match attributes
+            canary_state = state.replace(params=jax.tree_util.tree_map(
+                lambda a: a + 0.1, state.params))
+            var_server.add_variant("canary", state=canary_state,
+                                   weight=0.25)
+            var_server.variants.set_weight("base", 0.75)
+            keys = [f"user-{i}".encode() for i in range(80)]
+            expected = var_server.variants.expected_split(keys)
+            served = {}
+            for k in keys:
+                _, name = vc.predict_variant(probe_blob, key=k)
+                served[name] = served.get(name, 0) + 1
+            assert served == expected
+
+            def counters():
+                return {v["name"]: v["requests"]
+                        for v in var_server._variants_doc()}
+
+            assert counters() == expected
+            for _ in range(20):
+                _, name = vc.predict_variant(probe_blob, variant="canary")
+                assert name == "canary"
+            assert counters() == {"base": expected["base"],
+                                  "canary": expected["canary"] + 20}
+            for name, st in (("base", state), ("canary", canary_state)):
+                _solo, sc = serve(st)
+                got, served_by = vc.predict_variant(probe_blob,
+                                                    variant=name)
+                assert served_by == name
+                np.testing.assert_array_equal(
+                    got, sc.predict_bytes(probe_blob))
+
+            # --- idle wire: subsystem off ------------------------------
+            _off, fc = serve(cache_rows=200_000, cache_ttl_sec=3600.0)
+            for blob in hot_blobs:  # warm pass fetches every row once
+                fc.predict_bytes(blob)
+            served0 = ps_served()
+            metas = set()
+            for i in range(30):
+                meta, _arrs = unpack_arrays(fc.client.call(
+                    "predict", hot_blobs[i % len(hot_blobs)]))
+                metas.add(tuple(sorted(meta.items())))
+            time.sleep(scan_sec * 3)  # an idle window
+            assert ps_served() == served0
+            assert metas == {()}
+            sub.scan_once()
+            assert ps_served() == served0
+    finally:
+        for s in servers + services:
+            s.stop()
+        worker.close()

@@ -509,6 +509,54 @@ def test_health_reports_resident_bytes_and_row_dtype():
         svc.stop()
 
 
+@pytest.mark.parametrize("backend", ["arena", "native"])
+def test_fp16_stack_cuts_wire_and_resident_bytes(backend):
+    """The policy's byte budgets over a live PS service, for the Python
+    arena and the native store alike: identical lookup+update cycles
+    through an fp32 / codec-off stack and an fp16 / fp16+int8 stack
+    admit the same rows, put >= 1.4x fewer payload bytes on the wire,
+    hold >= 1.8x fewer embedding bytes resident, and read back within
+    the documented int8-wire parity budget."""
+    from persia_tpu.ps.native import make_holder
+
+    def stack(row_dtype, codec):
+        # "native" builds the library on demand and raises without it:
+        # a machine that cannot build it fails this case, never skips it
+        h = make_holder(100_000, 4, row_dtype=row_dtype, backend=backend)
+        h.configure("bounded_uniform", {"lower": -0.01, "upper": 0.01})
+        h.register_optimizer(dict(ADAGRAD))
+        svc = _svc(h)
+        return svc, PsClient(svc.addr, wire_codec=codec)
+
+    (svc_a, full), (svc_b, half) = stack("fp32", "off"), stack(
+        "fp16", "fp16+int8")
+    try:
+        rng = np.random.default_rng(0)
+        work = [(rng.integers(1, 1 << 40, 1024, dtype=np.uint64),
+                 rng.normal(size=(1024, 4 * DIM)).astype(np.float32))
+                for _ in range(4)]
+        for c in (full, half):
+            c.lookup(work[0][0], 4 * DIM, True)  # dial + negotiate
+        sent0 = {c: sum(c.wire_stats().values()) for c in (full, half)}
+        for c in (full, half):
+            for signs, grads in work:
+                c.lookup(signs, 4 * DIM, True)
+                c.update_gradients(signs, grads, 4 * DIM)
+        wire = {c: sum(c.wire_stats().values()) - sent0[c]
+                for c in (full, half)}
+        docs = {c: c.health() for c in (full, half)}
+        assert docs[full]["holder_entries"] == docs[half]["holder_entries"]
+        assert wire[full] / wire[half] >= 1.4, wire
+        assert (docs[full]["resident_emb_bytes"]
+                / docs[half]["resident_emb_bytes"]) >= 1.8
+        a = full.lookup(work[0][0], 4 * DIM, False)
+        b = half.lookup(work[0][0], 4 * DIM, False)
+        assert np.abs(a - b).max() / np.abs(a).max() <= 2e-1
+    finally:
+        svc_a.stop()
+        svc_b.stop()
+
+
 def test_old_native_so_negotiates_down_loudly(monkeypatch):
     """An OLD pre-arena ``.so`` (no ptps_new2 and friends) asked for a
     policy it cannot store must negotiate DOWN to the Python arena

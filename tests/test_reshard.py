@@ -76,6 +76,14 @@ def test_pack_unpack_rows_round_trip():
     for (_, _, a), (_, _, b) in zip(rows, back):
         np.testing.assert_array_equal(a, b)
     assert unpack_rows(pack_rows([])) == []
+    # the wire format itself: <Q count, then per row <QII sign, dim,
+    # len and the f32 payload — the vectorized packer is held to it
+    import struct
+
+    want = struct.pack("<Q", len(rows)) + b"".join(
+        struct.pack("<QII", s, d, len(v)) + v.tobytes()
+        for s, d, v in rows)
+    assert bytes(pack_rows(rows)) == want
 
 
 def test_plan_assignment_moves_minimally():
@@ -128,7 +136,9 @@ def test_placement_plan_beats_hash_even_under_zipf():
     snap = _zipf_snapshot()
     plan = placement_plan(snap, 4, num_slots=64)
     assert plan["max_replica_share"] < plan["hash_even_max_share"]
-    assert abs(sum(plan["replica_shares"]) - 1.0) < 1e-6
+    # each share is rounded to six places for the report, so the sum
+    # may be off by half a unit in the last place per replica
+    assert abs(sum(plan["replica_shares"]) - 1.0) <= 4 * 0.5e-6 + 1e-12
     assert len(plan["assignment"]) == 64
     # the weights the plan balanced really concentrate: the head slot
     # outweighs the uniform-share floor
@@ -210,16 +220,9 @@ def test_live_reshard_2_to_3_zero_lost_updates():
     # count ONLY rows where the new table routes them: donors keep
     # frozen stale copies of moved rows through the double-read window
     # (by design), and those must not double-count
-    applied = 0.0
-    for i, h in enumerate(holders):
-        rows = [(s, -float(vec[:dim].sum()) / DIM)
-                for shard in h._shards
-                for s, (dim, vec) in shard._map.items()]
-        if not rows:
-            continue
-        owners = new_table.replica_of(
-            np.array([s for s, _ in rows], np.uint64))
-        applied += sum(v for (_s, v), o in zip(rows, owners) if o == i)
+    from tests.fleet_support import owner_filtered_applied
+
+    applied = owner_filtered_applied(holders, new_table, DIM)
     assert abs(applied - ships[0]) < 1e-3, (applied, ships[0])
     # --- rows live where the new table routes them --------------------
     all_signs = []
@@ -238,6 +241,183 @@ def test_live_reshard_2_to_3_zero_lost_updates():
     worker.close()
     for s in services:
         s.stop()
+
+
+def test_live_reshard_dance_2_to_4_to_3_zero_lost_updates():
+    """Grow then shrink under traffic: two trainer threads hammer
+    lookup+update through the worker while the tier goes 2→4→3. The
+    counting identity must hold exactly across BOTH cutovers, counted
+    over rows at their final owners."""
+    from persia_tpu.service.ps_service import PsClient
+    from tests.fleet_support import (
+        owner_filtered_applied,
+        unit_update,
+        wait_until,
+    )
+
+    holders = [_holder(2_000_000) for _ in range(4)]
+    services = [_service(h) for h in holders]
+    clients = [PsClient(s.addr, circuit_breaker=False) for s in services]
+    for c in clients:
+        _arm(c)
+    table = RoutingTable.uniform(2)
+    worker = EmbeddingWorker(_schema(), clients[:2], routing=table)
+    bs = 256
+    ships = [0]
+    ship_lock = threading.Lock()
+    stop = threading.Event()
+    errors = []
+
+    def train(seed):
+        rng = np.random.default_rng(seed)
+        while not stop.is_set():
+            feats = [_feature(f"slot_{i}",
+                              rng.integers(0, 1 << 20, bs,
+                                           dtype=np.uint64))
+                     for i in range(2)]
+            try:
+                unit_update(worker, feats)
+            except Exception as e:  # noqa: BLE001
+                errors.append(e)
+                return
+            with ship_lock:
+                ships[0] += 2 * bs
+
+    def quiet():
+        base = ships[0]
+        wait_until(lambda: errors or ships[0] >= base + 8 * 2 * bs, 30,
+                   "no live traffic between the cutovers")
+
+    threads = [threading.Thread(target=train, args=(s,))
+               for s in range(2)]
+    for t in threads:
+        t.start()
+    controller = ReshardController(clients[:2], table, workers=[worker],
+                                   replay_settle_rows=64, drain_sec=0.25)
+    try:
+        quiet()
+        t4 = controller.reshard_to(4, new_ps_clients=clients)
+        quiet()
+        t3 = controller.reshard_to(3)
+        quiet()
+    finally:
+        stop.set()
+        for t in threads:
+            t.join(timeout=60)
+    try:
+        assert not errors, f"trainer thread died mid-reshard: {errors[0]!r}"
+        assert not any(t.is_alive() for t in threads), \
+            "trainer thread wedged across the reshard"
+        controller.finalize(drain_sec=0.0)
+        assert (t4.num_replicas, t3.num_replicas) == (4, 3)
+        assert worker.routing_epoch == t3.epoch == t4.epoch + 1
+        applied = owner_filtered_applied(holders, t3, DIM)
+        assert abs(ships[0] - applied) <= 1e-3, (ships[0], applied)
+    finally:
+        worker.close()
+        for s in services:
+            s.stop()
+
+
+@pytest.mark.xfail(strict=True, reason=(
+    "ROADMAP Design 14: an update for a row that is no longer there is "
+    "skipped, counted in gradient_id_miss_count and acked"))
+def test_update_for_a_row_lost_before_it_lands_is_not_acked():
+    """A training lookup creates a row in memory only; the replica then
+    loses it before the cycle's update arrives (a SIGKILL and a restore
+    from a checkpoint that never saw the row; found by the reshard kill
+    matrix under load). The trainer must either hear of it or find the
+    update applied, and today it gets neither: the miss is a counter."""
+    from persia_tpu.service.ps_service import PsClient
+
+    holder = _holder()
+    service = _service(holder)
+    client = PsClient(service.addr, circuit_breaker=False)
+    _arm(client)
+    worker = EmbeddingWorker(_schema(n_slots=1), [client])
+    signs = np.arange(1, 9, dtype=np.uint64)
+    try:
+        ref, out = worker.lookup_direct_training([_feature("slot_0", signs)])
+        assert len(holder) == len(signs)
+        holder.clear()  # the process died; its restore has no such row
+        try:
+            worker.update_gradients(ref, {
+                k: np.ones_like(v.embeddings) for k, v in out.items()})
+        except Exception:  # noqa: BLE001 — told: the trainer can retry
+            return
+        got = -worker.lookup_signs(signs, DIM).sum(axis=1) / DIM
+        assert (got == 1).all(), (
+            f"acked and not applied: {got} "
+            f"(gradient_id_miss_count={holder.gradient_id_miss_count})")
+    finally:
+        worker.close()
+        service.stop()
+
+
+def test_hotness_balanced_table_serves_lower_max_share_than_hash_even():
+    """The placement plan, measured and not only planned: zipf(1.05)
+    traffic with a hot set in every batch goes through a 4-replica
+    fleet under uniform hash-even routing and under the table planned
+    from the fleet's OWN merged sketches. Load is counted server-side
+    (per-replica hotness totals = signs actually served); the balanced
+    table's busiest replica must serve a smaller share."""
+    from persia_tpu import hotness, knobs
+    from persia_tpu.ps.store import EmbeddingHolder
+    from persia_tpu.service.ps_service import PsClient
+
+    services = [_service(EmbeddingHolder(capacity=2_000_000, hotness=True))
+                for _ in range(4)]
+    clients = [PsClient(s.addr, circuit_breaker=False) for s in services]
+    for c in clients:
+        _arm(c)
+    spr = int(knobs.get("PERSIA_ROUTING_SLOTS_PER_REPLICA"))
+    even = RoutingTable(1, np.arange(4 * spr, dtype=np.int32) % 4, 4)
+    worker = EmbeddingWorker(_schema(), clients, routing=even)
+    rng = np.random.default_rng(11)
+    # the serving tier dedups per batch, so slot-level skew comes from
+    # hot signs CLUSTERING on slots: ~128 hot signs over 256 slots
+    # hands some replica 2-3x its fair share under hash-even
+    hot_p = np.arange(1, 129, dtype=np.float64) ** -1.05
+    hot_p /= hot_p.sum()
+    with np.errstate(over="ignore"):
+        hot_pool = (np.arange(1, 129, dtype=np.uint64)
+                    * np.uint64(0x9E3779B97F4A7C15)) >> np.uint64(1)
+
+    def zipf_feats(bs=256):
+        n_hot = int(bs * 0.7)
+        signs = np.concatenate([
+            rng.choice(hot_pool, size=n_hot, p=hot_p),
+            rng.integers(1 << 30, 1 << 40, bs - n_hot, dtype=np.uint64)])
+        return [_feature(f"slot_{i}", signs) for i in range(2)]
+
+    try:
+        for _ in range(12):  # sketch-building pass
+            worker.lookup_direct(zipf_feats(), training=False)
+        plan = hotness.placement_plan(
+            hotness.merge_snapshots([c.hotness() for c in clients]), 4,
+            current_table=even)
+        balanced = even.derive(np.asarray(plan["assignment"], np.int32), 4,
+                               weights=np.asarray(plan["slot_weights"]))
+        trace = [zipf_feats() for _ in range(24)]
+
+        def max_served_share(tbl):
+            worker.apply_routing(tbl)
+            worker.close_routing_window()
+            before = [c.hotness().get("total", 0) for c in clients]
+            for feats in trace:
+                worker.lookup_direct(feats, training=False)
+            served = np.array([c.hotness().get("total", 0)
+                               for c in clients], np.float64) - before
+            return float((served / max(served.sum(), 1.0)).max())
+
+        even_max = max_served_share(even.derive(even.replica_of_slot, 4))
+        balanced_max = max_served_share(
+            balanced.derive(balanced.replica_of_slot, 4))
+        assert balanced_max < even_max, (balanced_max, even_max)
+    finally:
+        worker.close()
+        for s in services:
+            s.stop()
 
 
 def test_freeze_bounces_writes_with_typed_stale_error():
@@ -855,6 +1035,74 @@ def test_gradient_return_across_epoch_resplits_by_live_table():
     worker.close()
     for s in services:
         s.stop()
+
+
+def test_update_retried_after_a_cutover_resplits_by_live_table(
+        monkeypatch):
+    """An update whose first shipment dies with its replica and whose
+    settle loop runs out of budget (one call's retry ladder against a
+    dead address can outlast it) comes back through the whole-fan-out
+    retry. If a migration cut over and finalized meanwhile, the groups
+    split before it must not ship: the donor is disarmed and would take
+    the moved signs onto copies nobody reads, acked (the reshard kill
+    matrix's donor cells, about one kill in thirteen). The retry
+    re-splits by the live table."""
+    holders = [_holder() for _ in range(3)]
+    services = [_service(h) for h in holders]
+    from persia_tpu.service.ps_service import PsClient
+
+    clients = [PsClient(s.addr, circuit_breaker=False) for s in services]
+    for c in clients:
+        _arm(c)
+    t2 = RoutingTable.uniform(2, slots_per_replica=8)
+    worker = EmbeddingWorker(schema=_schema(2), ps_clients=clients[:2],
+                             routing=t2)
+    signs = np.arange(1024, dtype=np.uint64)
+    feats = [_feature(f"slot_{i}", signs[i * 512:(i + 1) * 512])
+             for i in range(2)]
+    ref, out = worker.lookup_direct_training(feats)
+    t3 = t2.derive(np.full(t2.num_slots, 2, np.int32), 3)
+
+    class DiesOnceWhileTheFleetMovesOn:
+        """Replica 0's client: its first update finds the process gone,
+        and by the time the call gives up every slot has moved to
+        replica 2 and the donors are disarmed."""
+
+        def __init__(self, client):
+            self._client = client
+            self.died = False
+
+        def __getattr__(self, name):
+            return getattr(self._client, name)
+
+        def update_gradients(self, *a, **kw):
+            if self.died:
+                return self._client.update_gradients(*a, **kw)
+            self.died = True
+            rows = [(int(s), d, vec.copy())
+                    for h in holders[:2] for shard in h._shards
+                    for s, (d, vec) in list(shard._map.items())]
+            clients[2].reshard_install(pack_rows(rows))
+            assert worker.apply_routing(t3, ps_clients=clients)
+            raise ConnectionError("replica 0 is gone")
+
+    worker.ps_clients[0] = DiesOnceWhileTheFleetMovesOn(clients[0])
+    monkeypatch.setenv("PERSIA_RESHARD_STALE_RETRY_SEC", "0")
+    try:
+        worker.update_gradients(ref, {k: np.ones_like(v.embeddings)
+                                      for k, v in out.items()})
+        # every sign reached the live owner (replica 1's half perhaps
+        # twice: once copied with its rows, once re-shipped, as any
+        # whole-fan-out retry is at-least-once), and replica 0's
+        # unreachable copies took nothing
+        got = -worker.lookup_signs(signs, DIM).sum(axis=1) / DIM
+        assert ((got >= 1) & (got <= 2)).all(), got
+        assert not any(vec[:d].any() for shard in holders[0]._shards
+                       for d, vec in shard._map.values())
+    finally:
+        worker.close()
+        for s in services:
+            s.stop()
 
 
 def test_routing_holder_swap_under_reader_load():

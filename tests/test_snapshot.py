@@ -1,8 +1,8 @@
 """Coordinated job-snapshot protocol units (persia_tpu/snapshot.py):
 manifest completeness + torn refusal, newest-complete fallback,
 retention GC, resolve/restore round trips, and the cursor doc. The
-full-fleet SIGKILL matrix lives in bench.py --mode chaos (chaos_job);
-these are the fast in-process invariants it builds on."""
+full-fleet SIGKILL matrix is tests/test_chaos_job.py; these are the
+fast in-process invariants it builds on."""
 
 import json
 import os

@@ -200,6 +200,38 @@ def test_coverage_curve_monotone_bounded():
 # --- holder wiring ---------------------------------------------------------
 
 
+def test_sketch_recall_and_coverage_error_against_exact_counts():
+    """Sketch accuracy under zipf(1.05) traffic through a real armed
+    holder, against exact counts of the same stream: top-100 recall
+    >= 0.95 and the coverage curve within 2 points of the truth at
+    every grid fraction."""
+    rng = np.random.default_rng(7)
+    vocab, dim = 1 << 14, 16
+    holder = _configured_holder(True)
+    exact = np.zeros(vocab + 1, dtype=np.int64)
+    for _ in range(16):
+        signs = _zipf_stream(rng, vocab, 2048).clip(max=vocab)
+        np.add.at(exact, signs.astype(np.int64), 1)
+        holder.lookup(signs, dim, training=True)
+    table = holder.hotness_snapshot()["tables"][str(dim)]
+    n_eval = 100
+    # tie-aware recall: a sketch pick whose TRUE count reaches the true
+    # 100th count is a correct heavy hitter even if argsort broke the
+    # tie the other way
+    kth_count = np.sort(exact)[::-1][n_eval - 1]
+    picks = [s for s, _c, _e in table["topk"][:n_eval]]
+    recall = sum(1 for s in picks
+                 if s <= vocab and exact[s] >= kth_count) / n_eval
+    assert recall >= 0.95, recall
+    true_counts = np.sort(exact[exact > 0])[::-1].astype(np.float64)
+    prefix = np.cumsum(true_counts)
+    n_uniq = len(true_counts)
+    for pt in hot.coverage_curve(table):
+        n_true = max(1, min(int(round(pt["frac"] * n_uniq)), n_uniq))
+        err = abs(pt["coverage"] - prefix[n_true - 1] / prefix[-1])
+        assert err <= 0.02, (pt, err)
+
+
 def test_holder_disabled_path_is_off():
     h = _configured_holder(hotness=False)
     assert h.hotness is None

@@ -432,6 +432,69 @@ def test_cached_hotness_admission_matches_uncached():
                                        atol=1e-3, err_msg=f"sign {sign}")
 
 
+def test_full_ladder_matches_flat_ps_and_flushes_bit_exact(tmp_path):
+    """Every rung at once: a hotness-admitted device cache over a PS
+    whose RAM rung is squeezed to ~128 rows (the rest lives on disk)
+    trains the same stream as flat-PS training to the same losses and
+    the same LOGICAL table, and ``flush_device_cache`` lands every
+    cached row on the PS bit-identical to the device copy (values AND
+    optimizer state), read back through the ladder."""
+    from persia_tpu.worker.worker import EmbeddingWorker
+    from tests.test_device_cache import _make_ctx, _schema, _zipf_batches
+
+    def run(cache_cap, ladder):
+        holders = [_armed_holder(
+            capacity=100_000, shards=2,
+            capacity_bytes=(1 << 13) if ladder else None,
+            spill_dir=str(tmp_path / f"r{i}") if ladder else None)
+            for i in range(2)]
+        ctx = _make_ctx(EmbeddingWorker(_schema(), holders), cache_cap)
+        ctx.device_cache_admission = "hotness" if ladder else None
+        losses, flushed = [], 0
+        with ctx:
+            for b in _zipf_batches(10, 64, vocab=2000):
+                loss, _ = ctx.train_step(b)
+                losses.append(float(loss))
+            if cache_cap:
+                eng = ctx._cache_engine
+                csigns, cslots = eng.mapper.signs_and_slots()
+                ctx.flush_device_cache()
+                vals = np.asarray(eng.cache_vals)
+                accs = np.asarray(eng.cache_acc)
+                for sign, slot in zip(csigns.tolist(), cslots.tolist()):
+                    got = next((e for e in (h.get_entry(int(sign))
+                                            for h in holders)
+                                if e is not None), None)
+                    assert got is not None, \
+                        f"flushed sign {sign} fell out of the table"
+                    d, vec = got
+                    np.testing.assert_array_equal(vec[:d], vals[slot][:d])
+                    np.testing.assert_array_equal(vec[d:2 * d],
+                                                  accs[slot][:d])
+                    flushed += 1
+        return losses, holders, flushed
+
+    flat_losses, flat_holders, _ = run(0, ladder=False)
+    lad_losses, lad_holders, flushed = run(280, ladder=True)
+    assert flushed > 0
+    np.testing.assert_allclose(lad_losses, flat_losses, rtol=1e-3,
+                               atol=1e-3)
+    assert sum(h.spill_stats()["spilled_rows_total"]
+               for h in lad_holders) > 0, \
+        "the squeeze never demoted a row to disk"
+    n_rows = 0
+    for fh, lh in zip(flat_holders, lad_holders):
+        assert len(lh) == len(fh)
+        for shard in fh._shards:
+            for sign, (d, vec) in shard._map.items():
+                got = lh.get_entry(int(sign))
+                assert got is not None, f"sign {sign} lost by the ladder"
+                np.testing.assert_allclose(got[1][:d], vec[:d], rtol=1e-3,
+                                           atol=1e-3)
+                n_rows += 1
+    assert n_rows > 100
+
+
 # --- coherence protocol: set_entries version + inc-update + wv rider ------
 
 

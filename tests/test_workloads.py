@@ -1,6 +1,8 @@
 """Workload zoo: generator determinism, zipf fidelity, worker-tier
 ragged pooling parity, multi-task gradient accounting, scenario
-registry round-trips, and the planner's predicted-vs-measured delta."""
+registry round-trips, the planner's predicted-vs-measured delta, and
+every scenario trained end to end through the hybrid stack (falling
+loss, held-out AUC floor, the ragged-free wire pin)."""
 
 import numpy as np
 import pytest
@@ -399,3 +401,160 @@ def test_cursor_resume_across_process_restart(tmp_path):
                          timeout=120)
     assert out.returncode == 0, out.stderr
     assert out.stdout.strip() == want
+
+
+# --- every scenario end to end through the hybrid stack --------------------
+
+
+def _planner_prediction_holds_on_fresh_traffic(scenario, holders):
+    """The hotness planner's predicted device-cache hit rate, fitted
+    from the telemetry the TRAINING traffic produced, against the hit
+    rate a frequency-admitted device mapper measures on FRESH traffic
+    from the same generator (seeds the sketches never saw)."""
+    from persia_tpu import hotness as hot
+    from persia_tpu.worker.device_cache import TieredSignSlotMap
+
+    snap = hot.merge_snapshots([h.hotness_snapshot() for h in holders])
+    assert snap.get("enabled"), "hotness sketches never armed"
+    # budget ~35% of the estimated unique fp32 rows: deep enough that
+    # the zipf head fits, shallow enough that the hit rate is a real
+    # number (not 1.0) the prediction could get wrong
+    full_bytes = sum(
+        float(t.get("unique_est") or 1.0) * int(tbl) * 4
+        for tbl, t in snap["tables"].items())
+    hbm_bytes = max(1 << 12, int(0.35 * full_bytes))
+    plan = hot.planner_report(snap, hbm_bytes=hbm_bytes)
+    # one frequency-admitted mapper per planner table (PS tables are
+    # keyed by dim), sized at the PLAN's hot_rows
+    mappers = {t["table"]: TieredSignSlotMap(max(int(t["hot_rows"]), 1))
+               for t in plan["tables"]}
+    bs = scenario.bench_batch_size
+
+    def replay(passes, first_seed):
+        for p in range(passes):
+            for b in scenario.batches(8 * bs, bs, seed=first_seed + p,
+                                      requires_grad=False):
+                by_dim = {}
+                for f in b.id_type_features:
+                    d = str(scenario.schema.get_slot(f.name).dim)
+                    by_dim.setdefault(d, []).append(f.signs)
+                for d, signs in by_dim.items():
+                    if d in mappers:
+                        mappers[d].assign(np.concatenate(signs))
+
+    replay(2, scenario.seed + 5000)
+    c0 = {d: (m.hits, m.misses) for d, m in mappers.items()}
+    replay(2, scenario.seed + 5002)
+    dh = sum(m.hits - c0[d][0] for d, m in mappers.items())
+    dm = sum(m.misses - c0[d][1] for d, m in mappers.items())
+    delta = hot.planner_report(
+        snap, hbm_bytes=hbm_bytes,
+        measured_hit_rate=dh / max(dh + dm, 1))["hit_rate_delta"]
+    assert abs(delta) <= 0.20, (
+        f"planner hit-rate delta {delta:+.3f}: the telemetry-driven "
+        f"capacity plan does not survive traffic it did not generate")
+
+
+@pytest.mark.parametrize("name", ["dlrm", "seqrec", "multitask"])
+def test_scenario_trains_through_hybrid_stack(name):
+    """generator -> worker middleware -> PS holders -> jitted dense step
+    -> sparse update: the loss must actually fall and the held-out AUC
+    (disjoint seed, same hidden task) must clear the scenario's floor —
+    "the pipeline runs but nothing learns" fails here. For dlrm the
+    training run's own telemetry must also predict the device hit rate
+    of fresh traffic."""
+    import jax
+
+    from persia_tpu.workloads import evaluate_auc
+    from tests.fleet_support import scenario_stack
+
+    sc = get_scenario(name, smoke=True)
+    bs = sc.bench_batch_size
+    ctx, worker, holders = scenario_stack(sc, hotness=(name == "dlrm"))
+    losses = []
+    try:
+        with ctx:
+            for b in sc.batches(120 * bs, bs):
+                loss, _ = ctx.train_step(b)
+                losses.append(float(loss))
+            jax.block_until_ready(loss)
+            aucs = evaluate_auc(ctx, sc, num_samples=2048,
+                                batch_size=min(bs, 512))
+        assert np.mean(losses[-5:]) < np.mean(losses[:5]), \
+            "loss did not fall — the scenario is not training"
+        assert min(aucs.values()) >= sc.auc_gate, (aucs, sc.auc_gate)
+        if name == "dlrm":
+            _planner_prediction_holds_on_fresh_traffic(sc, holders)
+    finally:
+        worker.close()
+
+
+def test_ragged_free_traffic_keeps_the_legacy_wire():
+    """A schema that spells the ``pooling`` field out (all-"sum") and
+    the same schema as a pre-zoo config would build it (no pooling keys
+    at all) produce byte-identical lookup framing AND serve identical
+    RPC counts for identical cycles over real PS services."""
+    from persia_tpu.ps.store import EmbeddingHolder
+    from persia_tpu.rpc import pack_arrays_sg
+    from persia_tpu.service.ps_service import PsClient, PsService
+    from persia_tpu.service.serialization import (
+        pack_id_features,
+        unpack_id_features,
+    )
+    from persia_tpu.worker.worker import EmbeddingWorker
+
+    sc = get_scenario("dlrm", smoke=True)
+    assert not sc.ragged_features
+
+    def join_sg(b):
+        return b if isinstance(b, (bytes, bytearray)) else b"".join(
+            bytes(x) for x in b)
+
+    legacy_schema = EmbeddingSchema.from_dict({"slots_config": {
+        n: {"dim": s.dim, "sample_fixed_size": s.sample_fixed_size,
+            "embedding_summation": s.embedding_summation}
+        for n, s in sc.schema.slots_config.items()}})
+    batch = next(iter(sc.batches(64, 64, requires_grad=False)))
+    # the loader wire: id-feature framing carries exactly the legacy
+    # meta (names only) — no pooling rider crept in
+    meta, _feats = unpack_id_features(
+        pack_id_features(batch.id_type_features))
+    assert set(meta) == {"names"}
+
+    first = batch.id_type_features[0]
+    g_signs = np.sort(np.unique(first.signs))[:32].astype(np.uint64)
+    dim = sc.schema.get_slot(first.name).dim
+    bs = min(sc.bench_batch_size, 256)
+    svcs, stacks = [], {}
+    try:
+        for k, schema in (("zoo", sc.schema), ("legacy", legacy_schema)):
+            svc = PsService(EmbeddingHolder(200_000, 4), port=0)
+            svc.server.serve_background()
+            svcs.append(svc)
+            cli = PsClient(svc.addr)
+            cli.configure("bounded_uniform", {"lower": -0.1, "upper": 0.1})
+            cli.register_optimizer({
+                "type": "adagrad", "lr": 0.05, "initialization": 0.01,
+                "g_square_momentum": 1.0, "vectorwise_shared": False})
+            stacks[k] = (EmbeddingWorker(schema, [cli]), cli)
+        served, framing = {}, {}
+        for k, (w, cli) in stacks.items():
+            served0 = cli.health()["served_rpcs"]
+            for b in sc.batches(2 * bs, bs, requires_grad=True):
+                ref, lookup = w.lookup_direct_training(b.id_type_features)
+                w.update_gradients(ref, {
+                    f.name: np.ones_like(lookup[f.name].embeddings)
+                    for f in b.id_type_features})
+            served[k] = cli.health()["served_rpcs"] - served0
+            # the client's REAL lookup framing (its own _lookup_meta,
+            # not a hand-built dict: a future meta rider shows up here)
+            framing[k] = join_sg(cli._pack(cli._lookup_meta(dim, True),
+                                           [g_signs]))
+        assert served["zoo"] == served["legacy"]
+        assert framing["zoo"] == framing["legacy"] == join_sg(
+            pack_arrays_sg({"dim": dim, "training": True}, [g_signs]))
+    finally:
+        for _w, cli in stacks.values():
+            cli.shutdown()
+        for s in svcs:
+            s.stop()

@@ -218,7 +218,7 @@ def _mentioned_outside(repo_root: str, name: str) -> bool:
                             return True
                 except OSError:
                     pass
-    for fn in ("bench.py", "README.md", "Dockerfile"):
+    for fn in ("README.md", "Dockerfile"):
         p = os.path.join(repo_root, fn)
         try:
             with open(p, "r", encoding="utf-8") as f:
