@@ -37,10 +37,22 @@ product over the held experts' matrices, and adds the shared expert for
 every token. What the experts held elsewhere would add is left out: in
 an expert-parallel job that part arrives by an exchange with the chips
 that hold them, and on one chip there is no exchange and nothing stands
-in for it. The buffer of pairs has the static size tokens x per_token,
-the most that can be routed here, so no pair is ever dropped whatever
-the imbalance; the grouped product visits only the tiles of rows that
-are in use.
+in for it. No pair is ever dropped whatever the imbalance, and the work
+follows the pairs routed here: they sort first, and ``dispatch_pairs``
+gathers, multiplies and sums them back a buffer of
+``pair_buffer_rows(tokens, per_token, held, routed)`` rows at a time (a
+size from static shapes alone: twice the pairs expected at the held
+experts, at least one a token), in a loop that runs as many times as
+the batch's held pairs fill that buffer, a count read on the device:
+once for a batch routed evenly, tokens x per_token / rows times for one
+routed wholly here (``routed_rows``, which the layer sows, says which:
+the passes are its sum over that size, rounded up). A loop of that kind
+has no derivative of JAX's making, so ``dispatch_pairs`` brings its own:
+the same loop, each buffer recomputed and carried back by the grouped
+product's own backward calls (what ``nn.remat`` recomputes of the
+forward loop is then dead code, so a step still runs a buffer forward
+twice). One body at one size is all the step holds of the grouped
+product, whose grid visits only the tiles of rows in use.
 
 Recomputation: each layer is wrapped in ``nn.remat``, so the backward
 pass holds one (batch, T, hidden) input a layer and rebuilds a layer's
@@ -53,6 +65,7 @@ away (190 s of a cold start on a v5e). No parameter's shape depends on
 the input's length.
 """
 
+import functools
 from typing import Any, Sequence
 
 import jax
@@ -174,6 +187,112 @@ def route(scores, per_token, scaling):
     return chosen, scaling * s / jnp.sum(s, axis=-1, keepdims=True)
 
 
+def pair_buffer_rows(tokens, per_token, held, routed):
+    """Rows of the buffer an expert layer takes its held pairs through at
+    a time, from static shapes alone: twice the pairs expected at the
+    held experts under even routing, at least one a token, at most all
+    ``tokens * per_token``. A granularity, not a capacity: a batch with
+    more held pairs takes more passes of ``dispatch_pairs``' loop."""
+    twice_expected = -(-2 * tokens * per_token * held // routed)
+    return min(tokens * per_token, max(tokens, twice_expected))
+
+
+def _buffers(order, sizes, rows):
+    """How many buffers of ``rows`` pairs the held pairs fill, and
+    ``at(i)``: the i-th buffer's slice of the sorted order and its group
+    sizes (each held group's part of it, then the rest)."""
+    here = sizes[:-1]
+    ends = jnp.cumsum(here)
+
+    def at(i):
+        lo = i * rows
+        part = jnp.clip(jnp.minimum(ends, lo + rows)
+                        - jnp.maximum(ends - here, lo), 0)
+        return (lax.dynamic_slice(order, (lo,), (rows,)),
+                jnp.append(part, rows - jnp.sum(part)))
+
+    return (ends[-1] + rows - 1) // rows, at
+
+
+def _through_experts(tokens, w1, w2, order, sizes, per_token):
+    """One buffer of sorted pairs through their experts: each pair's
+    token, its rows gathered, before and after the square-relu, and what
+    the second product gives. Rows past the held groups stay in the
+    buffer (its size is static) but no tile of theirs is multiplied, and
+    they come out zero."""
+    # Pallas is imported where a kernel is called: every tower of the
+    # zoo is imported together, and the others' start-up does not pay
+    # the two seconds it takes
+    from persia_tpu.ops.grouped_matmul import grouped_matmul
+
+    token_of = order // per_token
+    rows = jnp.take(tokens, token_of, axis=0)
+    pre = grouped_matmul(rows, w1, sizes)
+    mid = jnp.square(nn.relu(pre))
+    return token_of, rows, pre, mid, grouped_matmul(mid, w2, sizes)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
+def dispatch_pairs(tokens, w1, w2, weight, order, sizes, rows, per_token):
+    """The held pairs of a batch through their experts, weighted and
+    summed onto their tokens, ``rows`` pairs at a time: (tokens, hidden)
+    float32. ``order`` lists the pairs (token x ``per_token`` + choice)
+    sorted by group, held groups first, padded to whole buffers;
+    ``sizes`` (held + 1,) counts them. The loop runs as many times as the
+    held pairs fill a buffer, a number read on the device, so its
+    derivative is written out below."""
+    count, at = _buffers(order, sizes, rows)
+
+    def one(i, acc):
+        part, part_sizes = at(i)
+        token_of, _, _, _, out = _through_experts(
+            tokens, w1, w2, part, part_sizes, per_token)
+        return acc.at[token_of].add(
+            out.astype(F32) * weight[part][:, None])
+
+    return lax.fori_loop(0, count, one, jnp.zeros(tokens.shape, F32))
+
+
+def _dispatch_fwd(tokens, w1, w2, weight, order, sizes, rows, per_token):
+    return (dispatch_pairs(tokens, w1, w2, weight, order, sizes, rows,
+                           per_token),
+            (tokens, w1, w2, weight, order, sizes))
+
+
+def _dispatch_bwd(rows, per_token, saved, ct):
+    """The same loop: each buffer recomputed, the cotangent of its
+    tokens' sums gathered and carried back through the weights, the
+    second product, the square-relu and the first, by the grouped
+    product's own two backward calls; sums in float32."""
+    from persia_tpu.ops.grouped_matmul import grouped_matmul_pullback
+
+    tokens, w1, w2, weight, order, sizes = saved
+    count, at = _buffers(order, sizes, rows)
+
+    def one(i, sums):
+        to_tokens, to_w1, to_w2, to_weight = sums
+        part, part_sizes = at(i)
+        token_of, gathered, pre, mid, out = _through_experts(
+            tokens, w1, w2, part, part_sizes, per_token)
+        g = jnp.take(ct, token_of, axis=0)
+        g_out = (g * weight[part][:, None]).astype(out.dtype)
+        g_mid, g_w2 = grouped_matmul_pullback(mid, w2, part_sizes, g_out)
+        g_rows, g_w1 = grouped_matmul_pullback(
+            gathered, w1, part_sizes, g_mid * (2 * nn.relu(pre)))
+        return (to_tokens.at[token_of].add(g_rows.astype(F32)),
+                to_w1 + g_w1.astype(F32), to_w2 + g_w2.astype(F32),
+                to_weight.at[part].add(
+                    jnp.sum(g * out.astype(F32), axis=-1)))
+
+    inputs = (tokens, w1, w2, weight)
+    sums = lax.fori_loop(0, count, one, tuple(
+        jnp.zeros(x.shape, F32) for x in inputs))
+    return (*(g.astype(x.dtype) for g, x in zip(sums, inputs)), None, None)
+
+
+dispatch_pairs.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
 class SparseExperts(nn.Module):
     """The held experts' part of a routed expert layer, plus the shared
     expert (module docstring: the contract)."""
@@ -203,11 +322,6 @@ class SparseExperts(nn.Module):
                         (self.shared_width, hidden), F32)
         if self.is_initializing():
             return jnp.zeros_like(u)
-        # Pallas is imported where a kernel is called: every tower of the
-        # zoo is imported together, and the others' start-up does not pay
-        # the two seconds it takes
-        from persia_tpu.ops.grouped_matmul import grouped_matmul
-
         tokens = u.reshape(bs * t, hidden)
         n = tokens.shape[0]
 
@@ -221,19 +335,17 @@ class SparseExperts(nn.Module):
             local[list(self.experts_held)] = np.arange(held)
             local = jnp.asarray(local)[chosen].reshape(n * self.per_token)
             order = jnp.argsort(local, stable=True)
-            token_of = order // self.per_token      # of each sorted pair
             sizes = jnp.bincount(local, length=held + 1).astype(jnp.int32)
             self.sow("intermediates", "routed_rows", sizes[:held])
 
         with jax.named_scope("experts_grouped"):
-            # rows past the held groups stay in the buffer (its size is
-            # static) but no tile of theirs is multiplied
-            rows = jnp.take(tokens.astype(cd), token_of, axis=0)
-            mid = grouped_matmul(rows, w1.astype(cd), sizes)
-            mid = jnp.square(nn.relu(mid))
-            out = grouped_matmul(mid, w2.astype(cd), sizes)
-            out = out.astype(F32) * weight.reshape(-1)[order][:, None]
-            routed = jax.ops.segment_sum(out, token_of, num_segments=n)
+            cap = pair_buffer_rows(n, self.per_token, held,
+                                   self.experts_routed)
+            pad = -order.shape[0] % cap     # to whole buffers: trailing rows
+            routed = dispatch_pairs(
+                tokens.astype(cd), w1.astype(cd), w2.astype(cd),
+                weight.reshape(-1), jnp.pad(order, (0, pad)), sizes, cap,
+                self.per_token)
 
         with jax.named_scope("experts_shared"):
             shared = _dense(jnp.square(nn.relu(_dense(tokens, s1, cd))),

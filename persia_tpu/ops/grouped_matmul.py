@@ -17,10 +17,24 @@ is visited and their output rows are zero.
 import jax
 import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu.megablox import gmm as _gmm
+from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as _gmm_plain
+from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm as _tgmm_plain
 
 
 # rows, contraction and output columns of a tile; never tuned on a chip
 TILING = (512, 512, 512)
+
+
+def _tiles(lhs, rhs, group_sizes):
+    """The tile of a product of these shapes, the rows that fill ``lhs``
+    to whole tiles, and the group sizes with those rows in the trailing
+    group, which no tile visits."""
+    m, k = lhs.shape
+    tiling = tuple(min(t, d) for t, d in zip(TILING, (m, k, rhs.shape[2])))
+    pad = -m % tiling[0]
+    if pad:
+        group_sizes = group_sizes.at[-1].add(pad)
+    return tiling, pad, group_sizes
 
 
 def grouped_matmul(lhs, rhs, group_sizes):
@@ -29,13 +43,30 @@ def grouped_matmul(lhs, rhs, group_sizes):
     ``lhs``'s dtype, float32 accumulation inside. Compiled on a TPU, run
     by the Pallas interpreter elsewhere (the CPU tests)."""
     interpret = jax.default_backend() != "tpu"
-    m, k = lhs.shape
-    n = rhs.shape[2]
-    tm, tk, tn = (min(t, d) for t, d in zip(TILING, (m, k, n)))
-    pad = -m % tm
-    if pad:     # rows of the trailing group, which no tile visits
+    m = lhs.shape[0]
+    tiling, pad, group_sizes = _tiles(lhs, rhs, group_sizes)
+    if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
-        group_sizes = group_sizes.at[-1].add(pad)
-    out = _gmm(lhs, rhs, group_sizes, lhs.dtype, (tm, tk, tn), None, None,
+    out = _gmm(lhs, rhs, group_sizes, lhs.dtype, tiling, None, None,
                False, interpret)
     return out[:m] if pad else out
+
+
+def grouped_matmul_pullback(lhs, rhs, group_sizes, grad):
+    """``grad`` (m, n), the cotangent of ``grouped_matmul(lhs, rhs,
+    group_sizes)``, carried back to ``lhs`` and to ``rhs``, each in its
+    dtype: the two calls megablox's own derivative makes, the product
+    with the matrices transposed and the grouped outer product, at the
+    forward's tiles. For a caller that writes its derivative out (a loop
+    whose trip count is read on the device has none of JAX's making)."""
+    interpret = jax.default_backend() != "tpu"
+    m = lhs.shape[0]
+    tiling, pad, group_sizes = _tiles(lhs, rhs, group_sizes)
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+        grad = jnp.pad(grad, ((0, pad), (0, 0)))
+    to_lhs = _gmm_plain(grad, rhs, group_sizes, lhs.dtype, tiling,
+                        transpose_rhs=True, interpret=interpret)
+    to_rhs = _tgmm_plain(lhs.swapaxes(0, 1), grad, group_sizes, rhs.dtype,
+                         tiling, None, rhs.shape[0], interpret=interpret)
+    return (to_lhs[:m] if pad else to_lhs), to_rhs

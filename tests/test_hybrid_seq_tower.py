@@ -182,22 +182,95 @@ def test_the_shares_add_up_to_the_uncut_layer():
     assert rows == 2 * 40 * SZ["experts_per_token"]     # every pair, once
 
 
-def test_an_imbalanced_batch_drops_nothing():
-    """Every token chooses held experts only: the buffer is full, and the
-    result is still the reference's."""
-    p = _expert_leaves(33, range(4))
-    # scores of the four held experts far above the rest for every token
+@pytest.mark.parametrize("tokens,per_token,held,routed,rows", [
+    (8192, 6, 8, 128, 8192),        # the cell: one pair a token binds
+    (256, 3, 4, 16, 384),           # the rehearsal: twice the expected 192
+    (80, 3, 16, 16, 240),           # every expert held: every pair
+    (80, 3, 5, 16, 150),            # no whole number of them in the 240
+], ids=["cell", "rehearsal", "all_held", "uneven"])
+def test_pair_buffer_rows_follow_the_share_of_experts_held(
+        tokens, per_token, held, routed, rows):
+    assert hybrid_seq.pair_buffer_rows(tokens, per_token, held,
+                                       routed) == rows
+
+
+# what each class of token chooses, of 16 experts with 0..3 held: three,
+# two, one and none of its pairs are routed here
+CHOICES = ((0, 1, 2), (3, 0, 8), (1, 8, 9), (8, 9, 10))
+
+
+def _steered(p, counts, seed=9):
+    """The layer's leaves and an input on which ``counts[c]`` tokens of
+    80 choose ``CHOICES[c]``: the token's class is written into its first
+    four features, and the router reads them far above the rest."""
+    rng = np.random.default_rng(seed)
     router = np.asarray(p["router"]) * 0.01
-    router[:, :4] += np.linspace(0.2, 0.5, 4, dtype=np.float32)
-    p = dict(p, router=jnp.asarray(router))
+    router[:4] = 0.0
+    for c, chosen in enumerate(CHOICES):
+        router[c, list(chosen)] = 1.0
+    u = rng.normal(size=(80, 64)).astype(np.float32)
+    u[:, :4] = 4.0 * np.eye(4, dtype=np.float32)[
+        rng.permutation(np.repeat(np.arange(4), counts))]
+    return dict(p, router=jnp.asarray(router)), jnp.asarray(
+        u.reshape(2, 40, 64))
+
+
+def _every_pair_held(p):
+    """Scores of the four held experts far above the rest for every
+    token, and short of where the sigmoid's gradient underflows."""
+    router = np.asarray(p["router"]) * 0.01
+    router[:, :4] += np.linspace(0.02, 0.05, 4, dtype=np.float32)
     u = jnp.abs(jnp.asarray(
         np.random.default_rng(9).normal(size=(2, 40, 64)), F32)) + 0.5
-    out, state = _highest(lambda: _mixer("E").apply(
+    return dict(p, router=jnp.asarray(router)), u
+
+
+@pytest.mark.parametrize("case,held,pairs,passes", [
+    ("balanced", 4, None, 1), ("exactly_the_buffer", 4, 120, 1),
+    ("one_pair_over", 4, 121, 2), ("every_pair_held", 4, 240, 2),
+    ("uneven_buffers", 5, 240, 2)])
+def test_no_pair_is_dropped_however_many_buffers_it_takes(case, held, pairs,
+                                                          passes):
+    """80 tokens, 4 of 16 experts held, top 3: the pair buffer has 120
+    rows. Output and every gradient are the reference's whether the held
+    pairs fit one buffer or take a second pass of the loop (121 pairs:
+    one pair in it; 240: both full), and with 5 held, where the 240 pairs
+    are no whole number of buffers of 150."""
+    cap = hybrid_seq.pair_buffer_rows(80, SZ["experts_per_token"], held, 16)
+    assert cap == {4: 120, 5: 150}[held]
+    ids = list(range(held))
+    p = _expert_leaves(33, ids)
+    if case == "balanced":
+        u = jnp.asarray(np.random.default_rng(9).normal(size=(2, 40, 64)),
+                        F32)
+    elif pairs == 240:
+        p, u = _every_pair_held(p)
+    else:       # 90 + 20 + (10 or 11) pairs here
+        ones = pairs - 110
+        p, u = _steered(p, [30, 10, ones, 40 - ones])
+    w = jnp.asarray(np.random.default_rng(4).normal(size=(2, 40, 64)), F32)
+    mixer = _mixer("E", held=ids)
+
+    def theirs(p, u):
+        return ref.experts(p, u, SZ, lambda v: v, held=ids)
+
+    out, state = _highest(lambda: mixer.apply(
         {"params": p}, u, mutable=["intermediates"]))
     rows = np.asarray(state["intermediates"]["routed_rows"][0])
-    assert rows.sum() == 2 * 40 * SZ["experts_per_token"]
-    assert rows.max() == 2 * 40         # one expert sees every token
-    _close(out, _highest(lambda: ref.experts(p, u, SZ, lambda v: v)))
+    if pairs is not None:
+        assert rows.sum() == pairs
+    if pairs == 240:
+        assert rows.max() == 2 * 40         # one expert sees every token
+    assert -(-rows.sum() // cap) == passes
+    _close(out, _highest(theirs, p, u))
+    got = _highest(jax.grad(lambda p, u: jnp.sum(w * mixer.apply(
+        {"params": p}, u)), argnums=(0, 1)), p, u)
+    want = _highest(jax.grad(lambda p, u: jnp.sum(w * theirs(p, u)),
+                             argnums=(0, 1)), p, u)
+    assert set(p) == {"router", "w1", "w2", "shared_w1", "shared_w2"}
+    for name in p:
+        _close(got[0][name], want[0][name])
+    _close(got[1], want[1])
 
 
 # --- the tower through the device-mode trainer ------------------------------
