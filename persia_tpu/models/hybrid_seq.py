@@ -1,6 +1,8 @@
 """Hybrid sequence tower: state-space mixers, sparse experts beside a
-shared expert, and grouped-query attention over a history of item ids,
-with an item head for next-item prediction.
+shared expert, grouped-query and latent attention and a gated dense
+feed-forward over a history of item ids, with an item head for next-item
+prediction and, optionally, one multi-token-prediction module for the
+item after next.
 
 A generative recommender: the history is the sequence, the item table
 (a ``DeviceEmbeddingCollection`` slot with ``pooling="none"``) is the
@@ -11,17 +13,36 @@ slot], tower=HybridSequenceTower(...), pooling="none")`` and
 ``make_device_mode_trainer``.
 
 Every layer is ``h + mixer(RMSNorm(h))``; ``pattern`` names the mixers,
-one letter a layer:
+one letter a layer (a published transformer block, attention then a
+feed-forward, is two letters):
 
 ``M``  a Mamba-2 mixer: one input projection to a gate ``z``, the
        convolved stream ``xBC`` and a step size ``dt`` a head; a causal
        depthwise convolution and SiLU; the selective scan
        (``ops.ssm_scan``); ``GroupRMSNorm(y * silu(z))``; an output
        projection.
-``E``  an expert layer (:class:`SparseExperts`).
+``E``  an expert layer (:class:`SparseExperts`), its experts' form
+       chosen by ``expert_activation``: ``relu2`` (a squared relu
+       between two matrices) or ``swiglu`` (``silu(gate) * up`` between
+       three, gate and up held as one).
 ``*``  causal grouped-query attention through the Pallas flash kernel,
-       without a positional encoding: the state-space layers carry
-       position.
+       without a positional encoding: in a pattern with state-space
+       layers those carry position.
+``L``  causal multi-head latent attention (:class:`LatentAttention`):
+       low-rank query and key-value projections with a norm on each
+       latent, and rotary position embedding (:func:`rotary`) on a
+       decoupled part of every query head and on one key shared by all
+       the heads; the same flash kernel, at the head's whole width.
+``D``  a gated dense feed-forward (:class:`GatedFeedForward`).
+
+``mtp_depth`` 1 adds a :class:`NextPrediction` module after the last
+layer: it merges the last hidden state (before the final norm) with the
+item slot's rows rolled left by one position, runs one more block of its
+own like the tower's last (the pattern's last two letters, attention and
+its feed-forward), and reads the tower's own item head, so the tower
+returns two sets of logits, for item t+1 and item t+2
+(``parallel.train.next_items_cross_entropy``). ``mtp_depth`` 0 is the
+tower without it.
 
 Histories are left-aligned: padding, if any, lies at the tail, where
 under causal mixing it reaches no real position, so no mixer masks it;
@@ -66,7 +87,7 @@ the input's length.
 """
 
 import functools
-from typing import Any, Sequence
+from typing import Any, Callable, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -214,9 +235,48 @@ def _buffers(order, sizes, rows):
     return (ends[-1] + rows - 1) // rows, at
 
 
-def _through_experts(tokens, w1, w2, order, sizes, per_token):
+def _relu2(pre):
+    return jnp.square(nn.relu(pre))
+
+
+def _relu2_pullback(pre, g):
+    return g * (2 * nn.relu(pre))
+
+
+def _swiglu(pre):
+    """``silu(gate) * up`` of ``pre = [gate | up]``, worked out in
+    float32 and returned in ``pre``'s dtype."""
+    gate, up = jnp.split(pre.astype(F32), 2, axis=-1)
+    return (nn.silu(gate) * up).astype(pre.dtype)
+
+
+def _swiglu_pullback(pre, g):
+    gate, up = jnp.split(pre.astype(F32), 2, axis=-1)
+    s, g = jax.nn.sigmoid(gate), g.astype(F32)
+    return jnp.concatenate([g * up * s * (1 + gate * (1 - s)),
+                            g * gate * s], axis=-1).astype(pre.dtype)
+
+
+class Activation(NamedTuple):
+    """An expert's activation between its two products, its derivative
+    written out for ``dispatch_pairs``' hand-made backward, and the
+    factor by which the first product is wider than the second's input."""
+
+    forward: Callable
+    pullback: Callable
+    wider: int
+
+
+# ``relu2`` squares a relu (two matrices an expert); ``swiglu`` gates the
+# second half of the first product's columns by the silu of the first
+# half (gate and up held as one matrix: three an expert)
+ACTIVATIONS = {"relu2": Activation(_relu2, _relu2_pullback, 1),
+               "swiglu": Activation(_swiglu, _swiglu_pullback, 2)}
+
+
+def _through_experts(tokens, w1, w2, order, sizes, per_token, activation):
     """One buffer of sorted pairs through their experts: each pair's
-    token, its rows gathered, before and after the square-relu, and what
+    token, its rows gathered, before and after the activation, and what
     the second product gives. Rows past the held groups stay in the
     buffer (its size is static) but no tile of theirs is multiplied, and
     they come out zero."""
@@ -228,41 +288,44 @@ def _through_experts(tokens, w1, w2, order, sizes, per_token):
     token_of = order // per_token
     rows = jnp.take(tokens, token_of, axis=0)
     pre = grouped_matmul(rows, w1, sizes)
-    mid = jnp.square(nn.relu(pre))
+    mid = ACTIVATIONS[activation].forward(pre)
     return token_of, rows, pre, mid, grouped_matmul(mid, w2, sizes)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7))
-def dispatch_pairs(tokens, w1, w2, weight, order, sizes, rows, per_token):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
+def dispatch_pairs(tokens, w1, w2, weight, order, sizes, rows, per_token,
+                   activation):
     """The held pairs of a batch through their experts, weighted and
     summed onto their tokens, ``rows`` pairs at a time: (tokens, hidden)
     float32. ``order`` lists the pairs (token x ``per_token`` + choice)
     sorted by group, held groups first, padded to whole buffers;
     ``sizes`` (held + 1,) counts them. The loop runs as many times as the
     held pairs fill a buffer, a number read on the device, so its
-    derivative is written out below."""
+    derivative is written out below. ``activation`` names an entry of
+    ``ACTIVATIONS``."""
     count, at = _buffers(order, sizes, rows)
 
     def one(i, acc):
         part, part_sizes = at(i)
         token_of, _, _, _, out = _through_experts(
-            tokens, w1, w2, part, part_sizes, per_token)
+            tokens, w1, w2, part, part_sizes, per_token, activation)
         return acc.at[token_of].add(
             out.astype(F32) * weight[part][:, None])
 
     return lax.fori_loop(0, count, one, jnp.zeros(tokens.shape, F32))
 
 
-def _dispatch_fwd(tokens, w1, w2, weight, order, sizes, rows, per_token):
+def _dispatch_fwd(tokens, w1, w2, weight, order, sizes, rows, per_token,
+                  activation):
     return (dispatch_pairs(tokens, w1, w2, weight, order, sizes, rows,
-                           per_token),
+                           per_token, activation),
             (tokens, w1, w2, weight, order, sizes))
 
 
-def _dispatch_bwd(rows, per_token, saved, ct):
+def _dispatch_bwd(rows, per_token, activation, saved, ct):
     """The same loop: each buffer recomputed, the cotangent of its
     tokens' sums gathered and carried back through the weights, the
-    second product, the square-relu and the first, by the grouped
+    second product, the activation and the first, by the grouped
     product's own two backward calls; sums in float32."""
     from persia_tpu.ops.grouped_matmul import grouped_matmul_pullback
 
@@ -273,12 +336,13 @@ def _dispatch_bwd(rows, per_token, saved, ct):
         to_tokens, to_w1, to_w2, to_weight = sums
         part, part_sizes = at(i)
         token_of, gathered, pre, mid, out = _through_experts(
-            tokens, w1, w2, part, part_sizes, per_token)
+            tokens, w1, w2, part, part_sizes, per_token, activation)
         g = jnp.take(ct, token_of, axis=0)
         g_out = (g * weight[part][:, None]).astype(out.dtype)
         g_mid, g_w2 = grouped_matmul_pullback(mid, w2, part_sizes, g_out)
         g_rows, g_w1 = grouped_matmul_pullback(
-            gathered, w1, part_sizes, g_mid * (2 * nn.relu(pre)))
+            gathered, w1, part_sizes,
+            ACTIVATIONS[activation].pullback(pre, g_mid))
         return (to_tokens.at[token_of].add(g_rows.astype(F32)),
                 to_w1 + g_w1.astype(F32), to_w2 + g_w2.astype(F32),
                 to_weight.at[part].add(
@@ -305,19 +369,21 @@ class SparseExperts(nn.Module):
     scaling: float = 2.5
     out_scale: float = 1.0
     compute_dtype: Any = jnp.bfloat16
+    activation: str = "relu2"       # an entry of ACTIVATIONS
 
     @nn.compact
     def __call__(self, u):
         bs, t, hidden = u.shape
         cd, held = self.compute_dtype, len(self.experts_held)
+        act, _, wider = ACTIVATIONS[self.activation]
         w_r = self.param("router", _kernel_init(),
                          (hidden, self.experts_routed), F32)
         w1 = self.param("w1", _kernel_init(),
-                        (held, hidden, self.expert_width), F32)
+                        (held, hidden, wider * self.expert_width), F32)
         w2 = self.param("w2", _kernel_init(self.out_scale),
                         (held, self.expert_width, hidden), F32)
         s1 = self.param("shared_w1", _kernel_init(),
-                        (hidden, self.shared_width), F32)
+                        (hidden, wider * self.shared_width), F32)
         s2 = self.param("shared_w2", _kernel_init(self.out_scale),
                         (self.shared_width, hidden), F32)
         if self.is_initializing():
@@ -345,11 +411,10 @@ class SparseExperts(nn.Module):
             routed = dispatch_pairs(
                 tokens.astype(cd), w1.astype(cd), w2.astype(cd),
                 weight.reshape(-1), jnp.pad(order, (0, pad)), sizes, cap,
-                self.per_token)
+                self.per_token, self.activation)
 
         with jax.named_scope("experts_shared"):
-            shared = _dense(jnp.square(nn.relu(_dense(tokens, s1, cd))),
-                            s2, cd)
+            shared = _dense(act(_dense(tokens, s1, cd)), s2, cd)
         return (routed + shared.astype(F32)).astype(cd).reshape(
             bs, t, hidden)
 
@@ -397,6 +462,110 @@ class GroupedQueryAttention(nn.Module):
         return _dense(out, wo, cd)
 
 
+def rotary(x, theta):
+    """Rotary position embedding of ``x`` (batch, T, heads, dim), in the
+    rotate-half convention: feature ``i`` of the first half pairs with
+    feature ``i`` of the second, and the pair at position ``t`` turns by
+    the angle ``t * theta ** (-2 i / dim)``. Positions are 0..T-1 a
+    history. Float32 in and out."""
+    t, half = x.shape[1], x.shape[-1] // 2
+    freq = jnp.exp(-np.log(theta) / half * jnp.arange(half, dtype=F32))
+    angle = jnp.arange(t, dtype=F32)[:, None, None] * freq
+    cos, sin = jnp.cos(angle), jnp.sin(angle)
+    x = x.astype(F32)
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
+
+
+class LatentAttention(nn.Module):
+    """Causal multi-head latent attention with decoupled rotary keys, in
+    the uncompressed form training uses: queries through a low-rank pair
+    of projections with a norm between them; keys and values built a head
+    from one normed latent of ``kv_rank`` features; ``rope_dim`` further
+    features of every query head, and one key of that width shared by all
+    the heads, carry position through :func:`rotary`. A head's query and
+    key are ``[nope | rope]``; the flash kernel takes one width for
+    queries, keys and values, so ``nope_dim + rope_dim`` has to equal
+    ``v_dim``."""
+
+    heads: int = 20
+    q_rank: int = 768
+    kv_rank: int = 512
+    nope_dim: int = 192
+    rope_dim: int = 64
+    v_dim: int = 256
+    rope_theta: float = 1e6
+    eps: float = 1e-5
+    out_scale: float = 1.0
+    compute_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        bs, t, hidden = u.shape
+        cd, heads = self.compute_dtype, self.heads
+        nope, rope, vd = self.nope_dim, self.rope_dim, self.v_dim
+        if nope + rope != vd:
+            raise ValueError(
+                f"query and key heads of {nope} + {rope} beside values of "
+                f"{vd}: the flash kernel takes one head width")
+        q_a = self.param("q_a", _kernel_init(), (hidden, self.q_rank), F32)
+        q_norm = self.param("q_norm", nn.initializers.ones, (self.q_rank,),
+                            F32)
+        q_b = self.param("q_b", _kernel_init(),
+                         (self.q_rank, heads * (nope + rope)), F32)
+        kv_a = self.param("kv_a", _kernel_init(),
+                          (hidden, self.kv_rank + rope), F32)
+        kv_norm = self.param("kv_norm", nn.initializers.ones,
+                             (self.kv_rank,), F32)
+        kv_b = self.param("kv_b", _kernel_init(),
+                          (self.kv_rank, heads * (nope + vd)), F32)
+        wo = self.param("o_proj", _kernel_init(self.out_scale),
+                        (heads * vd, hidden), F32)
+        if self.is_initializing():
+            return jnp.zeros_like(u)
+        from persia_tpu.ops.flash_attention import flash_attention_masked
+
+        with jax.named_scope("latent_project"):
+            c_q = (_rms(_dense(u, q_a, cd), self.eps) * q_norm).astype(cd)
+            q = _dense(c_q, q_b, cd).reshape(bs, t, heads, nope + rope)
+            c_kv, k_rope = jnp.split(_dense(u, kv_a, cd), [self.kv_rank],
+                                     axis=-1)
+            c_kv = (_rms(c_kv, self.eps) * kv_norm).astype(cd)
+            kv = _dense(c_kv, kv_b, cd).reshape(bs, t, heads, nope + vd)
+        with jax.named_scope("rotary"):
+            q_rope = rotary(q[..., nope:], self.rope_theta).astype(cd)
+            k_rope = rotary(k_rope[:, :, None, :], self.rope_theta).astype(cd)
+            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :nope],
+                 jnp.broadcast_to(k_rope, (bs, t, heads, rope))], axis=-1)
+        q, k, v = (y.transpose(0, 2, 1, 3) for y in (q, k, kv[..., nope:]))
+        with jax.named_scope("flash_attention"):    # the calls' name in a trace
+            out = flash_attention_masked(q, k, v, causal=True)
+        out = out.transpose(0, 2, 1, 3).reshape(bs, t, heads * vd)
+        return _dense(out, wo, cd)
+
+
+class GatedFeedForward(nn.Module):
+    """``down(silu(gate(u)) * up(u))``, gate and up held as one matrix
+    ``[gate | up]`` as an expert's are."""
+
+    width: int = 10240
+    out_scale: float = 1.0
+    compute_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        hidden, cd = u.shape[-1], self.compute_dtype
+        w1 = self.param("gate_up", _kernel_init(), (hidden, 2 * self.width),
+                        F32)
+        w2 = self.param("down", _kernel_init(self.out_scale),
+                        (self.width, hidden), F32)
+        if self.is_initializing():
+            return jnp.zeros_like(u)
+        return _dense(_swiglu(_dense(u, w1, cd)), w2, cd)
+
+
 class _Layer(nn.Module):
     """``h + mixer(RMSNorm(h))`` under the mixer's scope name."""
 
@@ -412,13 +581,48 @@ class _Layer(nn.Module):
             return h + self.mixer(u)
 
 
+class NextPrediction(nn.Module):
+    """A multi-token-prediction module: the item after next from the
+    tower's last hidden state (before the final norm) and the rows of
+    the next item, ``h'_t = W [RMSNorm(e_{t+1}) | RMSNorm(h_t)]``,
+    through ``layer`` (one more block, its own weights), a norm and the
+    **tower's** item head, which it is handed. ``rows`` are the item
+    slot's (batch, T, hidden), rolled left by one position here; the
+    length stays T, and what the last position reads (the first item,
+    rolled round) reaches no earlier one under causal mixing: the loss
+    leaves it out."""
+
+    layer: Sequence[nn.Module]
+    eps: float
+    compute_dtype: Any
+
+    @nn.compact
+    def __call__(self, h, rows, head):
+        cd, hidden = self.compute_dtype, h.shape[-1]
+        with jax.named_scope("mtp_merge"):
+            e = RMSNorm(self.eps, cd, name="embed_norm")(
+                jnp.roll(rows, -1, axis=1))
+            u = RMSNorm(self.eps, cd, name="hidden_norm")(h)
+            w = self.param("merge", _kernel_init(), (2 * hidden, hidden),
+                           F32)
+            h = _dense(jnp.concatenate([e, u], axis=-1), w, cd)
+        for one in self.layer:
+            h = one(h)
+        with jax.named_scope("mtp_head"):
+            h = RMSNorm(self.eps, cd, name="head_norm")(h)
+            return _dense(h, head, cd, out=F32)
+
+
 class HybridSequenceTower(nn.Module):
     """``pattern`` and every size are constructor data; the defaults are
     one chip's share of a published 52-layer model (its first nine
     layers, 8 of its 128 routed experts, an eighth of its vocabulary).
     Called as every tower is, with the item slot's ``(sequence, mask)``
     as the one embedding input; returns float32 logits (batch, T,
-    vocab) over the item table's rows."""
+    vocab) over the item table's rows, and with ``mtp_depth`` 1 the
+    pair of them with the prediction module's logits for the item after
+    next (``parallel.train.next_items_cross_entropy`` is that pair's
+    loss)."""
 
     pattern: str = "MEMEM*EME"
     hidden: int = 2688
@@ -440,14 +644,28 @@ class HybridSequenceTower(nn.Module):
     attn_kv_heads: int = 2
     attn_head_dim: int = 128
     compute_dtype: Any = jnp.bfloat16
+    expert_activation: str = "relu2"
+    dense_width: int = 10240
+    latent_heads: int = 20
+    latent_q_rank: int = 768
+    latent_kv_rank: int = 512
+    latent_nope_dim: int = 192
+    latent_rope_dim: int = 64
+    latent_v_dim: int = 256
+    rope_theta: float = 1e6
+    mtp_depth: int = 0
 
-    SCOPES = {"M": "ssm_mixer", "E": "experts", "*": "attention"}
+    SCOPES = {"M": "ssm_mixer", "E": "experts", "*": "attention",
+              "L": "latent_attention", "D": "dense_ffn"}
 
     def step_tags(self):
         """What ``make_device_mode_trainer`` tags its build with."""
         return {"tower_layers": self.pattern,
                 "experts_held": tuple(self.experts_held),
-                "experts_routed": self.experts_routed}
+                "experts_routed": self.experts_routed,
+                "expert_matrices":
+                    1 + ACTIVATIONS[self.expert_activation].wider,
+                "mtp_depth": self.mtp_depth}
 
     def _mixer(self, kind, out_scale):
         """Unbound, so that the layer it is handed to adopts it."""
@@ -463,29 +681,54 @@ class HybridSequenceTower(nn.Module):
                                  tuple(self.experts_held),
                                  self.experts_per_token, self.expert_width,
                                  self.shared_width, self.routed_scaling,
-                                 out_scale, cd, parent=None)
+                                 out_scale, cd,
+                                 activation=self.expert_activation,
+                                 parent=None)
         if kind == "*":
             return GroupedQueryAttention(self.attn_heads, self.attn_kv_heads,
                                          self.attn_head_dim, out_scale, cd,
                                          parent=None)
+        if kind == "L":
+            return LatentAttention(self.latent_heads, self.latent_q_rank,
+                                   self.latent_kv_rank, self.latent_nope_dim,
+                                   self.latent_rope_dim, self.latent_v_dim,
+                                   self.rope_theta, self.eps, out_scale, cd,
+                                   parent=None)
+        if kind == "D":
+            return GatedFeedForward(self.dense_width, out_scale, cd,
+                                    parent=None)
         raise ValueError(f"pattern {self.pattern!r}: unknown layer {kind!r}")
 
     @nn.compact
     def __call__(self, non_id_tensors, embedding_tensors, train: bool = False):
-        (h, _mask), = embedding_tensors
-        h = h.astype(self.compute_dtype)
+        (rows, _mask), = embedding_tensors
+        h = rows = rows.astype(self.compute_dtype)
+        if self.mtp_depth not in (0, 1):
+            raise ValueError(f"mtp_depth {self.mtp_depth}: one prediction "
+                             f"module or none")
         # output projections start smaller the deeper the stack
         # (the published rescale_prenorm_residual)
         out_scale = 1.0 / len(self.pattern)
         layer = _Layer if self.is_initializing() else nn.remat(_Layer)
+
+        def layer_of(kind, **how):
+            return layer(self._mixer(kind, out_scale), self.SCOPES[kind],
+                         self.eps, self.compute_dtype, **how)
+
         for i, kind in enumerate(self.pattern):
-            h = layer(self._mixer(kind, out_scale), self.SCOPES[kind],
-                      self.eps, self.compute_dtype, name=f"layer_{i}")(h)
+            h = layer_of(kind, name=f"layer_{i}")(h)
         with jax.named_scope("item_head"):
-            h = RMSNorm(self.eps, self.compute_dtype, name="final_norm")(h)
+            u = RMSNorm(self.eps, self.compute_dtype, name="final_norm")(h)
             w = self.param("item_head", _kernel_init(),
                            (self.hidden, self.vocab), F32)
-            return _dense(h, w, self.compute_dtype, out=F32)
+            logits = _dense(u, w, self.compute_dtype, out=F32)
+        if not self.mtp_depth:
+            return logits
+        with jax.named_scope("mtp"):
+            ahead = NextPrediction(
+                [layer_of(kind, parent=None) for kind in self.pattern[-2:]],
+                self.eps, self.compute_dtype, name="mtp")(h, rows, w)
+        return logits, ahead
 
 
 def routed_rows(model, params, non_id_tensors, id_tensors):
@@ -496,9 +739,11 @@ def routed_rows(model, params, non_id_tensors, id_tensors):
                            train=False, mutable=["intermediates"])
     found = jax.tree_util.tree_flatten_with_path(state["intermediates"])[0]
 
-    def layer_of(path):
-        return min(int(k.key.split("_")[1]) for k in path
-                   if getattr(k, "key", "").startswith("layer_"))
+    def layer_of(path):     # the prediction module's layers come last
+        keys = [getattr(k, "key", "") for k in path]
+        return ("mtp" in keys,
+                min(int(k.split("_")[1]) for k in keys
+                    if k.startswith("layer_")))
 
     found = sorted(found, key=lambda kv: layer_of(kv[0]))
     return jnp.stack([leaf for _, leaf in found])

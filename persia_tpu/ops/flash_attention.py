@@ -13,10 +13,11 @@ This is the classic flash-attention schedule mapped onto the Pallas
 TPU grid (sequential iteration, innermost axis fastest; scratch
 persists across grid steps — see /opt/skills/guides/pallas_guide.md).
 
-Kernel shape rules: dh is the lane axis of every block (any dh ≤ 128
-works, full-axis blocks are padded internally; dh=128 is the sweet
-spot). Block sizes are multiples of the 128-lane width (``_clamp_block``)
-so every tile — bf16 (16, 128) included — and every per-row lane vector
+Kernel shape rules: dh is the lane axis of every block, one width for
+queries, keys and values (any dh ≤ 128 works, full-axis blocks are
+padded internally; a multiple of 128 above that too: the latent
+attention tower's heads are 256 wide). Block sizes are multiples of the
+128-lane width (``_clamp_block``) so every tile — bf16 (16, 128) included — and every per-row lane vector
 (lse, delta, kv_mask ride as ``(.., 1, T)`` rows blocked ``(1, block)``)
 meets the TPU lowering's (8, 128) block rule. T is padded to the k/q
 block size by the wrapper; padded KEY positions are masked via the

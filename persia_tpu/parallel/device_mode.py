@@ -27,7 +27,8 @@ not): a method ``step_tags() -> {name: int or sequence}``. What it
 returns becomes tags of the span ``trainer/build_device_step`` as given,
 and gauges ``device_mode_<name>`` (an int as it is, a sequence by its
 length). The hybrid sequence tower gives ``tower_layers`` (its pattern),
-``experts_held`` and ``experts_routed``.
+``experts_held``, ``experts_routed``, ``expert_matrices`` (2 for
+square-relu experts, 3 for silu-gated ones) and ``mtp_depth``.
 """
 
 from typing import Any, Callable, Dict, Sequence, Tuple

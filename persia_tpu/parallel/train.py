@@ -50,6 +50,22 @@ def next_item_cross_entropy(logits: jnp.ndarray,
     return -jnp.sum(picked * valid) / jnp.maximum(jnp.sum(valid), 1)
 
 
+def next_items_cross_entropy(logits, target: jnp.ndarray,
+                             ahead_weight: float = 0.3) -> jnp.ndarray:
+    """The loss of a tower with one multi-token-prediction module:
+    ``logits`` is the pair (main head's, module's), position t of the
+    first against the next event's class as above, of the second against
+    the class of the event after next, which is ``target`` shifted left
+    by one with nothing for a history's last position; the module's mean
+    over its own positions counts ``ahead_weight`` times (the family's
+    published lambda is 0.3 early in training)."""
+    main, ahead = logits
+    shifted = jnp.concatenate(
+        [target[..., 1:], jnp.full_like(target[..., :1], -1)], axis=-1)
+    return (next_item_cross_entropy(main, target)
+            + ahead_weight * next_item_cross_entropy(ahead, shifted))
+
+
 def _rebuild_embedding_inputs(
     emb_values: Sequence[jnp.ndarray], emb_indices: Sequence[Optional[jnp.ndarray]]
 ) -> List[Any]:

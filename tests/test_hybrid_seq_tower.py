@@ -26,7 +26,9 @@ import check  # noqa: E402
 import device_seq  # noqa: E402
 import reference  # noqa: E402
 import reference_hybrid_seq as ref  # noqa: E402
+import reference_latent_seq as ref_gated  # noqa: E402
 import weights_hybrid_seq as weights  # noqa: E402
+import weights_latent_seq as weights_gated  # noqa: E402
 
 from persia_tpu import metrics, tracing  # noqa: E402
 from persia_tpu.models import hybrid_seq  # noqa: E402
@@ -102,10 +104,16 @@ def test_chunked_scan_is_the_sequential_recurrence(t):
 # --- each mixer, forward and gradients --------------------------------------
 
 
-def _mixer(kind, held=SZ["experts_held"]):
+def _mixer(kind, held=SZ["experts_held"], activation="relu2"):
     """One float32 mixer of the tower, holding the experts ``held``."""
-    return device_seq.build_tower(dict(SZ, experts_held=list(held)),
-                                  compute_dtype=F32)._mixer(kind, 1.0)
+    return device_seq.build_tower(
+        dict(SZ, experts_held=list(held), expert_activation=activation),
+        compute_dtype=F32)._mixer(kind, 1.0)
+
+
+# the expert layer's two forms, each with the reference that has it
+ACTIVATIONS = pytest.mark.parametrize("activation", ["relu2", "swiglu"])
+REF_EXPERTS = {"relu2": ref, "swiglu": ref_gated}
 
 
 @pytest.mark.parametrize("kind,t", [("M", 48), ("E", 40), ("*", 40)],
@@ -154,27 +162,35 @@ def test_routing_weights_are_normalised_and_scaled():
 # --- the share: what one chip of an expert-parallel job computes ------------
 
 
-def _expert_leaves(seed, ids):
-    """The layer's leaves with the experts ``ids`` of 16 made whole."""
+def _expert_leaves(seed, ids, activation="relu2"):
+    """The layer's leaves with the experts ``ids`` of 16 made whole; the
+    gated form's first matrices are ``[gate | up]``, twice as wide."""
     sz = dict(SZ, experts_held=list(range(16)))
-    p = _layer_params(weights.make(seed, sz), 1, sz)
+    if activation == "relu2":
+        p = _layer_params(weights.make(seed, sz), 1, sz)
+    else:
+        key = weights.seed_key(seed)
+        p = {n: weights.gen_leaf(key, i, shape, kind, sz) for i, (
+            n, shape, kind) in enumerate(weights_gated.layer_leaves("E", sz))}
     return dict(p, w1=p["w1"][np.asarray(ids)], w2=p["w2"][np.asarray(ids)])
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
+@ACTIVATIONS
+def test_the_shares_add_up_to_the_uncut_layer(activation):
     """16 routed experts in 4 shares of 4: the four shares' routed parts,
     with the shared expert counted once, are the uncut reference layer."""
     u = jnp.asarray(np.random.default_rng(8).normal(size=(2, 40, 64)), F32)
-    whole = _expert_leaves(21, range(16))
-    want = _highest(lambda: ref.experts(whole, u, SZ, lambda v: v,
-                                        held=list(range(16))))
-    shared = _highest(lambda: ref.shared_expert(
+    whole = _expert_leaves(21, range(16), activation)
+    theirs = REF_EXPERTS[activation]
+    want = _highest(lambda: theirs.experts(whole, u, SZ, lambda v: v,
+                                           held=list(range(16))))
+    shared = _highest(lambda: theirs.shared_expert(
         whole, u.reshape(-1, 64), lambda v: v)).reshape(u.shape)
     total, rows = 0.0, 0
     for first in range(0, 16, 4):
         ids = list(range(first, first + 4))
-        out, state = _highest(lambda: _mixer("E", held=ids).apply(
-            {"params": _expert_leaves(21, ids)}, u,
+        out, state = _highest(lambda: _mixer("E", ids, activation).apply(
+            {"params": _expert_leaves(21, ids, activation)}, u,
             mutable=["intermediates"]))
         total = total + (out - shared)
         rows += int(np.sum(state["intermediates"]["routed_rows"][0]))
@@ -225,21 +241,24 @@ def _every_pair_held(p):
     return dict(p, router=jnp.asarray(router)), u
 
 
+@ACTIVATIONS
 @pytest.mark.parametrize("case,held,pairs,passes", [
     ("balanced", 4, None, 1), ("exactly_the_buffer", 4, 120, 1),
     ("one_pair_over", 4, 121, 2), ("every_pair_held", 4, 240, 2),
     ("uneven_buffers", 5, 240, 2)])
 def test_no_pair_is_dropped_however_many_buffers_it_takes(case, held, pairs,
-                                                          passes):
+                                                          passes, activation):
     """80 tokens, 4 of 16 experts held, top 3: the pair buffer has 120
     rows. Output and every gradient are the reference's whether the held
     pairs fit one buffer or take a second pass of the loop (121 pairs:
     one pair in it; 240: both full), and with 5 held, where the 240 pairs
-    are no whole number of buffers of 150."""
+    are no whole number of buffers of 150; with square-relu experts of
+    two matrices and with silu-gated experts of three, through the one
+    ``dispatch_pairs`` and its hand-written backward."""
     cap = hybrid_seq.pair_buffer_rows(80, SZ["experts_per_token"], held, 16)
     assert cap == {4: 120, 5: 150}[held]
     ids = list(range(held))
-    p = _expert_leaves(33, ids)
+    p = _expert_leaves(33, ids, activation)
     if case == "balanced":
         u = jnp.asarray(np.random.default_rng(9).normal(size=(2, 40, 64)),
                         F32)
@@ -249,10 +268,11 @@ def test_no_pair_is_dropped_however_many_buffers_it_takes(case, held, pairs,
         ones = pairs - 110
         p, u = _steered(p, [30, 10, ones, 40 - ones])
     w = jnp.asarray(np.random.default_rng(4).normal(size=(2, 40, 64)), F32)
-    mixer = _mixer("E", held=ids)
+    mixer = _mixer("E", ids, activation)
 
     def theirs(p, u):
-        return ref.experts(p, u, SZ, lambda v: v, held=ids)
+        return REF_EXPERTS[activation].experts(p, u, SZ, lambda v: v,
+                                               held=ids)
 
     out, state = _highest(lambda: mixer.apply(
         {"params": p}, u, mutable=["intermediates"]))
@@ -427,7 +447,8 @@ def test_the_configuration_states_the_parameters_it_runs():
     tower = device_seq.build_tower(sz)
     assert tower.step_tags() == {"tower_layers": "MEMEM*EME",
                                  "experts_held": tuple(range(8)),
-                                 "experts_routed": 128}
+                                 "experts_routed": 128, "expert_matrices": 2,
+                                 "mtp_depth": 0}
 
 
 def test_kernels_roofline_is_the_algorithm_s_need_at_the_rows_routed():

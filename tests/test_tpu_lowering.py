@@ -36,11 +36,13 @@ from persia_tpu.ops.flash_attention import (  # noqa: E402
     flash_attention_masked,
 )
 
-# (T, dh, dtype): the smoke's two lengths at the full head width, plus
-# small ragged shapes the CPU tests use
+# (T, dh, dtype): the smoke's two lengths at the full head width, the
+# latent attention tower's history at its 256-wide head, plus small
+# ragged shapes the CPU tests use
 SHAPES = [
     (4096, 128, jnp.bfloat16),
     (1000, 128, jnp.bfloat16),
+    (8192, 256, jnp.bfloat16),
     (200, 64, jnp.bfloat16),
     (100, 8, jnp.float32),
 ]
@@ -116,7 +118,7 @@ def _aot_compile_all() -> int:
     sharding = SingleDeviceSharding(topo.devices[0])
     failed = 0
     for (t, dh, dtype), (masked, grad), causal in itertools.product(
-            SHAPES[:2], VARIANTS, (False, True)):
+            SHAPES[:3], VARIANTS, (False, True)):
         name = (f"T={t} dh={dh} {'masked' if masked else 'plain'} "
                 f"{'grad' if grad else 'fwd'} causal={causal}")
         try:
@@ -145,7 +147,7 @@ def test_attention_aot_compiles_for_v5e():
                        timeout=600)
     assert r.returncode == 0 and "REFUSED" not in r.stdout, (
         r.stdout[-4000:] + r.stderr[-2000:])
-    assert r.stdout.count("COMPILED") == 16, r.stdout[-4000:]
+    assert r.stdout.count("COMPILED") == 24, r.stdout[-4000:]
 
 
 if __name__ == "__main__":
