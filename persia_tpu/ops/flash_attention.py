@@ -13,6 +13,19 @@ This is the classic flash-attention schedule mapped onto the Pallas
 TPU grid (sequential iteration, innermost axis fastest; scratch
 persists across grid steps — see /opt/skills/guides/pallas_guide.md).
 
+What a call walks: not the dense ``(n_q, n_k)`` rectangle of block
+pairs but a list of them, ``block_schedule``, made from the call's
+static shapes and holding only the pairs in which some score is live.
+The three kernels take the list as scalar-prefetch operands, their grid
+is ``(b*h, len(pairs))`` and every index map reads its block index from
+the list, so a causal call takes no grid step, fetches no block and
+builds no mask above the diagonal: at T 8192 136 pairs a head where the
+rectangle has 256 in 512 x 512 blocks, 36 of 64 in the 1024 x 1024 that
+``_blocks`` picks, and only the 16 (8) that straddle the diagonal pay
+for the mask (``interior`` pairs run the same body without the iotas,
+the comparisons and the select). A non-causal call gets its whole
+rectangle from the same function.
+
 Kernel shape rules: dh is the lane axis of every block, one width for
 queries, keys and values (any dh ≤ 128 works, full-axis blocks are
 padded internally; a multiple of 128 above that too: the latent
@@ -21,33 +34,119 @@ attention tower's heads are 256 wide). Block sizes are multiples of the
 (lse, delta, kv_mask ride as ``(.., 1, T)`` rows blocked ``(1, block)``)
 meets the TPU lowering's (8, 128) block rule. T is padded to the k/q
 block size by the wrapper; padded KEY positions are masked via the
-static true-length, padded QUERY rows compute garbage that the wrapper
-slices off.
+static true-length (their pairs are ``edge`` pairs), padded QUERY rows
+compute garbage that the wrapper slices off.
 
 Backward: Pallas too (jax.custom_vjp). The forward saves (q, k, v,
 out, lse); `flash_attention_bwd_pallas` recomputes each softmax block
 in VMEM from those residuals with the same schedule run twice — dq
-accumulates across the k-grid, dk/dv across the q-grid. delta
+accumulates along a row of pairs, dk/dv along a column. delta
 (rowsum(dO·O)) is a cheap XLA reduce. Memory stays O(T) end to end.
 
-The carry-bandwidth figures above are computed from shapes; kernel time
-and roofline share on the chip: not measured (see PERF.md).
+The carry-bandwidth figures above are computed from shapes. Kernel
+times and roofline shares on the chip are in PERF.md (§5, §6 "PR 34").
 """
 
 import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from persia_tpu import metrics
 
 _NEG_INF = -1e30  # large-finite: -inf NaNs the m-update on all-masked rows
 _LANES = 128
 
+# flags of a scheduled pair
+EDGE = 1    # some score of the block is masked: build the positional mask
+FIRST = 2   # first pair of its accumulation row: zero the accumulators
+LAST = 4    # last pair of its accumulation row: write the output block
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
+
+def block_schedule(t_q: int, t_k: int, block_q: int, block_k: int,
+                   causal: bool, by_key: bool = False):
+    """The block pairs one call walks, from its static shapes alone:
+    ``(qi, ki, flags)``, three int32 arrays of one length.
+
+    A pair is listed exactly when some score of it is live (query and
+    key inside their true lengths and, in a causal call, key not after
+    query). It is an ``EDGE`` pair exactly when some score of it is
+    masked: it straddles the diagonal, or is the last key or query
+    block of a padded T. The order is the accumulation order: rows of
+    ``qi`` with ``ki`` ascending (forward, dq) or, ``by_key``, columns
+    of ``ki`` with ``qi`` ascending (dk/dv); ``FIRST`` and ``LAST``
+    mark the ends of each row. A row with nothing live (a causal key
+    block no query reaches, t_q < t_k) keeps one fully masked pair, so
+    its output block is still written, as zeros."""
+    n_q, n_k = -(-t_q // block_q), -(-t_k // block_k)
+    qi, ki = np.meshgrid(np.arange(n_q), np.arange(n_k), indexing="ij")
+    q_lo, k_lo = qi * block_q, ki * block_k
+    q_hi = np.minimum(q_lo + block_q, t_q) - 1   # last true position
+    k_hi = np.minimum(k_lo + block_k, t_k) - 1
+    live = np.full((n_q, n_k), True)
+    edge = (q_lo + block_q > t_q) | (k_lo + block_k > t_k)
+    if causal:
+        live = q_hi >= k_lo
+        edge |= q_lo < k_hi
+    if by_key:
+        qi, ki, live, edge = qi.T, ki.T, live.T, edge.T
+    # dead rows keep the pair nearest the diagonal (masked: EDGE holds)
+    dead = ~live.any(axis=1)
+    live[dead, -1 if by_key else 0] = True
+    row = (ki if by_key else qi)[live]
+    ends = np.flatnonzero(np.diff(row)) + 1
+    flags = np.where(edge[live], EDGE, 0)
+    flags[np.r_[0, ends]] |= FIRST
+    flags[np.r_[ends - 1, row.size - 1]] |= LAST
+    return tuple(np.asarray(a, np.int32) for a in (qi[live], ki[live], flags))
+
+
+def _pair(qi_ref, ki_ref, flags_ref):
+    """This grid step's pair, read from the prefetched schedule."""
+    s = pl.program_id(1)
+    return qi_ref[s], ki_ref[s], flags_ref[s]
+
+
+def _run_bodies(body, flags, has_edge: bool, has_interior: bool):
+    """``body(edge)`` under the pair's kind; a schedule of one kind
+    only (a non-causal call on unpadded T) compiles one body."""
+    if has_edge and has_interior:
+        pl.when(flags & EDGE != 0)(lambda: body(True))
+        pl.when(flags & EDGE == 0)(lambda: body(False))
+    else:
+        body(has_edge)
+
+
+def _block_mask(edge: bool, qi, ki, block_q, block_k, t_k_real, causal,
+                mask_row, t_q_real=None):
+    """The (block_q, block_k) mask of a pair, or None where every score
+    is live: positions (padding, causal) only in an edge pair, the
+    ``kv_mask`` row, (1, bk) broadcast, in either kind."""
+    mask = None
+    if edge:
+        k_pos = ki * block_k + jax.lax.broadcasted_iota(
+            jnp.int32, (block_q, block_k), 1)
+        mask = k_pos < t_k_real            # padded keys never attend
+        if causal or t_q_real is not None:
+            q_pos = qi * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0)
+        if t_q_real is not None:
+            mask = jnp.logical_and(q_pos < t_q_real, mask)
+        if causal:
+            mask = jnp.logical_and(mask, q_pos >= k_pos)
+    if mask_row is not None:
+        keep = mask_row > 0
+        mask = keep if mask is None else jnp.logical_and(mask, keep)
+    return mask
+
+
+def _fwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, *rest,
                 scale: float, causal: bool, block_q: int, block_k: int,
-                t_k_real: int, n_k: int, with_lse: bool, with_mask: bool):
+                t_k_real: int, has_edge: bool, has_interior: bool,
+                with_lse: bool, with_mask: bool):
     if with_mask:
         mask_ref, rest = rest[0], rest[1:]
     o_ref, rest = rest[0], rest[1:]
@@ -55,31 +154,24 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
         lse_ref, acc, m_scr, l_scr = rest
     else:
         acc, m_scr, l_scr = rest
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    qi, ki, flags = _pair(qi_ref, ki_ref, flags_ref)
 
-    @pl.when(ki == 0)
+    @pl.when(flags & FIRST != 0)
     def _init():
         acc[...] = jnp.zeros_like(acc)
         m_scr[...] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
 
-    def _body():
+    def _body(edge: bool):
         q = q_ref[0]                       # (block_q, dh) bf16/f32
         k = k_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (bq, bk)
-        k_pos = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1)
-        mask = k_pos < t_k_real            # padded keys never attend
-        if causal:
-            q_pos = qi * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            mask = jnp.logical_and(mask, q_pos >= k_pos)
-        if with_mask:
-            mask = jnp.logical_and(mask, mask_ref[...] > 0)  # (1, bk) bcast
-        s = jnp.where(mask, s, _NEG_INF)
+        mask = _block_mask(edge, qi, ki, block_q, block_k, t_k_real, causal,
+                           mask_ref[...] if with_mask else None)
+        if mask is not None:
+            s = jnp.where(mask, s, _NEG_INF)
 
         m_prev = m_scr[...]                # (block_q, 128) lane-replicated
         m_cur = jnp.max(s, axis=1, keepdims=True)       # (bq, 1)
@@ -99,15 +191,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, *rest,
             preferred_element_type=jnp.float32)         # (bq, dh)
         acc[...] = acc[...] * alpha[:, :1] + pv
 
-    if causal:
-        # blocks strictly above the diagonal contribute nothing — skip
-        # their matmuls (their k/v DMAs still ride the pipeline; pruning
-        # those too needs grid index-remapping, not worth it here)
-        pl.when((qi + 1) * block_q - 1 >= ki * block_k)(_body)
-    else:
-        _body()
+    _run_bodies(_body, flags, has_edge, has_interior)
 
-    @pl.when(ki == n_k - 1)
+    @pl.when(flags & LAST != 0)
     def _finish():
         l = jnp.maximum(l_scr[...][:, :1], 1e-20)
         o_ref[0] = (acc[...] / l).astype(o_ref.dtype)
@@ -129,6 +215,22 @@ def _clamp_block(block, t):
     return -(-min(block, max(t, 1)) // _LANES) * _LANES
 
 
+def _blocks(block_q, block_k, t_q, t_k, dh, dtype):
+    """The call's block sizes: a caller's request, or where it makes
+    none the size the kernels alone ran fastest at on a v5e (PERF.md §6,
+    "PR 34": T 2048-8192, head widths 64-256, bfloat16). 1024 rows a
+    block: at T 8192 the three calls take a third less time than in 512s
+    at head width 128 and 8 % less at 256, since what a step does beside
+    its products (row statistics, rescaling, the pipeline's turn) is
+    spread over four times the scores. 512 where a 1024-row tile of the
+    inputs would pass 512 KiB (float32 at width 256), which does not fit
+    VMEM beside the float32 score blocks. Either is clamped to T."""
+    fits = 1024 * dh * jnp.dtype(dtype).itemsize <= 512 * 1024
+    default = 1024 if fits else 512
+    return (_clamp_block(block_q or default, t_q),
+            _clamp_block(block_k or default, t_k))
+
+
 def _pad_t(x, block, axis=1):
     """Zero-pad ``axis`` up to a multiple of ``block``."""
     pad = (-x.shape[axis]) % block
@@ -144,34 +246,60 @@ def _mask_rows(kv_mask, block_k):
     return _pad_t(kv_mask.astype(jnp.float32), block_k)[:, None, :]
 
 
-def _spec_family(block_q, block_k, dh, h, q_minor: bool):
-    """The four block-spec shapes every kernel here uses, for one grid
-    order: q-tile, k-tile, per-q lane row (lse/delta, arrays shaped
-    (BH, 1, T)), per-k lane row (kv_mask, (B, 1, T), batch axis =
-    bh // h). The row arrays carry a unit sublane axis so the block's
-    last two dims are (1 == full, block % 128 == 0) — a bare
-    ``(1, block)`` block over ``(BH, T)`` does not lower for TPU.
-    ``q_minor=True`` = grid (bh, qi, ki); ``False`` = (bh, ki, qi). One
-    definition so a layout change cannot drift between the forward and
-    the two backward calls."""
-    if q_minor:
-        def pos(bh, qi, ki):
-            return qi, ki
-    else:
-        def pos(bh, ki, qi):
-            return qi, ki
+def _spec_family(block_q, block_k, dh, h):
+    """The four block-spec shapes every kernel here uses: q-tile,
+    k-tile, per-q lane row (lse/delta, arrays shaped (BH, 1, T)), per-k
+    lane row (kv_mask, (B, 1, T), batch axis = bh // h). The row arrays
+    carry a unit sublane axis so the block's last two dims are
+    (1 == full, block % 128 == 0) — a bare ``(1, block)`` block over
+    ``(BH, T)`` does not lower for TPU. The grid is (bh, step) and a
+    step's block indices are the schedule's: ``qi[s]`` and ``ki[s]``,
+    whatever order the schedule walks them in. One definition so a
+    layout change cannot drift between the forward and the two backward
+    calls."""
     return (
-        pl.BlockSpec((1, block_q, dh), lambda *g: (g[0], pos(*g)[0], 0)),
-        pl.BlockSpec((1, block_k, dh), lambda *g: (g[0], pos(*g)[1], 0)),
+        pl.BlockSpec((1, block_q, dh),
+                     lambda bh, s, qi, ki, flags: (bh, qi[s], 0)),
+        pl.BlockSpec((1, block_k, dh),
+                     lambda bh, s, qi, ki, flags: (bh, ki[s], 0)),
         pl.BlockSpec((None, 1, block_q),
-                     lambda *g: (g[0], 0, pos(*g)[0])),
+                     lambda bh, s, qi, ki, flags: (bh, 0, qi[s])),
         pl.BlockSpec((None, 1, block_k),
-                     lambda *g, h=h: (g[0] // h, 0, pos(*g)[1])),
+                     lambda bh, s, qi, ki, flags, h=h: (bh // h, 0, ki[s])),
     )
 
 
+def _scheduled_call(kernel, schedule, bh, in_specs, out_specs, out_shape,
+                    scratch_shapes, interpret, operands):
+    """One kernel over grid (bh, pairs) of ``block_schedule(*schedule)``,
+    the pairs prefetched as scalars and the kernel told which of its two
+    bodies the schedule needs at all. Sets the process's gauges of how
+    far the schedule cut the rectangle (a head, of the call built last):
+    pairs walked, pairs a dense grid would have, edge pairs."""
+    pairs = block_schedule(*schedule)
+    t_q, t_k, block_q, block_k = schedule[:4]
+    n_edge = int(np.count_nonzero(pairs[2] & EDGE))
+    for name, n in (("walked", pairs[0].size),
+                    ("dense", -(-t_q // block_q) * -(-t_k // block_k)),
+                    ("edge", n_edge)):
+        metrics.default_registry().gauge(
+            f"flash_attention_pairs_{name}").set(n)
+    return pl.pallas_call(
+        functools.partial(kernel, has_edge=n_edge > 0,
+                          has_interior=n_edge < pairs[0].size),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(bh, pairs[0].size),
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch_shapes),
+        out_shape=out_shape,
+        interpret=interpret,
+    )(*pairs, *operands)
+
+
 def flash_attention_fwd_pallas(q, k, v, causal: bool = False,
-                               block_q: int = 512, block_k: int = 512,
+                               block_q=None, block_k=None,
                                interpret: bool = False,
                                return_lse: bool = False,
                                kv_mask=None):
@@ -182,19 +310,16 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool = False,
     valid key positions; fully-masked query rows yield 0."""
     b, h, t_q, dh = q.shape
     t_k = k.shape[2]
-    block_q = _clamp_block(block_q, t_q)
-    block_k = _clamp_block(block_k, t_k)
+    block_q, block_k = _blocks(block_q, block_k, t_q, t_k, dh, q.dtype)
     qp = _pad_t(q.reshape(b * h, t_q, dh), block_q)
     kp = _pad_t(k.reshape(b * h, t_k, dh), block_k)
     vp = _pad_t(v.reshape(b * h, t_k, dh), block_k)
-    n_q = qp.shape[1] // block_q
-    n_k = kp.shape[1] // block_k
     kernel = functools.partial(
         _fwd_kernel, scale=1.0 / float(dh) ** 0.5, causal=causal,
-        block_q=block_q, block_k=block_k, t_k_real=t_k, n_k=n_k,
+        block_q=block_q, block_k=block_k, t_k_real=t_k,
         with_lse=return_lse, with_mask=kv_mask is not None)
     q_spec, k_spec, qrow_spec, krow_spec = _spec_family(
-        block_q, block_k, dh, h, q_minor=True)
+        block_q, block_k, dh, h)
     in_specs = [q_spec, k_spec, k_spec]
     operands = [qp, kp, vp]
     if kv_mask is not None:
@@ -202,26 +327,20 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool = False,
         in_specs.append(krow_spec)
         operands.append(_mask_rows(kv_mask, block_k))
     o_spec = q_spec
-    o_shape = jax.ShapeDtypeStruct((b * h, n_q * block_q, dh), q.dtype)
+    o_shape = jax.ShapeDtypeStruct(qp.shape, q.dtype)
     if return_lse:
         out_specs = (o_spec, qrow_spec)
         out_shape = (o_shape, jax.ShapeDtypeStruct(
-            (b * h, 1, n_q * block_q), jnp.float32))
+            (b * h, 1, qp.shape[1]), jnp.float32))
     else:  # serving path: no lse output, no wasted HBM write
         out_specs, out_shape = o_spec, o_shape
-    res = pl.pallas_call(
-        kernel,
-        grid=(b * h, n_q, n_k),
-        in_specs=in_specs,
-        out_specs=out_specs,
-        out_shape=out_shape,
-        scratch_shapes=[
-            pltpu.VMEM((block_q, dh), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-            pltpu.VMEM((block_q, _LANES), jnp.float32),
-        ],
-        interpret=interpret,
-    )(*operands)
+    res = _scheduled_call(
+        kernel, (t_q, t_k, block_q, block_k, causal), b * h, in_specs,
+        out_specs, out_shape,
+        [pltpu.VMEM((block_q, dh), jnp.float32),
+         pltpu.VMEM((block_q, _LANES), jnp.float32),
+         pltpu.VMEM((block_q, _LANES), jnp.float32)],
+        interpret, operands)
     if return_lse:
         out, lse = res
         return (out[:, :t_q].reshape(b, h, t_q, dh),
@@ -229,53 +348,45 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool = False,
     return res[:, :t_q].reshape(b, h, t_q, dh)
 
 
-def _masked_p(q, k, lse, *, scale, causal, block_q, block_k, qi, ki,
-              t_q_real, t_k_real, mask_row=None):
-    """Recompute the (block_q, block_k) softmax block from q/k/lse with
-    padding + causal + optional key masking — shared by both backward
-    kernels. Fully-masked rows (lse pinned at NEG_INF by the forward)
-    are forced to p=0, not the exp(0)=1 the raw arithmetic gives."""
+def _masked_p(q, k, lse, mask, *, scale):
+    """Recompute the (block_q, block_k) softmax block from q/k/lse under
+    the pair's mask (``_block_mask``; None in an interior pair) — shared
+    by both backward kernels. Fully-masked rows (lse pinned at NEG_INF
+    by the forward) are forced to p=0, not the exp(0)=1 the raw
+    arithmetic gives."""
     s = jax.lax.dot_general(
         q, k, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32) * scale
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1)
-    mask = jnp.logical_and(q_pos < t_q_real, k_pos < t_k_real)
-    if causal:
-        mask = jnp.logical_and(mask, q_pos >= k_pos)
-    if mask_row is not None:
-        mask = jnp.logical_and(mask, mask_row > 0)      # (1, bk) bcast
-    s = jnp.where(mask, s, _NEG_INF)
+    if mask is not None:
+        s = jnp.where(mask, s, _NEG_INF)
     p = jnp.exp(s - lse)
     return jnp.where(lse > _NEG_INF / 2, p, 0.0)
 
 
-def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   *rest, scale: float, causal: bool,
+def _bwd_dq_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
+                   lse_ref, delta_ref, *rest, scale: float, causal: bool,
                    block_q: int, block_k: int, t_q_real: int,
-                   t_k_real: int, n_k: int, with_mask: bool):
+                   t_k_real: int, has_edge: bool, has_interior: bool,
+                   with_mask: bool):
     if with_mask:
         mask_ref, dq_ref, dq_acc = rest
     else:
         mask_ref, (dq_ref, dq_acc) = None, rest
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    qi, ki, flags = _pair(qi_ref, ki_ref, flags_ref)
 
-    @pl.when(ki == 0)
+    @pl.when(flags & FIRST != 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    def _body():
+    def _body(edge: bool):
         # lse/delta ride in (1, block_q) lane-major rows (a (T, 1)
         # layout would be 128-lane padded in HBM); transpose to columns
         lse = jnp.transpose(lse_ref[...])               # (bq, 1)
         delta = jnp.transpose(delta_ref[...])
-        p = _masked_p(q_ref[0], k_ref[0], lse, scale=scale,
-                      causal=causal, block_q=block_q, block_k=block_k,
-                      qi=qi, ki=ki, t_q_real=t_q_real, t_k_real=t_k_real,
-                      mask_row=None if mask_ref is None else mask_ref[...])
+        mask = _block_mask(edge, qi, ki, block_q, block_k, t_k_real, causal,
+                           None if mask_ref is None else mask_ref[...],
+                           t_q_real)
+        p = _masked_p(q_ref[0], k_ref[0], lse, mask, scale=scale)
         do = do_ref[0]
         dp = jax.lax.dot_general(                       # dO @ V^T
             do, v_ref[0], (((1,), (1,)), ((), ())),
@@ -285,41 +396,37 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(k_ref.dtype), k_ref[0], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
 
-    if causal:
-        pl.when((qi + 1) * block_q - 1 >= ki * block_k)(_body)
-    else:
-        _body()
+    _run_bodies(_body, flags, has_edge, has_interior)
 
-    @pl.when(ki == n_k - 1)
+    @pl.when(flags & LAST != 0)
     def _finish():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    *rest, scale: float,
+def _bwd_dkv_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
+                    lse_ref, delta_ref, *rest, scale: float,
                     causal: bool, block_q: int, block_k: int,
-                    t_q_real: int, t_k_real: int, n_q: int,
-                    with_mask: bool):
+                    t_q_real: int, t_k_real: int, has_edge: bool,
+                    has_interior: bool, with_mask: bool):
     if with_mask:
         mask_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
     else:
         mask_ref, (dk_ref, dv_ref, dk_acc, dv_acc) = None, rest
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    qi, ki, flags = _pair(qi_ref, ki_ref, flags_ref)
 
-    @pl.when(qi == 0)
+    @pl.when(flags & FIRST != 0)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def _body():
+    def _body(edge: bool):
         q = q_ref[0]
         lse = jnp.transpose(lse_ref[...])               # (bq, 1)
         delta = jnp.transpose(delta_ref[...])
-        p = _masked_p(q, k_ref[0], lse, scale=scale,
-                      causal=causal, block_q=block_q, block_k=block_k,
-                      qi=qi, ki=ki, t_q_real=t_q_real, t_k_real=t_k_real,
-                      mask_row=None if mask_ref is None else mask_ref[...])
+        mask = _block_mask(edge, qi, ki, block_q, block_k, t_k_real, causal,
+                           None if mask_ref is None else mask_ref[...],
+                           t_q_real)
+        p = _masked_p(q, k_ref[0], lse, mask, scale=scale)
         do = do_ref[0]
         dv_acc[...] += jax.lax.dot_general(             # P^T @ dO
             p.astype(do.dtype), do, (((0,), (0,)), ((), ())),
@@ -332,32 +439,28 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale
 
-    if causal:
-        pl.when((qi + 1) * block_q - 1 >= ki * block_k)(_body)
-    else:
-        _body()
+    _run_bodies(_body, flags, has_edge, has_interior)
 
-    @pl.when(qi == n_q - 1)
+    @pl.when(flags & LAST != 0)
     def _finish():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
-                               block_q: int = 512, block_k: int = 512,
+                               block_q=None, block_k=None,
                                interpret: bool = False, kv_mask=None):
     """Pallas flash-attention backward: (dq, dk, dv).
 
     Same schedule as the forward, run twice: dq revisits its q-block
-    accumulator across the k-grid; dk/dv revisit their k-block
-    accumulators across the q-grid. The softmax block is recomputed
-    from (q, k, lse) in VMEM — nothing quadratic ever touches HBM.
+    accumulator along a row of pairs; dk/dv revisit their k-block
+    accumulators along a column (``by_key``). The softmax block is
+    recomputed from (q, k, lse) in VMEM — nothing quadratic ever
+    touches HBM.
     """
     b, h, t_q, dh = q.shape
     t_k = k.shape[2]
-    block_q = _clamp_block(block_q, t_q)
-    block_k = _clamp_block(block_k, t_k)
-    scale = 1.0 / float(dh) ** 0.5
+    block_q, block_k = _blocks(block_q, block_k, t_q, t_k, dh, q.dtype)
     # delta_i = rowsum(dO_i * O_i) — cheap XLA elementwise+reduce
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)                             # (b, h, t_q)
@@ -367,63 +470,41 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
     dop = _pad_t(do.reshape(b * h, t_q, dh), block_q)
     lsep = _pad_t(lse.reshape(b * h, t_q), block_q)[:, None, :]
     deltap = _pad_t(delta.reshape(b * h, t_q), block_q)[:, None, :]
-    n_q = qp.shape[1] // block_q
-    n_k = kp.shape[1] // block_k
-
-    maskp = None if kv_mask is None else _mask_rows(kv_mask, block_k)
 
     q_spec, k_spec, col_spec, mask_spec = _spec_family(
-        block_q, block_k, dh, h, q_minor=True)
+        block_q, block_k, dh, h)
     in_specs = [q_spec, k_spec, k_spec, q_spec, col_spec, col_spec]
     operands = [qp, kp, vp, dop, lsep, deltap]
-    if maskp is not None:
+    if kv_mask is not None:
         in_specs.append(mask_spec)
-        operands.append(maskp)
-    dq = pl.pallas_call(
-        functools.partial(
-            _bwd_dq_kernel, scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, t_q_real=t_q, t_k_real=t_k, n_k=n_k,
-            with_mask=maskp is not None),
-        grid=(b * h, n_q, n_k),
-        in_specs=in_specs,
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b * h, n_q * block_q, dh), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
-        interpret=interpret,
-    )(*operands)
+        operands.append(_mask_rows(kv_mask, block_k))
 
-    # dk/dv: k-block outermost, q innermost (the accumulation axis)
-    q_spec2, k_spec2, col_spec2, mask_spec2 = _spec_family(
-        block_q, block_k, dh, h, q_minor=False)
-    in_specs2 = [q_spec2, k_spec2, k_spec2, q_spec2, col_spec2, col_spec2]
-    operands2 = [qp, kp, vp, dop, lsep, deltap]
-    if maskp is not None:
-        in_specs2.append(mask_spec2)
-        operands2.append(maskp)
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _bwd_dkv_kernel, scale=scale, causal=causal, block_q=block_q,
-            block_k=block_k, t_q_real=t_q, t_k_real=t_k, n_q=n_q,
-            with_mask=maskp is not None),
-        grid=(b * h, n_k, n_q),
-        in_specs=in_specs2,
-        out_specs=(k_spec2, k_spec2),
-        out_shape=(
-            jax.ShapeDtypeStruct((b * h, n_k * block_k, dh), k.dtype),
-            jax.ShapeDtypeStruct((b * h, n_k * block_k, dh), v.dtype),
-        ),
-        scratch_shapes=[pltpu.VMEM((block_k, dh), jnp.float32),
-                        pltpu.VMEM((block_k, dh), jnp.float32)],
-        interpret=interpret,
-    )(*operands2)
+    def call(kernel, by_key, out_specs, out_shape, scratch_shapes):
+        return _scheduled_call(
+            functools.partial(
+                kernel, scale=1.0 / float(dh) ** 0.5, causal=causal,
+                block_q=block_q, block_k=block_k, t_q_real=t_q,
+                t_k_real=t_k, with_mask=kv_mask is not None),
+            (t_q, t_k, block_q, block_k, causal, by_key), b * h, in_specs,
+            out_specs, out_shape, scratch_shapes, interpret, operands)
+
+    dq = call(_bwd_dq_kernel, False, q_spec,
+              jax.ShapeDtypeStruct(qp.shape, q.dtype),
+              [pltpu.VMEM((block_q, dh), jnp.float32)])
+    # dk/dv: columns of pairs, q innermost (the accumulation axis)
+    dk, dv = call(_bwd_dkv_kernel, True, (k_spec, k_spec),
+                  (jax.ShapeDtypeStruct(kp.shape, k.dtype),
+                   jax.ShapeDtypeStruct(kp.shape, v.dtype)),
+                  [pltpu.VMEM((block_k, dh), jnp.float32),
+                   pltpu.VMEM((block_k, dh), jnp.float32)])
     return (dq[:, :t_q].reshape(b, h, t_q, dh),
             dk[:, :t_k].reshape(b, h, t_k, dh),
             dv[:, :t_k].reshape(b, h, t_k, dh))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def flash_attention(q, k, v, causal: bool = False, block_q: int = 512,
-                    block_k: int = 512, interpret: bool = False):
+def flash_attention(q, k, v, causal: bool = False, block_q=None,
+                    block_k=None, interpret: bool = False):
     """Flash attention, Pallas forward AND backward.
 
     The forward saves (q, k, v, out, lse); the backward recomputes each
@@ -479,7 +560,7 @@ _flash_attention_masked.defvjp(_fam_fwd, _fam_bwd)
 
 
 def flash_attention_masked(q, k, v, kv_mask=None, causal: bool = False,
-                           block_q: int = 512, block_k: int = 512,
+                           block_q=None, block_k=None,
                            interpret="auto"):
     """`flash_attention` with an optional (B, T_k) key-validity mask —
     the entry the sequence tower / Ulysses paths use (the mask rides as

@@ -12,9 +12,17 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from persia_tpu import metrics
 from persia_tpu.ops.flash_attention import (
+    EDGE,
+    FIRST,
+    LAST,
+    _blocks,
+    _clamp_block,
+    block_schedule,
     flash_attention,
     flash_attention_fwd_pallas,
+    flash_attention_masked,
 )
 from persia_tpu.parallel.ring_attention import (
     local_flash_attention,
@@ -124,8 +132,6 @@ def test_grad_bf16_finite_and_close():
 def test_kv_mask_fwd_and_grad(causal):
     """Masked path: parity with reference_attention's kv_mask handling,
     including a fully-masked batch row (output and grads -> 0)."""
-    from persia_tpu.ops.flash_attention import flash_attention_masked
-
     q, k, v = _qkv(t=288)
     rng = np.random.default_rng(3)
     kv_mask = jnp.asarray(rng.random((2, 288)) > 0.3)
@@ -149,6 +155,142 @@ def test_kv_mask_fwd_and_grad(causal):
     assert float(jnp.abs(out_p[1]).max()) == 0.0
     gp = jax.grad(loss_p, argnums=(0, 1, 2))(q, k, v)
     gr = jax.grad(loss_r, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gp, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+
+
+# (t_q, t_k, block_q, block_k): square and rectangular blocks, T ragged
+# against the block on either side, t_q != t_k both ways (with t_q < t_k
+# a causal call leaves whole key blocks without a query)
+SCHEDULE_SHAPES = [
+    (512, 512, 128, 128), (300, 300, 128, 128), (384, 640, 128, 256),
+    (640, 384, 256, 128), (144, 336, 128, 128), (1000, 1000, 512, 256),
+    (100, 100, 128, 128), (700, 260, 128, 256), (1024, 1024, 256, 512),
+]
+
+
+def _brute_force(t_q, t_k, block_q, block_k, causal):
+    """Per block pair, from the full (padded) score mask: is some score
+    live, is some score masked."""
+    n_q, n_k = -(-t_q // block_q), -(-t_k // block_k)
+    q_pos = np.arange(n_q * block_q)[:, None]
+    k_pos = np.arange(n_k * block_k)[None, :]
+    mask = (q_pos < t_q) & (k_pos < t_k)
+    if causal:
+        mask &= q_pos >= k_pos
+    blocks = mask.reshape(n_q, block_q, n_k, block_k)
+    return blocks.any(axis=(1, 3)), ~blocks.all(axis=(1, 3))
+
+
+@pytest.mark.parametrize("by_key", [False, True])
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("t_q,t_k,block_q,block_k", SCHEDULE_SHAPES)
+def test_schedule_matches_brute_force_mask(t_q, t_k, block_q, block_k,
+                                           causal, by_key):
+    """The table holds a pair exactly when some score of it is live,
+    marks it EDGE exactly when some score of it is masked, and walks
+    each accumulation row in ascending order, every row once, its first
+    and last pair marked. A row with nothing live keeps one pair, fully
+    masked, so that its output block is written."""
+    live, masked = _brute_force(t_q, t_k, block_q, block_k, causal)
+    qi, ki, flags = block_schedule(t_q, t_k, block_q, block_k, causal,
+                                   by_key)
+    assert qi.dtype == ki.dtype == flags.dtype == np.int32
+    listed = np.zeros_like(live)
+    listed[qi, ki] = True
+    assert len(set(zip(qi.tolist(), ki.tolist()))) == qi.size
+    row, col = (ki, qi) if by_key else (qi, ki)
+    live_rows = live.any(axis=0 if by_key else 1)
+    placeholders = ~live[qi, ki]
+    np.testing.assert_array_equal(listed & live, live)
+    # a placeholder only in a dead row, one a dead row, and masked
+    assert not live_rows[row[placeholders]].any()
+    assert placeholders.sum() == (~live_rows).sum()
+    np.testing.assert_array_equal((flags & EDGE) != 0, masked[qi, ki])
+    # rows in order, each once and whole; inside a row ascending
+    new_row = np.r_[True, np.diff(row) != 0]
+    np.testing.assert_array_equal(row[new_row], np.arange(live_rows.size))
+    assert (np.diff(col)[~new_row[1:]] > 0).all()
+    np.testing.assert_array_equal((flags & FIRST) != 0, new_row)
+    np.testing.assert_array_equal((flags & LAST) != 0,
+                                  np.r_[new_row[1:], True])
+
+
+@pytest.mark.parametrize("block,pairs", [
+    (512, [136, 256, 16]),      # the size until PR 34, and ISSUE 34's count
+    (None, [36, 64, 8]),        # the default at T 8192: 1024 x 1024
+])
+def test_schedule_at_the_cells_shape_and_its_gauges(block, pairs):
+    """T 8192, causal: in 512 x 512 blocks 136 pairs of the rectangle's
+    256, 16 of them on the diagonal; the gauges say so once a call is
+    built. A non-causal call walks the whole rectangle, no pair EDGE."""
+    size = block or 1024
+    for by_key in (False, True):
+        qi, ki, flags = block_schedule(8192, 8192, size, size, True, by_key)
+        on_edge = (flags & EDGE) != 0
+        assert [qi.size, on_edge.sum()] == [pairs[0], pairs[2]]
+        assert (qi[on_edge] == ki[on_edge]).all()
+    qi, ki, flags = block_schedule(8192, 8192, size, size, False)
+    assert qi.size == pairs[1] and not (flags & EDGE).any()
+    x = jax.ShapeDtypeStruct((1, 2, 8192, 128), jnp.bfloat16)
+    jax.eval_shape(lambda q, k, v: flash_attention_masked(
+        q, k, v, causal=True, block_q=block, block_k=block,
+        interpret=True), x, x, x)
+    reg = metrics.default_registry()
+    assert [reg.gauge(f"flash_attention_pairs_{n}").value
+            for n in ("walked", "dense", "edge")] == pairs
+
+
+@pytest.mark.parametrize("t,dh,dtype,want", [
+    (8192, 128, jnp.bfloat16, 1024), (8192, 256, jnp.bfloat16, 1024),
+    (4096, 256, jnp.bfloat16, 1024), (2048, 256, jnp.bfloat16, 1024),
+    (2048, 128, jnp.bfloat16, 1024), (8192, 64, jnp.float32, 1024),
+    (8192, 256, jnp.float32, 512),   # a 1 MiB tile: does not fit VMEM
+    (8192, 512, jnp.bfloat16, 512),
+    (300, 128, jnp.bfloat16, 384),   # clamped to T, in 128s
+    (100, 8, jnp.float32, 128),
+])
+def test_default_blocks_follow_the_tile_bytes(t, dh, dtype, want):
+    """No caller sets a block size; the file picks it from what the call
+    can see, and a request still wins (clamped as ever)."""
+    assert _blocks(None, None, t, t, dh, dtype) == (want, want)
+    assert _blocks(200, 64, t, t, dh, dtype) == (
+        _clamp_block(200, t), _clamp_block(64, t))
+
+
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("t_q,t_k,block_q,block_k", [
+    (300, 300, 256, 128), (300, 300, 128, 256), (144, 336, 128, 256),
+    (420, 200, 128, 128)])
+def test_causal_rectangular_blocks_ragged_t(t_q, t_k, block_q, block_k,
+                                            masked):
+    """Causal forward and gradients with block_q != block_k, T ragged
+    against both and t_q != t_k, against reference_attention; with a
+    kv_mask one batch row is fully masked (output and gradients 0)."""
+    q, k, v = _qkv(t=t_q, t_k=t_k, seed=7)
+    kv_mask = None
+    if masked:
+        rng = np.random.default_rng(11)
+        kv_mask = jnp.asarray(rng.random((2, t_k)) > 0.3).at[1, :].set(False)
+
+    def fwd_p(q, k, v):
+        return flash_attention_masked(
+            q, k, v, kv_mask=kv_mask, causal=True, block_q=block_q,
+            block_k=block_k, interpret=True)
+
+    def fwd_r(q, k, v):
+        return reference_attention(q, k, v, causal=True, kv_mask=kv_mask)
+
+    out = fwd_p(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(fwd_r(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    if masked:
+        assert float(jnp.abs(out[1]).max()) == 0.0
+    gp = jax.grad(lambda *x: jnp.mean(fwd_p(*x) ** 2), argnums=(0, 1, 2))(
+        q, k, v)
+    gr = jax.grad(lambda *x: jnp.mean(fwd_r(*x) ** 2), argnums=(0, 1, 2))(
+        q, k, v)
     for a, b in zip(gp, gr):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-4)
