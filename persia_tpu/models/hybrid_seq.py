@@ -12,9 +12,14 @@ driven like every other tower, through ``DeviceModeModel(slot_specs=[item
 slot], tower=HybridSequenceTower(...), pooling="none")`` and
 ``make_device_mode_trainer``.
 
-Every layer is ``h + mixer(RMSNorm(h))``; ``pattern`` names the mixers,
-one letter a layer (a published transformer block, attention then a
-feed-forward, is two letters):
+Every layer is ``h + mixer(RMSNorm(h))`` over one residual stream, or
+over ``residual_streams`` of them a hyper-connected layer
+(:class:`_HyperLayer`: the mixer's input read out of the streams, its
+output written back to each, the streams mixed by a doubly stochastic
+map, all three input-dependent; the item's row is copied to every stream
+before the first layer and the streams are summed before the final
+norm). ``pattern`` names the mixers, one letter a layer (a published
+transformer block, attention then a feed-forward, is two letters):
 
 ``M``  a Mamba-2 mixer: one input projection to a gate ``z``, the
        convolved stream ``xBC`` and a step size ``dt`` a head; a causal
@@ -30,9 +35,11 @@ feed-forward, is two letters):
        layers those carry position.
 ``L``  causal multi-head latent attention (:class:`LatentAttention`):
        low-rank query and key-value projections with a norm on each
-       latent, and rotary position embedding (:func:`rotary`) on a
-       decoupled part of every query head and on one key shared by all
-       the heads; the same flash kernel, at the head's whole width.
+       latent, and rotary position embedding (:func:`rotary`, plain or
+       under a :class:`YarnRule`) on a decoupled part of every query
+       head and on one key shared by all the heads; the same flash
+       kernel, queries and keys at the head's whole key width and
+       values at their own.
 ``D``  a gated dense feed-forward (:class:`GatedFeedForward`).
 
 ``mtp_depth`` 1 adds a :class:`NextPrediction` module after the last
@@ -76,8 +83,9 @@ twice). One body at one size is all the step holds of the grouped
 product, whose grid visits only the tiles of rows in use.
 
 Recomputation: each layer is wrapped in ``nn.remat``, so the backward
-pass holds one (batch, T, hidden) input a layer and rebuilds a layer's
-internals when it reaches it (one forward more a step).
+pass holds one (batch, T, hidden) input a layer, (batch, T, streams,
+hidden) over several streams, and rebuilds a layer's internals when it
+reaches it (one forward more a step).
 
 ``init`` declares every parameter and runs no mixer: a trainer that
 initialises eagerly (``make_device_mode_trainer``) would otherwise
@@ -462,16 +470,81 @@ class GroupedQueryAttention(nn.Module):
         return _dense(out, wo, cd)
 
 
-def rotary(x, theta):
+class YarnRule(NamedTuple):
+    """A YaRN rotary scaling rule, as a published ``rope_scaling`` record
+    of type ``yarn`` gives it (read as DeepSeek-V3's code reads those
+    keys): positions trained up to ``original_positions`` stretched by
+    ``factor``. Rotary pairs that turn more than ``beta_fast`` times over
+    the original positions keep their frequency, those that turn less
+    than ``beta_slow`` times have it divided by ``factor``, and a linear
+    ramp over the pair index joins the two. ``mscale`` and
+    ``mscale_all_dim`` feed :func:`yarn_mscale`: their ratio multiplies
+    cos and sin, and the second one's square the softmax scale."""
+
+    factor: float
+    original_positions: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+
+def yarn_mscale(factor, m):
+    """``0.1 m ln(factor) + 1``; 1 where nothing is stretched."""
+    return 0.1 * m * np.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_frequencies(dim, theta, rule):
+    """The ``dim // 2`` rotary frequencies under ``rule``, float64:
+    ``theta_i ((1 - ramp_i) + ramp_i / factor)`` with ``theta_i = theta
+    ** (-2 i / dim)`` and ``ramp_i = clip((i - low) / (high - low), 0,
+    1)``, ``low`` and ``high`` the pair indices that turn ``beta_fast``
+    and ``beta_slow`` times over the original positions (floor and
+    ceiling, clamped to the pairs there are)."""
+    half = dim // 2
+    plain = theta ** (-np.arange(half, dtype=np.float64) / half)
+
+    def pair_turning(times):
+        return (dim * np.log(rule.original_positions / (times * 2 * np.pi))
+                / (2 * np.log(theta)))
+
+    low = np.clip(np.floor(pair_turning(rule.beta_fast)), 0, half - 1)
+    high = np.clip(np.ceil(pair_turning(rule.beta_slow)), 0, half - 1)
+    ramp = np.clip((np.arange(half) - low) / max(high - low, 1e-3), 0, 1)
+    return plain * ((1 - ramp) + ramp / rule.factor)
+
+
+def softmax_scale(key_width, rule=None):
+    """What multiplies the scores of heads ``key_width`` wide: ``1 /
+    sqrt(key_width)``, times ``yarn_mscale(factor, mscale_all_dim) ** 2``
+    under a rule that sets ``mscale_all_dim``."""
+    scale = 1.0 / float(key_width) ** 0.5
+    if rule is not None and rule.mscale_all_dim:
+        scale *= yarn_mscale(rule.factor, rule.mscale_all_dim) ** 2
+    return scale
+
+
+def rotary(x, theta, rule=None):
     """Rotary position embedding of ``x`` (batch, T, heads, dim), in the
     rotate-half convention: feature ``i`` of the first half pairs with
     feature ``i`` of the second, and the pair at position ``t`` turns by
-    the angle ``t * theta ** (-2 i / dim)``. Positions are 0..T-1 a
+    the angle ``t * theta ** (-2 i / dim)``, or under a scaling ``rule``
+    (:class:`YarnRule`) by ``t`` times its :func:`yarn_frequencies`, cos
+    and sin then multiplied by ``yarn_mscale(factor, mscale) /
+    yarn_mscale(factor, mscale_all_dim)``. Positions are 0..T-1 a
     history. Float32 in and out."""
     t, half = x.shape[1], x.shape[-1] // 2
-    freq = jnp.exp(-np.log(theta) / half * jnp.arange(half, dtype=F32))
+    if rule is None:
+        freq = jnp.exp(-np.log(theta) / half * jnp.arange(half, dtype=F32))
+    else:
+        freq = jnp.asarray(yarn_frequencies(x.shape[-1], theta, rule), F32)
     angle = jnp.arange(t, dtype=F32)[:, None, None] * freq
     cos, sin = jnp.cos(angle), jnp.sin(angle)
+    if rule is not None:
+        amplitude = (yarn_mscale(rule.factor, rule.mscale)
+                     / yarn_mscale(rule.factor, rule.mscale_all_dim))
+        if amplitude != 1.0:
+            cos, sin = cos * amplitude, sin * amplitude
     x = x.astype(F32)
     a, b = x[..., :half], x[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
@@ -484,9 +557,12 @@ class LatentAttention(nn.Module):
     from one normed latent of ``kv_rank`` features; ``rope_dim`` further
     features of every query head, and one key of that width shared by all
     the heads, carry position through :func:`rotary`. A head's query and
-    key are ``[nope | rope]``; the flash kernel takes one width for
-    queries, keys and values, so ``nope_dim + rope_dim`` has to equal
-    ``v_dim``."""
+    key are ``[nope | rope]``, ``nope_dim + rope_dim`` wide; its value
+    and output are ``v_dim`` wide, which may be another width (the flash
+    kernel takes the two apart, neither padded to the other).
+    ``rope_scaling`` is None or a :class:`YarnRule`, which changes the
+    rotary frequencies and, through :func:`softmax_scale`, the factor the
+    kernel multiplies the scores by."""
 
     heads: int = 20
     q_rank: int = 768
@@ -498,16 +574,13 @@ class LatentAttention(nn.Module):
     eps: float = 1e-5
     out_scale: float = 1.0
     compute_dtype: Any = jnp.bfloat16
+    rope_scaling: Any = None
 
     @nn.compact
     def __call__(self, u):
         bs, t, hidden = u.shape
         cd, heads = self.compute_dtype, self.heads
         nope, rope, vd = self.nope_dim, self.rope_dim, self.v_dim
-        if nope + rope != vd:
-            raise ValueError(
-                f"query and key heads of {nope} + {rope} beside values of "
-                f"{vd}: the flash kernel takes one head width")
         q_a = self.param("q_a", _kernel_init(), (hidden, self.q_rank), F32)
         q_norm = self.param("q_norm", nn.initializers.ones, (self.q_rank,),
                             F32)
@@ -533,15 +606,18 @@ class LatentAttention(nn.Module):
             c_kv = (_rms(c_kv, self.eps) * kv_norm).astype(cd)
             kv = _dense(c_kv, kv_b, cd).reshape(bs, t, heads, nope + vd)
         with jax.named_scope("rotary"):
-            q_rope = rotary(q[..., nope:], self.rope_theta).astype(cd)
-            k_rope = rotary(k_rope[:, :, None, :], self.rope_theta).astype(cd)
+            rule = self.rope_scaling
+            q_rope = rotary(q[..., nope:], self.rope_theta, rule).astype(cd)
+            k_rope = rotary(k_rope[:, :, None, :], self.rope_theta,
+                            rule).astype(cd)
             q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
             k = jnp.concatenate(
                 [kv[..., :nope],
                  jnp.broadcast_to(k_rope, (bs, t, heads, rope))], axis=-1)
         q, k, v = (y.transpose(0, 2, 1, 3) for y in (q, k, kv[..., nope:]))
         with jax.named_scope("flash_attention"):    # the calls' name in a trace
-            out = flash_attention_masked(q, k, v, causal=True)
+            out = flash_attention_masked(
+                q, k, v, causal=True, scale=softmax_scale(nope + rope, rule))
         out = out.transpose(0, 2, 1, 3).reshape(bs, t, heads * vd)
         return _dense(out, wo, cd)
 
@@ -579,6 +655,102 @@ class _Layer(nn.Module):
         with jax.named_scope(self.scope_name):
             u = RMSNorm(self.eps, self.compute_dtype, name="norm")(h)
             return h + self.mixer(u)
+
+
+def sinkhorn(logits, iters, eps):
+    """``exp(logits)`` (.., n, n) made doubly stochastic by ``iters``
+    rounds of: every row over its sum + ``eps``, then every column over
+    its sum + ``eps``. A ``lax.scan`` over the rounds (unrolled they were
+    a third of the compiled step's code), differentiated through all of
+    them."""
+    def one_round(m, _):
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+        return m, None
+
+    m, _ = lax.scan(one_round, jnp.exp(logits), None, length=iters)
+    return m
+
+
+def _near_identity_init(streams):
+    """A diagonal of 4, row-major: the stream map starts near the
+    identity."""
+    def init(key, shape, dtype=F32):
+        return 4.0 * jnp.eye(streams, dtype=dtype).reshape(shape)
+    return init
+
+
+class _HyperLayer(nn.Module):
+    """A sublayer over ``streams`` residual streams (manifold-constrained
+    hyper-connections): ``x`` (batch, T, streams, hidden) in the compute
+    dtype. From the position's flattened streams ``z``, in float32,
+    ``m = (z phi) rsqrt(mean(z^2) + hyper_eps)`` gives three maps, each
+    with its part of ``phi``, a bias ``b`` and a scale ``a``: ``pre =
+    sigmoid(a m + b)`` (n,) reads the mixer's input out of the streams,
+    ``u = sum_j pre[j] x[j]``; ``post = 2 sigmoid(a m + b)`` (n,) writes
+    its output back to each; and ``res = sinkhorn(clip(a m + b,
+    *clamp))`` (n, n row-major), doubly stochastic, mixes the streams:
+    ``x'[i] = sum_j res[i, j] x[j] + post[i] mixer(RMSNorm(u))``.
+
+    A map's three parameters are leaves of their own
+    (``hyper_<map>_phi``, ``_bias``, ``_scale``): over the identical
+    streams that the expansion hands the first sublayer, ``pre`` only
+    scales a normed input and ``res`` mixes equals, and the last
+    sublayer's ``res`` is undone by the contraction (its columns sum to
+    one), so their gradients there are zero but for rounding, and an
+    optimizer's or a comparison's per-leaf statistics should not mix
+    them with live ones."""
+
+    mixer: nn.Module
+    scope_name: str
+    eps: float
+    compute_dtype: Any
+    streams: int
+    sinkhorn_iters: int
+    hyper_eps: float
+    clamp: Sequence[float]
+
+    @nn.compact
+    def __call__(self, x):
+        n, hidden, cd = self.streams, x.shape[-1], self.compute_dtype
+
+        def leaves(name, width, bias_init=nn.initializers.zeros):
+            return (self.param(f"hyper_{name}_phi", _kernel_init(),
+                               (n * hidden, width), F32),
+                    self.param(f"hyper_{name}_bias", bias_init, (width,),
+                               F32),
+                    self.param(f"hyper_{name}_scale",
+                               nn.initializers.constant(0.01), (1,), F32))
+
+        phi, bias, scale = zip(leaves("pre", n), leaves("post", n),
+                               leaves("res", n * n, _near_identity_init(n)))
+        norm = RMSNorm(self.eps, cd, name="norm")
+        if self.is_initializing():      # declares, runs no map or mixer
+            self.mixer(norm(x[..., 0, :]))
+            return x
+        with jax.named_scope("hyper_connection"):
+            with jax.named_scope("hyper_maps"):
+                z = x.reshape(*x.shape[:-2], n * hidden).astype(F32)
+                m = (jnp.dot(z, jnp.concatenate(phi, axis=-1),
+                             precision=lax.Precision.HIGHEST)
+                     * lax.rsqrt(jnp.mean(z * z, axis=-1, keepdims=True)
+                                 + self.hyper_eps))
+                pre, post, res = (
+                    a * part + b for a, b, part in zip(
+                        scale, bias, jnp.split(m, [n, 2 * n], axis=-1)))
+                pre, post = jax.nn.sigmoid(pre), 2 * jax.nn.sigmoid(post)
+                res = sinkhorn(
+                    jnp.clip(res, *self.clamp).reshape(*m.shape[:-1], n, n),
+                    self.sinkhorn_iters, self.hyper_eps)
+            with jax.named_scope("hyper_mix"):
+                u = sum(pre[..., j, None] * x[..., j, :] for j in range(n))
+        with jax.named_scope(self.scope_name):
+            y = self.mixer(norm(u.astype(cd)))
+        with jax.named_scope("hyper_connection"), \
+                jax.named_scope("hyper_mix"):
+            mixed = sum(res[..., :, j, None] * x[..., None, j, :]
+                        for j in range(n))
+            return (mixed + post[..., None] * y[..., None, :]).astype(cd)
 
 
 class NextPrediction(nn.Module):
@@ -654,18 +826,33 @@ class HybridSequenceTower(nn.Module):
     latent_v_dim: int = 256
     rope_theta: float = 1e6
     mtp_depth: int = 0
+    rope_scaling: Any = None        # None or a YarnRule
+    residual_streams: int = 1
+    sinkhorn_iters: int = 20
+    hyper_eps: float = 1e-6
+    hyper_clamp: Sequence[float] = (-30.0, 30.0)
 
     SCOPES = {"M": "ssm_mixer", "E": "experts", "*": "attention",
               "L": "latent_attention", "D": "dense_ffn"}
 
     def step_tags(self):
         """What ``make_device_mode_trainer`` tags its build with."""
+        widths = ()     # of attention's keys and values, where there is any
+        if "L" in self.pattern:
+            widths = (self.latent_nope_dim + self.latent_rope_dim,
+                      self.latent_v_dim)
+        elif "*" in self.pattern:
+            widths = (self.attn_head_dim, self.attn_head_dim)
+        hyper = self.residual_streams > 1
         return {"tower_layers": self.pattern,
                 "experts_held": tuple(self.experts_held),
                 "experts_routed": self.experts_routed,
                 "expert_matrices":
                     1 + ACTIVATIONS[self.expert_activation].wider,
-                "mtp_depth": self.mtp_depth}
+                "mtp_depth": self.mtp_depth,
+                "residual_streams": self.residual_streams,
+                "sinkhorn_iters": self.sinkhorn_iters * hyper,
+                **dict(zip(("key_width", "value_width"), widths))}
 
     def _mixer(self, kind, out_scale):
         """Unbound, so that the layer it is handed to adopts it."""
@@ -693,7 +880,7 @@ class HybridSequenceTower(nn.Module):
                                    self.latent_kv_rank, self.latent_nope_dim,
                                    self.latent_rope_dim, self.latent_v_dim,
                                    self.rope_theta, self.eps, out_scale, cd,
-                                   parent=None)
+                                   self.rope_scaling, parent=None)
         if kind == "D":
             return GatedFeedForward(self.dense_width, out_scale, cd,
                                     parent=None)
@@ -706,17 +893,32 @@ class HybridSequenceTower(nn.Module):
         if self.mtp_depth not in (0, 1):
             raise ValueError(f"mtp_depth {self.mtp_depth}: one prediction "
                              f"module or none")
+        streams = self.residual_streams
+        if streams > 1 and self.mtp_depth:
+            raise ValueError("no prediction module over residual streams")
         # output projections start smaller the deeper the stack
         # (the published rescale_prenorm_residual)
         out_scale = 1.0 / len(self.pattern)
-        layer = _Layer if self.is_initializing() else nn.remat(_Layer)
+        layer = _Layer if streams == 1 else _HyperLayer
+        if not self.is_initializing():
+            layer = nn.remat(layer)
+        hyper = () if streams == 1 else (
+            streams, self.sinkhorn_iters, self.hyper_eps,
+            tuple(self.hyper_clamp))
 
         def layer_of(kind, **how):
             return layer(self._mixer(kind, out_scale), self.SCOPES[kind],
-                         self.eps, self.compute_dtype, **how)
+                         self.eps, self.compute_dtype, *hyper, **how)
 
+        if streams > 1:
+            with jax.named_scope("hyper_expand"):   # every stream the row
+                h = jnp.broadcast_to(h[:, :, None, :],
+                                     (*h.shape[:2], streams, h.shape[-1]))
         for i, kind in enumerate(self.pattern):
             h = layer_of(kind, name=f"layer_{i}")(h)
+        if streams > 1:
+            with jax.named_scope("hyper_contract"):
+                h = jnp.sum(h.astype(F32), axis=2).astype(self.compute_dtype)
         with jax.named_scope("item_head"):
             u = RMSNorm(self.eps, self.compute_dtype, name="final_norm")(h)
             w = self.param("item_head", _kernel_init(),

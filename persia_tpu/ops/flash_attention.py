@@ -26,10 +26,15 @@ for the mask (``interior`` pairs run the same body without the iotas,
 the comparisons and the select). A non-causal call gets its whole
 rectangle from the same function.
 
-Kernel shape rules: dh is the lane axis of every block, one width for
-queries, keys and values (any dh ≤ 128 works, full-axis blocks are
-padded internally; a multiple of 128 above that too: the latent
-attention tower's heads are 256 wide). Block sizes are multiples of the
+Kernel shape rules: the head width is the lane axis of every block, and
+there are two of them: queries, keys and their gradients are ``dk``
+wide, values, the output and its cotangent ``dv`` (scores contract
+``dk``, the output emits ``dv``; neither is padded to the other in HBM).
+A width is a full-axis block, padded to whole lanes in VMEM: 64, 128,
+256 and 192 beside 128 (a latent-attention head of 128 + 64 rotary key
+features over values of 128) all lower. The softmax scale is the
+caller's, ``1 / sqrt(dk)`` where it gives none (a rotary scaling rule
+brings its own factor). Block sizes are multiples of the
 128-lane width (``_clamp_block``) so every tile — bf16 (16, 128) included — and every per-row lane vector
 (lse, delta, kv_mask ride as ``(.., 1, T)`` rows blocked ``(1, block)``)
 meets the TPU lowering's (8, 128) block rule. T is padded to the k/q
@@ -163,7 +168,7 @@ def _fwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, *rest,
         l_scr[...] = jnp.zeros_like(l_scr)
 
     def _body(edge: bool):
-        q = q_ref[0]                       # (block_q, dh) bf16/f32
+        q = q_ref[0]                       # (block_q, dk) bf16/f32
         k = k_ref[0]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
@@ -188,7 +193,7 @@ def _fwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, *rest,
         m_scr[...] = m_new
         pv = jax.lax.dot_general(
             p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # (bq, dh)
+            preferred_element_type=jnp.float32)         # (bq, dv)
         acc[...] = acc[...] * alpha[:, :1] + pv
 
     _run_bodies(_body, flags, has_edge, has_interior)
@@ -215,8 +220,9 @@ def _clamp_block(block, t):
     return -(-min(block, max(t, 1)) // _LANES) * _LANES
 
 
-def _blocks(block_q, block_k, t_q, t_k, dh, dtype):
-    """The call's block sizes: a caller's request, or where it makes
+def _blocks(block_q, block_k, t_q, t_k, width, dtype):
+    """The call's block sizes at head width ``width`` (the wider of the
+    key and value widths): a caller's request, or where it makes
     none the size the kernels alone ran fastest at on a v5e (PERF.md §6,
     "PR 34": T 2048-8192, head widths 64-256, bfloat16). 1024 rows a
     block: at T 8192 the three calls take a third less time than in 512s
@@ -225,7 +231,7 @@ def _blocks(block_q, block_k, t_q, t_k, dh, dtype):
     spread over four times the scores. 512 where a 1024-row tile of the
     inputs would pass 512 KiB (float32 at width 256), which does not fit
     VMEM beside the float32 score blocks. Either is clamped to T."""
-    fits = 1024 * dh * jnp.dtype(dtype).itemsize <= 512 * 1024
+    fits = 1024 * width * jnp.dtype(dtype).itemsize <= 512 * 1024
     default = 1024 if fits else 512
     return (_clamp_block(block_q or default, t_q),
             _clamp_block(block_k or default, t_k))
@@ -241,15 +247,22 @@ def _pad_t(x, block, axis=1):
     return x
 
 
+def _scale(scale, dk):
+    """The scores' factor: the caller's, or ``1 / sqrt(dk)``."""
+    return 1.0 / float(dk) ** 0.5 if scale is None else float(scale)
+
+
 def _mask_rows(kv_mask, block_k):
     """(B, T_k) key-validity mask -> (B, 1, T_k padded) f32 0/1 rows."""
     return _pad_t(kv_mask.astype(jnp.float32), block_k)[:, None, :]
 
 
-def _spec_family(block_q, block_k, dh, h):
-    """The four block-spec shapes every kernel here uses: q-tile,
-    k-tile, per-q lane row (lse/delta, arrays shaped (BH, 1, T)), per-k
-    lane row (kv_mask, (B, 1, T), batch axis = bh // h). The row arrays
+def _spec_family(block_q, block_k, dk, dv, h):
+    """The six block-spec shapes every kernel here uses: q-tile and
+    k-tile at the key width (q, k, dq, dk), v-tile and o-tile at the
+    value width (v, dv by key block; out, do by query block), per-q lane
+    row (lse/delta, arrays shaped (BH, 1, T)), per-k lane row (kv_mask,
+    (B, 1, T), batch axis = bh // h). The row arrays
     carry a unit sublane axis so the block's last two dims are
     (1 == full, block % 128 == 0) — a bare ``(1, block)`` block over
     ``(BH, T)`` does not lower for TPU. The grid is (bh, step) and a
@@ -257,11 +270,16 @@ def _spec_family(block_q, block_k, dh, h):
     whatever order the schedule walks them in. One definition so a
     layout change cannot drift between the forward and the two backward
     calls."""
+    def by_query(width):
+        return pl.BlockSpec((1, block_q, width),
+                            lambda bh, s, qi, ki, flags: (bh, qi[s], 0))
+
+    def by_key(width):
+        return pl.BlockSpec((1, block_k, width),
+                            lambda bh, s, qi, ki, flags: (bh, ki[s], 0))
+
     return (
-        pl.BlockSpec((1, block_q, dh),
-                     lambda bh, s, qi, ki, flags: (bh, qi[s], 0)),
-        pl.BlockSpec((1, block_k, dh),
-                     lambda bh, s, qi, ki, flags: (bh, ki[s], 0)),
+        by_query(dk), by_key(dk), by_key(dv), by_query(dv),
         pl.BlockSpec((None, 1, block_q),
                      lambda bh, s, qi, ki, flags: (bh, 0, qi[s])),
         pl.BlockSpec((None, 1, block_k),
@@ -302,32 +320,34 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool = False,
                                block_q=None, block_k=None,
                                interpret: bool = False,
                                return_lse: bool = False,
-                               kv_mask=None):
-    """Forward Pallas flash attention. q/k/v: (B, H, T, Dh).
+                               kv_mask=None, scale=None):
+    """Forward Pallas flash attention. q/k: (B, H, T, Dk), v and the
+    output (B, H, T, Dv); ``scale`` multiplies the scores, ``1 /
+    sqrt(Dk)`` where it is None.
 
     With ``return_lse`` also returns the (B, H, T) logsumexp residual
     the backward kernels consume. ``kv_mask`` optional (B, T_k) of
     valid key positions; fully-masked query rows yield 0."""
-    b, h, t_q, dh = q.shape
-    t_k = k.shape[2]
-    block_q, block_k = _blocks(block_q, block_k, t_q, t_k, dh, q.dtype)
-    qp = _pad_t(q.reshape(b * h, t_q, dh), block_q)
-    kp = _pad_t(k.reshape(b * h, t_k, dh), block_k)
-    vp = _pad_t(v.reshape(b * h, t_k, dh), block_k)
+    b, h, t_q, dk = q.shape
+    t_k, dv = k.shape[2], v.shape[3]
+    block_q, block_k = _blocks(block_q, block_k, t_q, t_k, max(dk, dv),
+                               q.dtype)
+    qp = _pad_t(q.reshape(b * h, t_q, dk), block_q)
+    kp = _pad_t(k.reshape(b * h, t_k, dk), block_k)
+    vp = _pad_t(v.reshape(b * h, t_k, dv), block_k)
     kernel = functools.partial(
-        _fwd_kernel, scale=1.0 / float(dh) ** 0.5, causal=causal,
+        _fwd_kernel, scale=_scale(scale, dk), causal=causal,
         block_q=block_q, block_k=block_k, t_k_real=t_k,
         with_lse=return_lse, with_mask=kv_mask is not None)
-    q_spec, k_spec, qrow_spec, krow_spec = _spec_family(
-        block_q, block_k, dh, h)
-    in_specs = [q_spec, k_spec, k_spec]
+    q_spec, k_spec, v_spec, o_spec, qrow_spec, krow_spec = _spec_family(
+        block_q, block_k, dk, dv, h)
+    in_specs = [q_spec, k_spec, v_spec]
     operands = [qp, kp, vp]
     if kv_mask is not None:
         # (B, T_k) f32 0/1; the grid's bh axis maps back to batch bh//h
         in_specs.append(krow_spec)
         operands.append(_mask_rows(kv_mask, block_k))
-    o_spec = q_spec
-    o_shape = jax.ShapeDtypeStruct(qp.shape, q.dtype)
+    o_shape = jax.ShapeDtypeStruct((*qp.shape[:2], dv), q.dtype)
     if return_lse:
         out_specs = (o_spec, qrow_spec)
         out_shape = (o_shape, jax.ShapeDtypeStruct(
@@ -337,15 +357,15 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool = False,
     res = _scheduled_call(
         kernel, (t_q, t_k, block_q, block_k, causal), b * h, in_specs,
         out_specs, out_shape,
-        [pltpu.VMEM((block_q, dh), jnp.float32),
+        [pltpu.VMEM((block_q, dv), jnp.float32),
          pltpu.VMEM((block_q, _LANES), jnp.float32),
          pltpu.VMEM((block_q, _LANES), jnp.float32)],
         interpret, operands)
     if return_lse:
         out, lse = res
-        return (out[:, :t_q].reshape(b, h, t_q, dh),
+        return (out[:, :t_q].reshape(b, h, t_q, dv),
                 lse[:, 0, :t_q].reshape(b, h, t_q))
-    return res[:, :t_q].reshape(b, h, t_q, dh)
+    return res[:, :t_q].reshape(b, h, t_q, dv)
 
 
 def _masked_p(q, k, lse, mask, *, scale):
@@ -449,8 +469,10 @@ def _bwd_dkv_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
 
 def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
                                block_q=None, block_k=None,
-                               interpret: bool = False, kv_mask=None):
-    """Pallas flash-attention backward: (dq, dk, dv).
+                               interpret: bool = False, kv_mask=None,
+                               scale=None):
+    """Pallas flash-attention backward: (dq, dk, dv), the first two at
+    the key width, the third at the value width.
 
     Same schedule as the forward, run twice: dq revisits its q-block
     accumulator along a row of pairs; dk/dv revisit their k-block
@@ -458,22 +480,23 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
     recomputed from (q, k, lse) in VMEM — nothing quadratic ever
     touches HBM.
     """
-    b, h, t_q, dh = q.shape
-    t_k = k.shape[2]
-    block_q, block_k = _blocks(block_q, block_k, t_q, t_k, dh, q.dtype)
+    b, h, t_q, dk = q.shape
+    t_k, dv = k.shape[2], v.shape[3]
+    block_q, block_k = _blocks(block_q, block_k, t_q, t_k, max(dk, dv),
+                               q.dtype)
     # delta_i = rowsum(dO_i * O_i) — cheap XLA elementwise+reduce
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)                             # (b, h, t_q)
-    qp = _pad_t(q.reshape(b * h, t_q, dh), block_q)
-    kp = _pad_t(k.reshape(b * h, t_k, dh), block_k)
-    vp = _pad_t(v.reshape(b * h, t_k, dh), block_k)
-    dop = _pad_t(do.reshape(b * h, t_q, dh), block_q)
+    qp = _pad_t(q.reshape(b * h, t_q, dk), block_q)
+    kp = _pad_t(k.reshape(b * h, t_k, dk), block_k)
+    vp = _pad_t(v.reshape(b * h, t_k, dv), block_k)
+    dop = _pad_t(do.reshape(b * h, t_q, dv), block_q)
     lsep = _pad_t(lse.reshape(b * h, t_q), block_q)[:, None, :]
     deltap = _pad_t(delta.reshape(b * h, t_q), block_q)[:, None, :]
 
-    q_spec, k_spec, col_spec, mask_spec = _spec_family(
-        block_q, block_k, dh, h)
-    in_specs = [q_spec, k_spec, k_spec, q_spec, col_spec, col_spec]
+    q_spec, k_spec, v_spec, o_spec, col_spec, mask_spec = _spec_family(
+        block_q, block_k, dk, dv, h)
+    in_specs = [q_spec, k_spec, v_spec, o_spec, col_spec, col_spec]
     operands = [qp, kp, vp, dop, lsep, deltap]
     if kv_mask is not None:
         in_specs.append(mask_spec)
@@ -482,29 +505,29 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
     def call(kernel, by_key, out_specs, out_shape, scratch_shapes):
         return _scheduled_call(
             functools.partial(
-                kernel, scale=1.0 / float(dh) ** 0.5, causal=causal,
+                kernel, scale=_scale(scale, dk), causal=causal,
                 block_q=block_q, block_k=block_k, t_q_real=t_q,
                 t_k_real=t_k, with_mask=kv_mask is not None),
             (t_q, t_k, block_q, block_k, causal, by_key), b * h, in_specs,
             out_specs, out_shape, scratch_shapes, interpret, operands)
 
-    dq = call(_bwd_dq_kernel, False, q_spec,
-              jax.ShapeDtypeStruct(qp.shape, q.dtype),
-              [pltpu.VMEM((block_q, dh), jnp.float32)])
+    g_q = call(_bwd_dq_kernel, False, q_spec,
+               jax.ShapeDtypeStruct(qp.shape, q.dtype),
+               [pltpu.VMEM((block_q, dk), jnp.float32)])
     # dk/dv: columns of pairs, q innermost (the accumulation axis)
-    dk, dv = call(_bwd_dkv_kernel, True, (k_spec, k_spec),
-                  (jax.ShapeDtypeStruct(kp.shape, k.dtype),
-                   jax.ShapeDtypeStruct(kp.shape, v.dtype)),
-                  [pltpu.VMEM((block_k, dh), jnp.float32),
-                   pltpu.VMEM((block_k, dh), jnp.float32)])
-    return (dq[:, :t_q].reshape(b, h, t_q, dh),
-            dk[:, :t_k].reshape(b, h, t_k, dh),
-            dv[:, :t_k].reshape(b, h, t_k, dh))
+    g_k, g_v = call(_bwd_dkv_kernel, True, (k_spec, v_spec),
+                    (jax.ShapeDtypeStruct(kp.shape, k.dtype),
+                     jax.ShapeDtypeStruct(vp.shape, v.dtype)),
+                    [pltpu.VMEM((block_k, dk), jnp.float32),
+                     pltpu.VMEM((block_k, dv), jnp.float32)])
+    return (g_q[:, :t_q].reshape(b, h, t_q, dk),
+            g_k[:, :t_k].reshape(b, h, t_k, dk),
+            g_v[:, :t_k].reshape(b, h, t_k, dv))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal: bool = False, block_q=None,
-                    block_k=None, interpret: bool = False):
+                    block_k=None, interpret: bool = False, scale=None):
     """Flash attention, Pallas forward AND backward.
 
     The forward saves (q, k, v, out, lse); the backward recomputes each
@@ -513,46 +536,46 @@ def flash_attention(q, k, v, causal: bool = False, block_q=None,
     """
     return flash_attention_fwd_pallas(q, k, v, causal=causal,
                                       block_q=block_q, block_k=block_k,
-                                      interpret=interpret)
+                                      interpret=interpret, scale=scale)
 
 
-def _fa_fwd(q, k, v, causal, block_q, block_k, interpret):
+def _fa_fwd(q, k, v, causal, block_q, block_k, interpret, scale):
     out, lse = flash_attention_fwd_pallas(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, return_lse=True)
+        interpret=interpret, return_lse=True, scale=scale)
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(causal, block_q, block_k, interpret, res, g):
+def _fa_bwd(causal, block_q, block_k, interpret, scale, res, g):
     q, k, v, out, lse = res
     return flash_attention_bwd_pallas(
         q, k, v, out, lse, g, causal=causal, block_q=block_q,
-        block_k=block_k, interpret=interpret)
+        block_k=block_k, interpret=interpret, scale=scale)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
 def _flash_attention_masked(q, k, v, maskf, causal, block_q, block_k,
-                            interpret):
+                            interpret, scale):
     return flash_attention_fwd_pallas(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, kv_mask=maskf)
+        interpret=interpret, kv_mask=maskf, scale=scale)
 
 
-def _fam_fwd(q, k, v, maskf, causal, block_q, block_k, interpret):
+def _fam_fwd(q, k, v, maskf, causal, block_q, block_k, interpret, scale):
     out, lse = flash_attention_fwd_pallas(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, return_lse=True, kv_mask=maskf)
+        interpret=interpret, return_lse=True, kv_mask=maskf, scale=scale)
     return out, (q, k, v, out, lse, maskf)
 
 
-def _fam_bwd(causal, block_q, block_k, interpret, res, g):
+def _fam_bwd(causal, block_q, block_k, interpret, scale, res, g):
     q, k, v, out, lse, maskf = res
     dq, dk, dv = flash_attention_bwd_pallas(
         q, k, v, out, lse, g, causal=causal, block_q=block_q,
-        block_k=block_k, interpret=interpret, kv_mask=maskf)
+        block_k=block_k, interpret=interpret, kv_mask=maskf, scale=scale)
     return dq, dk, dv, jnp.zeros_like(maskf)
 
 
@@ -561,16 +584,18 @@ _flash_attention_masked.defvjp(_fam_fwd, _fam_bwd)
 
 def flash_attention_masked(q, k, v, kv_mask=None, causal: bool = False,
                            block_q=None, block_k=None,
-                           interpret="auto"):
+                           interpret="auto", scale=None):
     """`flash_attention` with an optional (B, T_k) key-validity mask —
     the entry the sequence tower / Ulysses paths use (the mask rides as
     f32 0/1 so the custom_vjp plumbing stays all-float; its cotangent
     is zero). ``interpret="auto"`` compiles on TPU and falls back to
-    the Pallas interpreter elsewhere (CPU tests)."""
+    the Pallas interpreter elsewhere (CPU tests). ``scale`` multiplies
+    the scores: a Python number, ``1 / sqrt(key width)`` where None."""
     if interpret == "auto":
         interpret = jax.default_backend() != "tpu"
     if kv_mask is None:
-        return flash_attention(q, k, v, causal, block_q, block_k, interpret)
+        return flash_attention(q, k, v, causal, block_q, block_k, interpret,
+                               scale)
     return _flash_attention_masked(
         q, k, v, kv_mask.astype(jnp.float32), causal, block_q, block_k,
-        interpret)
+        interpret, scale)

@@ -28,7 +28,9 @@ returns becomes tags of the span ``trainer/build_device_step`` as given,
 and gauges ``device_mode_<name>`` (an int as it is, a sequence by its
 length). The hybrid sequence tower gives ``tower_layers`` (its pattern),
 ``experts_held``, ``experts_routed``, ``expert_matrices`` (2 for
-square-relu experts, 3 for silu-gated ones) and ``mtp_depth``.
+square-relu experts, 3 for silu-gated ones), ``mtp_depth``,
+``residual_streams``, ``sinkhorn_iters`` (0 at one stream) and, where
+its pattern has attention, ``key_width`` and ``value_width``.
 """
 
 from typing import Any, Callable, Dict, Sequence, Tuple

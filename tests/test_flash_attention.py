@@ -296,6 +296,83 @@ def test_causal_rectangular_blocks_ragged_t(t_q, t_k, block_q, block_k,
                                    rtol=1e-4, atol=1e-4)
 
 
+# --- key width and value width apart, the softmax scale an argument --------
+
+
+def _plain_attention(q, k, v, causal, scale, kv_mask=None):
+    """softmax(scale q k^T) v as a full score matrix, float32."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   precision="highest") * scale
+    keep = jnp.ones(s.shape[-2:], bool)
+    if causal:
+        keep = jnp.tril(keep)
+    keep = keep[None, None]
+    if kv_mask is not None:
+        keep = keep & kv_mask[:, None, None, :]
+    s = jnp.where(keep, s, -jnp.inf)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v,
+                      precision="highest")
+
+
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("dk,dv,t,scale", [
+    (192, 128, 300, None),      # the hyper-connected tower's heads
+    (24, 16, 260, 0.31),        # a small pair, the scale the caller's
+    (16, 24, 200, None),        # values wider than keys
+])
+def test_unequal_widths_match_plain_attention(dk, dv, t, scale, masked):
+    """q and k ``dk`` wide, v, the output and its cotangent ``dv``: the
+    forward, its ``lse`` and all three gradients (dq and dk at ``dk``,
+    dv at ``dv``) against the full score matrix, interpreted, causal,
+    T over two blocks with a ragged tail. float32 in and out: 2e-5
+    absolute on values of order one is the accumulation order's
+    round-off, as in the equal-width tests above."""
+    rng = np.random.default_rng(dk + dv)
+    q, k = (jnp.asarray(rng.normal(size=(1, 2, t, dk)), jnp.float32)
+            for _ in range(2))
+    v, w = (jnp.asarray(rng.normal(size=(1, 2, t, dv)), jnp.float32)
+            for _ in range(2))
+    mask = None
+    if masked:
+        mask = jnp.asarray(rng.random((1, t)) > 0.2).at[:, 0].set(True)
+    factor = 1.0 / np.sqrt(dk) if scale is None else scale
+
+    def mine(q, k, v):
+        return flash_attention_masked(q, k, v, kv_mask=mask, causal=True,
+                                      block_q=128, block_k=128,
+                                      interpret=True, scale=scale)
+
+    def plain(q, k, v):
+        return _plain_attention(q, k, v, True, factor, mask)
+
+    out = mine(q, k, v)
+    assert out.shape == (1, 2, t, dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(plain(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    got = jax.grad(lambda *x: jnp.sum(w * mine(*x)), argnums=(0, 1, 2))(
+        q, k, v)
+    want = jax.grad(lambda *x: jnp.sum(w * plain(*x)), argnums=(0, 1, 2))(
+        q, k, v)
+    assert [g.shape[-1] for g in got] == [dk, dk, dv]
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_the_scale_defaults_to_the_key_width_s():
+    """No scale given is ``1 / sqrt(dk)`` whatever ``dv``, bit for bit
+    the explicit call."""
+    rng = np.random.default_rng(5)
+    q, k = (jnp.asarray(rng.normal(size=(1, 1, 130, 24)), jnp.float32)
+            for _ in range(2))
+    v = jnp.asarray(rng.normal(size=(1, 1, 130, 8)), jnp.float32)
+    kw = dict(causal=True, interpret=True)
+    np.testing.assert_array_equal(
+        np.asarray(flash_attention_fwd_pallas(q, k, v, **kw)),
+        np.asarray(flash_attention_fwd_pallas(q, k, v, scale=24 ** -0.5,
+                                              **kw)))
+
+
 def test_sequence_tower_pallas_impl():
     """SequenceSelfAttention(attn_impl='pallas') matches the xla impl
     through the flax module (single-device path)."""

@@ -448,7 +448,9 @@ def test_the_configuration_states_the_parameters_it_runs():
     assert tower.step_tags() == {"tower_layers": "MEMEM*EME",
                                  "experts_held": tuple(range(8)),
                                  "experts_routed": 128, "expert_matrices": 2,
-                                 "mtp_depth": 0}
+                                 "mtp_depth": 0, "residual_streams": 1,
+                                 "sinkhorn_iters": 0, "key_width": 128,
+                                 "value_width": 128}
 
 
 def test_kernels_roofline_is_the_algorithm_s_need_at_the_rows_routed():

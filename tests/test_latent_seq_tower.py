@@ -155,38 +155,69 @@ def test_latent_attention_reads_position_and_nothing_ahead():
     assert np.abs(np.asarray(out[:, -1] - out3[:, -1])).max() > 1e-4
 
 
-def test_value_heads_of_another_width_are_refused():
-    tower = placement.build_tower(dict(SZ, v_dim=16), compute_dtype=F32)
-    with pytest.raises(ValueError, match="one head width"):
-        tower._mixer("L", 1.0).init(jax.random.key(0),
-                                    jnp.zeros((1, 8, 64), F32))
+@pytest.mark.parametrize("v_dim", [16, 48])
+def test_value_heads_of_another_width_match_the_reference(v_dim):
+    """Keys of 24 + 8 beside values of 16 or 48 (the kernel takes the
+    two widths apart since PR 35; until then the layer refused them):
+    the output and the gradient of every leaf against the reference's
+    full score matrix, 2e-4 of the largest entry as above."""
+    sz = dict(SZ, v_dim=v_dim)
+    p = _layer_params(_leaves(13, sz), 0, sz)
+    assert p["kv_b"].shape == (16, 4 * (24 + v_dim))
+    assert p["o_proj"].shape == (4 * v_dim, 64)
+    u = jnp.asarray(np.random.default_rng(6).normal(size=(2, 40, 64)), F32)
+    w = jnp.asarray(np.random.default_rng(7).normal(size=(2, 40, 64)), F32)
+    mixer = placement.build_tower(sz, compute_dtype=F32)._mixer("L", 1.0)
+
+    def mine(p, u):
+        return mixer.apply({"params": p}, u)
+
+    def theirs(p, u):
+        return ref.latent_attention(p, u, sz, lambda v: v)
+
+    _close(_highest(mine, p, u), _highest(theirs, p, u))
+    got = _highest(jax.grad(lambda p, u: jnp.sum(w * mine(p, u)),
+                            argnums=(0, 1)), p, u)
+    want = _highest(jax.grad(lambda p, u: jnp.sum(w * theirs(p, u)),
+                             argnums=(0, 1)), p, u)
+    for name in p:
+        _close(got[0][name], want[0][name])
+    _close(got[1], want[1])
 
 
 # --- the share: what one chip of an expert-parallel job computes ------------
 
 
-def test_the_eight_shares_of_a_gated_layer_add_up_to_the_uncut_layer():
-    """16 routed experts in 8 shares of 2: the eight shares' routed
-    parts, with the shared expert counted once, are the uncut reference
-    layer, and every (token, expert) pair is routed to one share."""
-    sz = dict(SZ, experts_held=list(range(16)))
+@pytest.mark.parametrize("routed,per_token,scaling", [
+    (16, 2, 1.8),       # this tower's rehearsal router
+    (64, 4, 2),         # the hyper-connected tower's: 64 routed, top 4
+], ids=["16_routed_top_2", "64_routed_top_4"])
+def test_the_eight_shares_of_a_gated_layer_add_up_to_the_uncut_layer(
+        routed, per_token, scaling):
+    """The routed experts in 8 shares: the eight shares' routed parts,
+    with the shared expert counted once, are the uncut reference layer,
+    and every (token, expert) pair is routed to one share."""
+    sz = dict(SZ, experts_routed=routed, experts_per_token=per_token,
+              routed_scaling=scaling, experts_held=list(range(routed)))
     whole = _layer_params(_leaves(21, sz), 3, sz)
     u = jnp.asarray(np.random.default_rng(8).normal(size=(2, 40, 64)), F32)
-    want = _highest(lambda: ref.experts(whole, u, SZ, lambda v: v,
-                                        held=list(range(16))))
+    want = _highest(lambda: ref.experts(whole, u, sz, lambda v: v,
+                                        held=list(range(routed))))
     shared = _highest(lambda: ref.shared_expert(
         whole, u.reshape(-1, 64), lambda v: v)).reshape(u.shape)
     total, rows = 0.0, 0
-    for first in range(0, 16, 2):
-        ids = [first, first + 1]
+    for first in range(0, routed, routed // 8):
+        ids = list(range(first, first + routed // 8))
         part = dict(whole, w1=whole["w1"][np.asarray(ids)],
                     w2=whole["w2"][np.asarray(ids)])
-        out, state = _highest(lambda: _mixer("E", held=ids).apply(
+        mixer = placement.build_tower(dict(sz, experts_held=ids),
+                                      compute_dtype=F32)._mixer("E", 1.0)
+        out, state = _highest(lambda: mixer.apply(
             {"params": part}, u, mutable=["intermediates"]))
         total = total + (out - shared)
         rows += int(np.sum(state["intermediates"]["routed_rows"][0]))
     _close(total + shared, want)
-    assert rows == 2 * 40 * SZ["experts_per_token"]     # every pair, once
+    assert rows == 2 * 40 * per_token       # every pair, once
 
 
 # --- the prediction module ---------------------------------------------------
@@ -420,7 +451,9 @@ def test_the_configuration_states_the_parameters_it_runs():
     assert tower.step_tags() == {"tower_layers": "LDLELELELE",
                                  "experts_held": tuple(range(8)),
                                  "experts_routed": 64, "expert_matrices": 3,
-                                 "mtp_depth": 1}
+                                 "mtp_depth": 1, "residual_streams": 1,
+                                 "sinkhorn_iters": 0, "key_width": 256,
+                                 "value_width": 256}
     model = placement.build_model(sz)
     shapes = jax.eval_shape(
         lambda: model.init(jax.random.key(0), [],

@@ -36,16 +36,20 @@ from persia_tpu.ops.flash_attention import (  # noqa: E402
     flash_attention_masked,
 )
 
-# (T, dh, dtype): the smoke's two lengths at the full head width, the
-# latent attention tower's history at its 256-wide head, plus small
-# ragged shapes the CPU tests use
+# (T, dk, dv, dtype), key width and value width: the smoke's two lengths
+# at the full head width, the latent attention towers' history at a
+# 256-wide head and at keys of 192 beside values of 128, plus small
+# ragged shapes the CPU tests use (one of them unequal)
 SHAPES = [
-    (4096, 128, jnp.bfloat16),
-    (1000, 128, jnp.bfloat16),
-    (8192, 256, jnp.bfloat16),
-    (200, 64, jnp.bfloat16),
-    (100, 8, jnp.float32),
+    (4096, 128, 128, jnp.bfloat16),
+    (1000, 128, 128, jnp.bfloat16),
+    (8192, 256, 256, jnp.bfloat16),
+    (8192, 192, 128, jnp.bfloat16),
+    (200, 64, 64, jnp.bfloat16),
+    (100, 8, 8, jnp.float32),
+    (200, 24, 16, jnp.bfloat16),
 ]
+AOT_SHAPES = SHAPES[:4]
 VARIANTS = list(itertools.product([False, True], [False, True]))  # mask×grad
 
 
@@ -66,18 +70,19 @@ def _attn_fn(masked: bool, grad: bool, causal: bool = False):
     return bwd
 
 
-def _avals(t, dh, dtype, sharding=None):
-    q = jax.ShapeDtypeStruct((4, 8, t, dh), dtype, sharding=sharding)
+def _avals(t, dk, dv, dtype, sharding=None):
+    q = jax.ShapeDtypeStruct((4, 8, t, dk), dtype, sharding=sharding)
+    v = jax.ShapeDtypeStruct((4, 8, t, dv), dtype, sharding=sharding)
     m = jax.ShapeDtypeStruct((4, t), jnp.bool_, sharding=sharding)
-    return q, q, q, m
+    return q, q, v, m
 
 
 @pytest.mark.parametrize("masked,grad", VARIANTS)
-@pytest.mark.parametrize("t,dh,dtype", SHAPES)
-def test_attention_cross_lowers_for_tpu(t, dh, dtype, masked, grad):
+@pytest.mark.parametrize("t,dk,dv,dtype", SHAPES)
+def test_attention_cross_lowers_for_tpu(t, dk, dv, dtype, masked, grad):
     exported = jax.export.export(
         jax.jit(_attn_fn(masked, grad)), platforms=["tpu"])(
-            *_avals(t, dh, dtype))
+            *_avals(t, dk, dv, dtype))
     assert "tpu_custom_call" in exported.mlir_module()
 
 
@@ -103,7 +108,7 @@ def test_custom_block_sizes_lower_for_tpu():
                 interpret=False).astype(jnp.float32).sum())(q)
 
         exported = jax.export.export(jax.jit(f), platforms=["tpu"])(
-            *_avals(300, 128, jnp.bfloat16))
+            *_avals(300, 128, 128, jnp.bfloat16))
         assert "tpu_custom_call" in exported.mlir_module()
 
 
@@ -117,13 +122,13 @@ def _aot_compile_all() -> int:
         platform="tpu", topology_name="v5e:2x2")
     sharding = SingleDeviceSharding(topo.devices[0])
     failed = 0
-    for (t, dh, dtype), (masked, grad), causal in itertools.product(
-            SHAPES[:3], VARIANTS, (False, True)):
-        name = (f"T={t} dh={dh} {'masked' if masked else 'plain'} "
+    for (t, dk, dv, dtype), (masked, grad), causal in itertools.product(
+            AOT_SHAPES, VARIANTS, (False, True)):
+        name = (f"T={t} dk={dk} dv={dv} {'masked' if masked else 'plain'} "
                 f"{'grad' if grad else 'fwd'} causal={causal}")
         try:
             compiled = jax.jit(_attn_fn(masked, grad, causal)).lower(
-                *_avals(t, dh, dtype, sharding)).compile()
+                *_avals(t, dk, dv, dtype, sharding)).compile()
             assert "tpu_custom_call" in compiled.as_text()
             print(f"COMPILED {name}")
         except Exception as e:  # noqa: BLE001 — every variant reported
@@ -147,7 +152,8 @@ def test_attention_aot_compiles_for_v5e():
                        timeout=600)
     assert r.returncode == 0 and "REFUSED" not in r.stdout, (
         r.stdout[-4000:] + r.stderr[-2000:])
-    assert r.stdout.count("COMPILED") == 24, r.stdout[-4000:]
+    assert r.stdout.count("COMPILED") == 8 * len(AOT_SHAPES), (
+        r.stdout[-4000:])
 
 
 if __name__ == "__main__":

@@ -1,11 +1,12 @@
 """Time the flash-attention kernels alone on the chip, one call each.
 
     python3 tools/flash_kernel_times.py [--tree DIR]
-        [--blocks default,512x512,...] [--shapes 20x8192x256,32x8192x128]
-        [--out FILE]
+        [--blocks default,512x512,...]
+        [--shapes 20x8192x256,32x8192x128,32x8192x192/128] [--out FILE]
 
-For each shape (heads x T x head width, batch 1, bfloat16, causal: what
-the two sequence cells of the benchmark run) and each block size
+For each shape (heads x T x head width, or key width / value width
+where the two differ; batch 1, bfloat16, causal: what the three
+attention-bearing sequence cells of the benchmark run) and each block size
 (``default``: whatever the kernel file picks for the call), the
 host-clock time of the forward as serving calls it, the forward with
 ``lse`` as training calls it, the backward's dq call and its dk/dv call
@@ -29,7 +30,8 @@ def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--tree", default=os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    ap.add_argument("--shapes", default="20x8192x256,32x8192x128")
+    ap.add_argument("--shapes",
+                    default="20x8192x256,32x8192x128,32x8192x192/128")
     ap.add_argument("--blocks", default="default")
     ap.add_argument("--iters", type=int, default=10)
     ap.add_argument("--out", default=None)
@@ -65,17 +67,21 @@ def main() -> int:
 
     lines = []
     for shape in args.shapes.split(","):
-        h, t, dh = (int(x) for x in shape.split("x"))
+        h, t, widths = shape.split("x")
+        h, t = int(h), int(t)
+        dh, dv = (int(x) for x in (widths.split("/") * 2)[:2])
         rng = np.random.default_rng(h * 1000 + dh)
-        q, k, v, do = (jnp.asarray(rng.normal(size=(1, h, t, dh)),
-                                   jnp.bfloat16) for _ in range(4))
+        q, k, v, do = (jnp.asarray(rng.normal(size=(1, h, t, width)),
+                                   jnp.bfloat16)
+                       for width in (dh, dh, dv, dv))
         for blocks in args.blocks.split(","):
             kw = {"causal": True}
             if blocks != "default":
                 kw["block_q"], kw["block_k"] = (
                     int(x) for x in blocks.split("x"))
             line = {"tree": args.tree, "device": dev.device_kind,
-                    "heads": h, "t": t, "dh": dh, "blocks": blocks}
+                    "heads": h, "t": t, "dh": dh, "dv": dv,
+                    "blocks": blocks}
             try:
                 fwd = jax.jit(lambda q, k, v: fa.flash_attention_fwd_pallas(
                     q, k, v, **kw))
