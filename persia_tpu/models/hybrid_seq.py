@@ -85,7 +85,12 @@ product, whose grid visits only the tiles of rows in use.
 Recomputation: each layer is wrapped in ``nn.remat``, so the backward
 pass holds one (batch, T, hidden) input a layer, (batch, T, streams,
 hidden) over several streams, and rebuilds a layer's internals when it
-reaches it (one forward more a step).
+reaches it (one forward more a step). An attention layer also keeps the
+flash kernel's ``out`` and ``lse`` (``flash_attention.RESIDUAL_NAMES``,
+the remat policy here): (batch, heads, T, value width) more in the
+compute dtype and a float32 row a head, for which the rebuilt layer runs
+its projections and rotations again but not the kernel, whose forward
+call is a quarter of the layer's attention time.
 
 ``init`` declares every parameter and runs no mixer: a trainer that
 initialises eagerly (``make_device_mode_trainer``) would otherwise
@@ -844,7 +849,12 @@ class HybridSequenceTower(nn.Module):
         elif "*" in self.pattern:
             widths = (self.attn_head_dim, self.attn_head_dim)
         hyper = self.residual_streams > 1
+        # attention layers, the prediction module's among them: each
+        # keeps its kernel's out and lse across nn.remat
+        kept = sum(kind in "*L" for kind in
+                   self.pattern + self.pattern[-2:] * self.mtp_depth)
         return {"tower_layers": self.pattern,
+                "attention_residuals_kept": kept,
                 "experts_held": tuple(self.experts_held),
                 "experts_routed": self.experts_routed,
                 "expert_matrices":
@@ -901,7 +911,10 @@ class HybridSequenceTower(nn.Module):
         out_scale = 1.0 / len(self.pattern)
         layer = _Layer if streams == 1 else _HyperLayer
         if not self.is_initializing():
-            layer = nn.remat(layer)
+            from persia_tpu.ops.flash_attention import RESIDUAL_NAMES
+            layer = nn.remat(
+                layer, policy=jax.checkpoint_policies.save_only_these_names(
+                    *RESIDUAL_NAMES))
         hyper = () if streams == 1 else (
             streams, self.sinkhorn_iters, self.hyper_eps,
             tuple(self.hyper_clamp))

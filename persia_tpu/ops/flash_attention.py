@@ -48,8 +48,19 @@ in VMEM from those residuals with the same schedule run twice — dq
 accumulates along a row of pairs, dk/dv along a column. delta
 (rowsum(dO·O)) is a cheap XLA reduce. Memory stays O(T) end to end.
 
+Of the five residuals two carry a name, ``RESIDUAL_NAMES``: ``out`` and
+``lse``, the two that only the forward kernel can make (q, k and v come
+back from their projections; these come back from the one expensive
+call). Outside a ``jax.checkpoint`` a name is the identity. Inside one
+whose policy is ``save_only_these_names(*RESIDUAL_NAMES)`` (the
+sequence tower's ``nn.remat``) both are kept from the forward pass, the
+recomputation's forward call has no reader left and is gone from the
+step: three calls an attention layer a step (forward with ``lse``, dq,
+dk/dv), not four. The named ``out`` is the primal result too, so what
+reads the layer's output in the recomputation reads the kept array.
+
 The carry-bandwidth figures above are computed from shapes. Kernel
-times and roofline shares on the chip are in PERF.md (§5, §6 "PR 34").
+times and roofline shares on the chip: PERF.md §5, §6 "PR 34", "PR 36".
 """
 
 import functools
@@ -57,6 +68,7 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -69,6 +81,10 @@ _LANES = 128
 EDGE = 1    # some score of the block is masked: build the positional mask
 FIRST = 2   # first pair of its accumulation row: zero the accumulators
 LAST = 4    # last pair of its accumulation row: write the output block
+
+# the names of the two residuals only the forward kernel can make, for a
+# ``jax.checkpoint`` policy to keep (``save_only_these_names``)
+RESIDUAL_NAMES = ("flash_attention_out", "flash_attention_lse")
 
 
 def block_schedule(t_q: int, t_k: int, block_q: int, block_k: int,
@@ -539,10 +555,17 @@ def flash_attention(q, k, v, causal: bool = False, block_q=None,
                                       interpret=interpret, scale=scale)
 
 
+def _named_residuals(out, lse):
+    """``out`` and ``lse`` under ``RESIDUAL_NAMES``: the identity, but
+    for a ``jax.checkpoint`` policy that keeps those names."""
+    return tuple(checkpoint_name(x, name)
+                 for x, name in zip((out, lse), RESIDUAL_NAMES))
+
+
 def _fa_fwd(q, k, v, causal, block_q, block_k, interpret, scale):
-    out, lse = flash_attention_fwd_pallas(
+    out, lse = _named_residuals(*flash_attention_fwd_pallas(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, return_lse=True, scale=scale)
+        interpret=interpret, return_lse=True, scale=scale))
     return out, (q, k, v, out, lse)
 
 
@@ -565,9 +588,9 @@ def _flash_attention_masked(q, k, v, maskf, causal, block_q, block_k,
 
 
 def _fam_fwd(q, k, v, maskf, causal, block_q, block_k, interpret, scale):
-    out, lse = flash_attention_fwd_pallas(
+    out, lse = _named_residuals(*flash_attention_fwd_pallas(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, return_lse=True, kv_mask=maskf, scale=scale)
+        interpret=interpret, return_lse=True, kv_mask=maskf, scale=scale))
     return out, (q, k, v, out, lse, maskf)
 
 

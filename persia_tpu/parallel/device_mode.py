@@ -29,8 +29,10 @@ and gauges ``device_mode_<name>`` (an int as it is, a sequence by its
 length). The hybrid sequence tower gives ``tower_layers`` (its pattern),
 ``experts_held``, ``experts_routed``, ``expert_matrices`` (2 for
 square-relu experts, 3 for silu-gated ones), ``mtp_depth``,
-``residual_streams``, ``sinkhorn_iters`` (0 at one stream) and, where
-its pattern has attention, ``key_width`` and ``value_width``.
+``residual_streams``, ``sinkhorn_iters`` (0 at one stream),
+``attention_residuals_kept`` (the attention layers whose kernel's
+``out`` and ``lse`` its ``nn.remat`` policy keeps) and, where its
+pattern has attention, ``key_width`` and ``value_width``.
 """
 
 from typing import Any, Callable, Dict, Sequence, Tuple

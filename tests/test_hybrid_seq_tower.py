@@ -386,10 +386,12 @@ def test_the_build_is_tagged_and_the_step_carries_its_scopes(built):
     assert tags["experts_held"] == (0, 1, 2, 3)
     assert tags["experts_routed"] == 16
     assert tags["dense_update_tables"] == 1      # Adam: the dense step
+    assert tags["attention_residuals_kept"] == 1     # the one `*` layer
     gauges = metrics.default_registry()
     for name, value in (("tower_layers", 4), ("experts_held", 4),
                         ("experts_routed", 16), ("row_update_tables", 0),
-                        ("dense_update_tables", 1)):
+                        ("dense_update_tables", 1),
+                        ("attention_residuals_kept", 1)):
         assert gauges.gauge(f"device_mode_{name}").value == value
     ids, label = _feed(*_batches(1)[0])
     with built["mesh"]:
@@ -450,7 +452,8 @@ def test_the_configuration_states_the_parameters_it_runs():
                                  "experts_routed": 128, "expert_matrices": 2,
                                  "mtp_depth": 0, "residual_streams": 1,
                                  "sinkhorn_iters": 0, "key_width": 128,
-                                 "value_width": 128}
+                                 "value_width": 128,
+                                 "attention_residuals_kept": 1}
 
 
 def test_kernels_roofline_is_the_algorithm_s_need_at_the_rows_routed():

@@ -403,10 +403,12 @@ def test_the_build_is_tagged_and_the_step_carries_its_scopes(built):
     assert tags["residual_streams"] == 4 and tags["sinkhorn_iters"] == 20
     assert tags["key_width"] == 24 and tags["value_width"] == 16
     assert tags["mtp_depth"] == 0 and tags["expert_matrices"] == 3
+    assert tags["attention_residuals_kept"] == 2     # the two `L` layers
     gauges = metrics.default_registry()
     for name, value in (("residual_streams", 4), ("sinkhorn_iters", 20),
                         ("key_width", 24), ("value_width", 16),
-                        ("tower_layers", 4), ("experts_held", 4)):
+                        ("tower_layers", 4), ("experts_held", 4),
+                        ("attention_residuals_kept", 2)):
         assert gauges.gauge(f"device_mode_{name}").value == value
     ids, label = _feed(*_batches(1)[0])
     with built["mesh"]:
@@ -432,14 +434,22 @@ def test_the_build_is_tagged_and_the_step_carries_its_scopes(built):
 # --- one stream is the tower it was -----------------------------------------
 
 # the digest of the train step's lowered text at the accepted sequence
-# cells' rehearsal sizes, recorded on the parent of PR 35 (interpreted
-# Pallas bodies and all): a tower of one stream lowers to the program it
-# lowered to before the tower knew of streams, unequal widths or rotary
-# rules. A later change that alters the one-stream program on purpose
-# records its own digests here.
+# cells' rehearsal sizes (interpreted Pallas bodies and all): a tower of
+# one stream lowers to the program it lowered to before the tower knew of
+# streams, unequal widths or rotary rules. A later change that alters the
+# one-stream program on purpose records its own digests here. PR 36 did:
+# `nn.remat` keeps the flash kernel's `out` and `lse`, so the recomputed
+# layer's forward call is gone. Against the text recorded on the parent
+# of PR 35 (`3b6b259c3d9e5c26`, `91aefdebbde77ab6`), value names made
+# alike, that is all that differs: 526 lines fewer and 5 new in the first
+# (one attention layer), 1578 fewer and 15 new in the second (three): gone
+# the interpreted forward kernel with its padding and reshapes, and the
+# layer's old barrier; new `lse`'s slice and two reshapes moved to the
+# forward pass, the `reduce_precision` to bfloat16 that JAX puts on a kept
+# `out`, and the barrier with the two kept arrays among its operands.
 ONE_STREAM = {
-    "nemotron-3-nano-30b-a3b.device-histories8k": "3b6b259c3d9e5c26",
-    "glm-4.7-flash.device-histories8k": "91aefdebbde77ab6",
+    "nemotron-3-nano-30b-a3b.device-histories8k": "a543cfedb432b0b5",
+    "glm-4.7-flash.device-histories8k": "453a82043b12ce2c",
 }
 
 
@@ -485,7 +495,7 @@ def test_the_configuration_states_the_parameters_it_runs():
         "tower_layers": "LDLELELELE", "experts_held": tuple(range(8)),
         "experts_routed": 64, "expert_matrices": 3, "mtp_depth": 0,
         "residual_streams": 4, "sinkhorn_iters": 20, "key_width": 192,
-        "value_width": 128}
+        "value_width": 128, "attention_residuals_kept": 5}
     assert tower.rope_scaling == hybrid_seq.YarnRule(64, 4096, 32, 1, 1, 1)
     model = placement.build_model(sz)
     shapes = jax.eval_shape(

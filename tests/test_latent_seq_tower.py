@@ -390,10 +390,12 @@ def test_the_build_is_tagged_and_the_step_carries_its_scopes(built):
     assert tags["expert_matrices"] == 3         # gate, up, down
     assert tags["mtp_depth"] == 1
     assert tags["dense_update_tables"] == 1      # Adam: the dense step
+    assert tags["attention_residuals_kept"] == 3     # two `L` and the module's
     gauges = metrics.default_registry()
     for name, value in (("tower_layers", 4), ("experts_held", 4),
                         ("experts_routed", 16), ("expert_matrices", 3),
-                        ("mtp_depth", 1), ("dense_update_tables", 1)):
+                        ("mtp_depth", 1), ("dense_update_tables", 1),
+                        ("attention_residuals_kept", 3)):
         assert gauges.gauge(f"device_mode_{name}").value == value
     ids, label = _feed(*_batches(1)[0])
     with built["mesh"]:
@@ -453,7 +455,8 @@ def test_the_configuration_states_the_parameters_it_runs():
                                  "experts_routed": 64, "expert_matrices": 3,
                                  "mtp_depth": 1, "residual_streams": 1,
                                  "sinkhorn_iters": 0, "key_width": 256,
-                                 "value_width": 256}
+                                 "value_width": 256,
+                                 "attention_residuals_kept": 6}
     model = placement.build_model(sz)
     shapes = jax.eval_shape(
         lambda: model.init(jax.random.key(0), [],
