@@ -88,34 +88,27 @@ def require(ok, msg):
 # --- compile accounting ---------------------------------------------------
 
 class CompileMeter:
-    """Sums JAX's own backend-compile durations (persistent-cache loads
-    included) and counts persistent-cache hits, per phase."""
+    """The program's own compile watch (``tracing.compile_watch()``:
+    JAX's backend-compile durations, persistent-cache loads included,
+    and its persistent-cache hits), read per phase."""
 
     def __init__(self):
-        from jax import monitoring
+        from persia_tpu import tracing
 
-        self.seconds = 0.0
-        self.compiles = 0
-        self.cache_hits = 0
-        monitoring.register_event_duration_secs_listener(self._on_duration)
-        monitoring.register_event_listener(self._on_event)
+        self._watch = tracing.compile_watch()
 
-    def _on_duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-            self.compiles += 1
-
-    def _on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
+    seconds = property(lambda self: self._watch.seconds)
+    cache_hits = property(lambda self: self._watch.cache_hits)
 
     def mark(self):
-        return self.seconds, self.compiles, self.cache_hits
+        w = self._watch
+        return w.seconds, w.compiles, w.cache_hits
 
     def since(self, mark):
-        return {"compile_s": round(self.seconds - mark[0], 2),
-                "compiles": self.compiles - mark[1],
-                "cache_hits": self.cache_hits - mark[2]}
+        now = self.mark()
+        return {"compile_s": round(now[0] - mark[0], 2),
+                "compiles": now[1] - mark[1],
+                "cache_hits": now[2] - mark[2]}
 
 
 def device_memory(jax):

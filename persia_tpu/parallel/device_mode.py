@@ -33,9 +33,21 @@ square-relu experts, 3 for silu-gated ones), ``mtp_depth``,
 ``attention_residuals_kept`` (the attention layers whose kernel's
 ``out`` and ``lse`` its ``nn.remat`` policy keeps) and, where its
 pattern has attention, ``key_width`` and ``value_width``.
+
+The step keeps its own account (:class:`DeviceStep`, which
+``make_device_mode_trainer`` returns in the jitted function's place): a
+``trainer/dispatch`` span a call with the device buffers that go in and
+come out, gauges for the build and the first call
+(``device_mode_init_seconds``, ``device_mode_first_call_seconds``,
+``device_mode_first_call_compile_seconds``), a counter of the calls
+after the first that compiled (``device_mode_step_recompiles_total``),
+the operator's profiler window (``PERSIA_PROFILE_DIR``) and, on
+request, the scope of every instruction of its compiled program
+(:meth:`DeviceStep.scopes`).
 """
 
-from typing import Any, Callable, Dict, Sequence, Tuple
+import time
+from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +58,7 @@ from flax.core import meta
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from persia_tpu import metrics, tracing
+from persia_tpu.logger import get_default_logger
 from persia_tpu.parallel.device_embedding import (
     DeviceEmbeddingCollection,
     distinct_rows,
@@ -55,6 +68,8 @@ from persia_tpu.parallel.device_embedding import (
 )
 from persia_tpu.parallel.mesh import replicated
 from persia_tpu.parallel.train import bce_loss
+
+_logger = get_default_logger(__name__)
 
 # the collection's place in DeviceModeModel's parameter tree
 TABLES = "DeviceEmbeddingCollection_0"
@@ -90,6 +105,152 @@ class DeviceModeModel(nn.Module):
         return {TABLES: table_rows(self.slot_specs, id_tensors)}
 
 
+def _abstract(x):
+    """Shape, dtype and sharding of one argument, and nothing of its
+    value (a donated array's are still there after the call)."""
+    aval = jax.typeof(x)
+    return jax.ShapeDtypeStruct(aval.shape, aval.dtype,
+                                sharding=getattr(x, "sharding", None),
+                                weak_type=aval.weak_type)
+
+
+def _changed(a, b) -> Optional[str]:
+    """``float32[16,13] -> float32[32,13]`` where two abstract arguments
+    differ in shape, dtype or (beyond equivalence on their devices)
+    sharding; None where they do not."""
+    def brief(x, placed):
+        at = f"@{x.sharding}" if placed else ""
+        return f"{x.dtype}[{','.join(map(str, x.shape))}]{at}"
+
+    moved = (a.sharding is not None and b.sharding is not None
+             and a.shape == b.shape
+             and not a.sharding.is_equivalent_to(b.sharding, len(a.shape)))
+    if (a.shape, a.dtype, a.weak_type) == (b.shape, b.dtype, b.weak_type) \
+            and not moved:
+        return None
+    return f"{brief(a, moved)} -> {brief(b, moved)}"
+
+
+class DeviceStep:
+    """The jitted device-mode step and its account. Called as the jitted
+    function is, ``step(params, opt_state, non_id, ids, label) ->
+    (params, opt_state, loss)``; ``lower`` and every other attribute are
+    the jitted function's own.
+
+    - Each call runs under ``tracing.span("trainer/dispatch")``. While
+      the span records (``PERSIA_TRACING`` or a live profiler session)
+      it is tagged ``args`` and ``results``, the device buffers that go
+      into and come out of the call (leaves, counted at the first call),
+      and ``compiled``. With both switches off the call pays one frame,
+      the shared null span and three reads of the compile watch.
+    - The first call sets ``device_mode_first_call_seconds`` (trace,
+      lower, compile or cache load, dispatch; the device's run is not
+      waited for) and ``device_mode_first_call_compile_seconds``. A
+      later call during which the process compiled bumps
+      ``device_mode_step_recompiles_total`` and logs the arguments that
+      differ from the first call's.
+    - ``profiler`` (``tracing.profiler_from_env()`` in
+      ``make_device_mode_trainer``) gets ``on_step(i)`` before call
+      ``i``; :meth:`close` stops a window that is still open.
+    """
+
+    def __init__(self, jitted, mesh: Mesh,
+                 profiler: Optional[tracing.StepProfiler] = None):
+        self._jitted = jitted
+        self._mesh = mesh
+        self._watch = tracing.compile_watch()
+        self._profiler = profiler
+        if profiler is not None and profiler.scopes is None:
+            profiler.scopes = self.scopes
+        self._calls = 0
+        self._avals = None      # the first call's arguments, abstract
+        self._buffers = None    # leaves (in, out) of the first call
+        self._scopes = None
+        self._recompiles = metrics.default_registry().counter(
+            "device_mode_step_recompiles_total",
+            help_text="calls of the device-mode step after the first "
+                      "during which the process compiled")
+
+    def __getattr__(self, name):
+        # only what this class lacks comes here: the jitted function's
+        if name == "_jitted":
+            raise AttributeError(name)
+        return getattr(self._jitted, name)
+
+    def __call__(self, params, opt_state, non_id, ids, label):
+        args = (params, opt_state, non_id, ids, label)
+        if self._profiler is not None:
+            self._profiler.on_step(self._calls)
+        first = self._avals is None
+        if first:
+            self._avals = jax.tree_util.tree_map(_abstract, args)
+            t0 = time.perf_counter()
+        watch = self._watch
+        compiles, seconds = watch.compiles, watch.seconds
+        with tracing.span("trainer/dispatch") as sp:
+            out = self._jitted(*args)
+            compiled = watch.compiles != compiles
+            if first:
+                self._buffers = (len(jax.tree_util.tree_leaves(args)),
+                                 len(jax.tree_util.tree_leaves(out)))
+            elif compiled:
+                self._recompiled(args)
+            if sp.ctx is not None:
+                sp.tag(args=self._buffers[0], results=self._buffers[1],
+                       compiled=compiled)
+        if first:
+            reg = metrics.default_registry()
+            reg.gauge("device_mode_first_call_seconds").set(
+                time.perf_counter() - t0)
+            reg.gauge("device_mode_first_call_compile_seconds").set(
+                watch.seconds - seconds)
+        self._calls += 1
+        return out
+
+    def _recompiled(self, args):
+        self._recompiles.inc()
+        was = jax.tree_util.tree_flatten_with_path(self._avals)[0]
+        now = jax.tree_util.tree_leaves(
+            jax.tree_util.tree_map(_abstract, args))
+        if len(was) != len(now):
+            differ = [f"{len(was)} -> {len(now)} leaves"]
+        else:
+            changes = ((path, _changed(a, b))
+                       for (path, a), b in zip(was, now))
+            differ = [f"{jax.tree_util.keystr(path)}: {change}"
+                      for path, change in changes if change]
+        if len(differ) > 8:
+            differ[8:] = [f"and {len(differ) - 8} more"]
+        _logger.warning(
+            "device-mode step compiled again at call %d: %s", self._calls,
+            "; ".join(differ) or "no argument differs from the first "
+            "call's in shape, dtype or sharding (another thread's "
+            "compilation, or a weak type)")
+
+    def scopes(self) -> Dict[str, Tuple[str, bool]]:
+        """``tracing.scope_table`` of this step's compiled program:
+        ``{instruction name: (scope path, backward)}``, for
+        ``tracing.device_time_by_scope`` to group a trace's device events
+        by. Lowered from the first call's abstract arguments (shapes,
+        dtypes, shardings; no array is held) and compiled, which with a
+        persistent compile cache is a load; kept after the first request.
+        Never on the step's path, and not free: it loads a second copy of
+        the executable (hundreds of MB of code for a large tower), so ask
+        once the state is freed, or catch the failure."""
+        if self._scopes is None:
+            if self._avals is None:
+                raise RuntimeError("the step has not been called yet")
+            with self._mesh:
+                compiled = self._jitted.lower(*self._avals).compile()
+            self._scopes = tracing.scope_table(compiled.as_text())
+        return self._scopes
+
+    def close(self):
+        """Stops the profiler's window if it is still open."""
+        if self._profiler is not None:
+            self._profiler.close()
+
+
 def make_device_mode_trainer(
     model: nn.Module,
     optimizer: optax.GradientTransformation,
@@ -101,7 +262,8 @@ def make_device_mode_trainer(
 ) -> Tuple[Any, Any, Callable]:
     """Initialize sharded params + opt state and build the jitted step.
 
-    Returns (params, opt_state, step) where
+    Returns (params, opt_state, step) where ``step`` is a
+    :class:`DeviceStep` over the jitted
     ``step(params, opt_state, non_id, ids, label) ->
     (params, opt_state, loss)``. Parameter shardings come from the
     modules' ``with_partitioning`` metadata; everything else replicates.
@@ -109,8 +271,11 @@ def make_device_mode_trainer(
     The step's operations carry the scopes ``tables_gather``, ``tower``
     and ``optimizer`` in their metadata, for a trace to group them by;
     the touched-rows step's table work inside ``optimizer`` carries
-    ``row_update`` besides.
+    ``row_update`` besides. The gauge ``device_mode_init_seconds`` is
+    the time from entry to the ``trainer/build_device_step`` span:
+    ``model.init``, the parameters' placement, ``optimizer.init``.
     """
+    t0 = time.perf_counter()
     with mesh:
         variables = model.init(jax.random.key(seed), sample_non_id,
                                sample_ids, train=False)
@@ -133,6 +298,8 @@ def make_device_mode_trainer(
     opt_state = jax.tree_util.tree_map(
         lambda x: x if isinstance(x, jax.core.Tracer) or x.committed
         else jax.device_put(x, replicated(mesh)), optimizer.init(params))
+    metrics.default_registry().gauge("device_mode_init_seconds").set(
+        time.perf_counter() - t0)
 
     with tracing.span("trainer/build_device_step") as built:
         flat, treedef = jax.tree_util.tree_flatten_with_path(params)
@@ -155,6 +322,11 @@ def make_device_mode_trainer(
                        for name, v in described.items()})
         for name, n in counts.items():
             metrics.default_registry().gauge(f"device_mode_{name}").set(n)
+
+    def account(step):
+        return DeviceStep(jax.jit(step, donate_argnums=(0, 1)), mesh,
+                          tracing.profiler_from_env())
+
     if not by_row:
         def step(params, opt_state, non_id, ids, label):
             def compute_loss(params):
@@ -169,7 +341,7 @@ def make_device_mode_trainer(
                 params2 = optax.apply_updates(params, updates)
             return params2, opt_state2, loss
 
-        return params, opt_state, jax.jit(step, donate_argnums=(0, 1))
+        return params, opt_state, account(step)
 
     # where in the flat parameters the tables are, and the other leaves
     at_tables = [paths.index(path) for path, _ in tables]
@@ -213,7 +385,7 @@ def make_device_mode_trainer(
                 tree_of([False] * len(at_dense), touched))
         return params2, opt_state2, loss
 
-    return params, opt_state, jax.jit(step, donate_argnums=(0, 1))
+    return params, opt_state, account(step)
 
 
 def _distinct_and_summed(index, row_grads, row_counts):

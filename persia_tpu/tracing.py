@@ -33,9 +33,20 @@ the ring. That switch is local to the process: the RPC envelope follows
 :class:`StepProfiler` is the device-side companion: opt-in
 ``jax.profiler`` start/stop keyed to a trainer step window; the spans of
 those steps land in the same xplane as the device operations.
+
+The device's own account is kept here too, as plain functions over plain
+lists (no JAX at import): :func:`compile_watch` counts the process's
+backend compilations, :func:`scope_table` reads a compiled module's text
+for the ``jax.named_scope`` path of every instruction, and
+:func:`device_time_by_scope` sums a trace's device events by those
+paths, each event by its self time.
 """
 
+import bisect
+import json
 import os
+import re
+import statistics
 import struct
 import sys
 import threading
@@ -416,13 +427,21 @@ class StepProfiler:
     open capture (ctx exit / teardown). Environment wiring:
     ``PERSIA_PROFILE_DIR`` (enables), ``PERSIA_PROFILE_START_STEP``
     (default 10), ``PERSIA_PROFILE_NUM_STEPS`` (default 5) — see
-    :func:`profiler_from_env`."""
+    :func:`profiler_from_env`.
+
+    ``scopes``, if given, is a callable that returns the profiled step's
+    :func:`scope_table`. ``close()`` then reads the xplane it just wrote
+    and leaves ``device_scopes.json`` beside it: what
+    :func:`device_time_by_scope` makes of the first device's events,
+    with the ten longest scopes logged in ms a step. A failure there is
+    a warning, as the profiler's own are."""
 
     def __init__(self, logdir: str, start_step: int = 10,
-                 num_steps: int = 5):
+                 num_steps: int = 5, scopes=None):
         self.logdir = logdir
         self.start_step = int(start_step)
         self.num_steps = max(1, int(num_steps))
+        self.scopes = scopes
         self.active = False
         self._done = False
 
@@ -456,6 +475,30 @@ class StepProfiler:
             _logger.info("device profiler stopped -> %s", self.logdir)
         except Exception as e:
             _logger.warning("jax.profiler stop failed: %s", e)
+            return
+        if self.scopes is not None:
+            try:
+                self._report_scopes()
+            except Exception as e:
+                _logger.warning("no device time by scope from %s: %s",
+                                self.logdir, e)
+
+    def _report_scopes(self):
+        xplane = max((os.path.join(base, f)
+                      for base, _, files in os.walk(self.logdir)
+                      for f in files if f.endswith(".xplane.pb")),
+                     key=os.path.getmtime)
+        ops, modules = load_device_events(xplane)
+        report = device_time_by_scope(ops, modules, self.scopes())
+        out = os.path.join(os.path.dirname(xplane), "device_scopes.json")
+        with open(out, "w") as f:
+            json.dump(report, f)
+        steps = report["steps"] or 1.0
+        _logger.info(
+            "device time by scope over %.1f steps (ms a step) -> %s: %s",
+            report["steps"], out, ", ".join(
+                f"{path} {1e3 * (fwd + bwd) / steps:.2f}"
+                for path, fwd, bwd in report["scopes"][:10]))
 
 
 def profiler_from_env() -> Optional[StepProfiler]:
@@ -468,6 +511,251 @@ def profiler_from_env() -> Optional[StepProfiler]:
         start_step=knobs.get("PERSIA_PROFILE_START_STEP"),
         num_steps=knobs.get("PERSIA_PROFILE_NUM_STEPS"),
     )
+
+
+# --- the device's account: compilations, scopes, device time --------------
+
+
+class CompileWatch:
+    """The process's backend compilations as JAX's own monitoring events
+    report them (``/jax/core/compile/backend_compile_duration``, which a
+    persistent-cache load fires too, and
+    ``/jax/compilation_cache/cache_hits``). ``compiles``, ``seconds``
+    and ``cache_hits`` are plain attributes, so that a caller can compare
+    a count before and after a call for the price of two reads; the
+    registry counters ``jax_backend_compiles_total``,
+    ``jax_backend_compile_seconds_total`` and
+    ``jax_compile_cache_hits_total`` carry the same to ``/metrics``.
+    Process-wide: a compilation on another thread counts as well."""
+
+    def __init__(self):
+        from jax import monitoring
+
+        self.compiles = 0
+        self.seconds = 0.0
+        self.cache_hits = 0
+        reg = default_registry()
+        self._compiles = reg.counter(
+            "jax_backend_compiles_total",
+            help_text="backend compilations in this process, persistent-"
+                      "cache loads included")
+        self._seconds = reg.counter(
+            "jax_backend_compile_seconds_total",
+            help_text="seconds inside those backend compilations")
+        self._hits = reg.counter(
+            "jax_compile_cache_hits_total",
+            help_text="programs found in JAX's persistent compile cache")
+        monitoring.register_event_duration_secs_listener(self._on_duration)
+        monitoring.register_event_listener(self._on_event)
+
+    def _on_duration(self, event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.compiles += 1
+            self.seconds += duration
+            self._compiles.inc()
+            self._seconds.inc(duration)
+
+    def _on_event(self, event, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.cache_hits += 1
+            self._hits.inc()
+
+
+_watch: Optional[CompileWatch] = None
+_watch_lock = threading.Lock()
+
+
+def compile_watch() -> CompileWatch:
+    """The process's one :class:`CompileWatch`, registered at the first
+    call: whoever builds a jitted step asks for it
+    (``make_device_mode_trainer``). A process that builds none (a PS, a
+    worker) never calls this, imports no JAX and registers nothing."""
+    global _watch
+    with _watch_lock:
+        if _watch is None:
+            _watch = CompileWatch()
+        return _watch
+
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_WRAPPED = re.compile(r"^(\w+)\((.*)\)$")
+# transformations that JAX writes around a scope's name, and what it
+# calls the pieces of its own control flow: neither is a named_scope
+_TRANSFORMS = frozenset(("jvp", "transpose", "vmap"))
+_STRUCTURAL = frozenset((
+    "while", "body", "cond", "closed_call", "checkpoint", "remat",
+    "rematted_computation", "custom_jvp_call", "custom_vjp_call"))
+
+
+def _scope_path(op_name: str) -> Tuple[str, bool]:
+    backward = "transpose(" in op_name
+    names: List[str] = []
+    # a fused instruction may list several paths: the first; and the
+    # last component is the primitive (or a jitted callee's name)
+    for part in op_name.split(";")[0].split("/")[:-1]:
+        wrapped = False
+        while True:
+            m = _WRAPPED.match(part)
+            if m is None or m.group(1) not in _TRANSFORMS:
+                break
+            part, wrapped = m.group(2), True
+        # what is left in brackets is a call (``jit(step)``, ``jit(_take)``)
+        if not part or "(" in part or part in _STRUCTURAL:
+            continue
+        if wrapped and names and part == names[0]:
+            # the root again under a transformation: ``jax.checkpoint``'s
+            # body brings the whole stack it was traced under
+            names = []
+        names.append(part)
+    return "/".join(names), backward
+
+
+def scope_table(hlo_text: str) -> Dict[str, Tuple[str, bool]]:
+    """``{instruction name: (scope path, backward)}`` over a compiled
+    module's text (``compiled.as_text()``), every computation of it:
+    instruction names are unique in a module, and a device event's name
+    begins with its instruction's.
+
+    The path is the instruction's ``op_name`` with JAX's own wrapping
+    taken off (``jit(...)`` calls, ``jvp(...)``/``transpose(...)``
+    around a name, ``checkpoint``, the ``while``/``body``/``cond`` of a
+    loop) and the trailing primitive dropped, so that what is left is
+    the program's ``jax.named_scope`` names and its modules' names,
+    outermost first: ``tower/layer_3/experts/experts_grouped``.
+    ``backward`` says whether ``transpose(`` stood in the path, which it
+    does for the forward pass that ``jax.checkpoint`` runs again inside
+    the backward one too. An instruction without metadata (a layout copy
+    the compiler made) maps to ``("", False)``."""
+    table = {}
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            continue
+        op = _OP_NAME.search(line)
+        table[m.group(1)] = _scope_path(op.group(1)) if op else ("", False)
+    return table
+
+
+def _instruction_of(event_name: str) -> str:
+    m = _INSTRUCTION.match(event_name)
+    return m.group(1) if m else event_name.strip().lstrip("%")
+
+
+def _self_times(ops) -> List[float]:
+    """Each event's self time in ns, in the order given: its duration
+    less the time in which an event that started later runs on the same
+    line. For events that nest, as a ``while`` and the operations of its
+    body do, that is the duration less the union of what lies inside, so
+    the wrapper keeps its own overhead and the body counts once; at
+    every moment exactly one running event is charged, so the self times
+    sum to the union of the busy intervals."""
+    order = sorted(range(len(ops)), key=lambda i: (ops[i][1], -ops[i][2]))
+    own = [0.0] * len(ops)
+    stack: List[Tuple[int, float]] = []   # (index, end), by start
+
+    def charge_until(t, cursor):
+        while stack:
+            i, end = stack[-1]
+            if end <= cursor:
+                stack.pop()
+                continue
+            if cursor >= t:
+                break
+            upto = min(end, t)
+            own[i] += upto - cursor
+            cursor = upto
+        return max(cursor, t)
+
+    cursor = float("-inf")
+    for i in order:
+        _, start, dur = ops[i]
+        cursor = charge_until(start, cursor)
+        stack.append((i, start + dur))
+    charge_until(float("inf"), cursor)
+    return own
+
+
+def device_time_by_scope(ops, modules, table, depth=None) -> Dict:
+    """Device time by the program's scopes. ``ops`` and ``modules`` are
+    one device's events as plain lists ``[[name, start_ns, dur_ns]]``
+    (its ``XLA Ops`` and ``XLA Modules`` lines: :func:`load_device_events`),
+    ``table`` a :func:`scope_table`. Each event's self time goes to the
+    scope of the instruction its name begins with, cut to the path's
+    innermost ``depth`` names (default 1; 0 keeps the whole path). The
+    table is that of the module that takes most of the time: an event
+    that starts outside its runs belongs to another program (instruction
+    names repeat from program to program) and is counted under that
+    program's name among the unmatched.
+
+    Returns, in seconds: ``steps`` (runs of that module, a cut one
+    counted as the fraction it is), ``total_s`` (the sum of all self
+    times, which is the union of the device's busy intervals),
+    ``unscoped_s`` (instructions without a scope), ``unmatched_s`` and
+    ``unmatched`` ``[[instruction or program, s]]`` (events the table
+    lacks: counted, never dropped), and ``scopes`` ``[[path, forward_s,
+    backward_s]]``, longest first; the scopes, ``unscoped_s`` and
+    ``unmatched_s`` add up to ``total_s``."""
+    depth = 1 if depth is None else depth
+    runs: Dict[str, List[float]] = {}
+    for name, _, dur in modules:
+        runs.setdefault(name, []).append(dur)
+    main = max(runs, key=lambda name: sum(runs[name])) if runs else None
+    steps = (sum(runs[main]) / statistics.median(runs[main])
+             if main is not None else 0.0)
+    others = sorted((start, start + dur, name)
+                    for name, start, dur in modules if name != main)
+    begins = [o[0] for o in others]
+
+    def other_program(start):
+        i = bisect.bisect_right(begins, start) - 1
+        return others[i][2] if i >= 0 and start < others[i][1] else None
+
+    by_scope: Dict[str, List[float]] = {}
+    unmatched: Dict[str, float] = {}
+    unscoped = total = 0.0
+    for (name, start, _), own in zip(ops, _self_times(ops)):
+        total += own
+        instruction = other_program(start) or _instruction_of(name)
+        found = table.get(instruction)
+        if found is None:
+            unmatched[instruction] = unmatched.get(instruction, 0.0) + own
+            continue
+        path, backward = found
+        if not path:
+            unscoped += own
+            continue
+        if depth:
+            path = "/".join(path.split("/")[-depth:])
+        by_scope.setdefault(path, [0.0, 0.0])[backward] += own
+    return {
+        "steps": steps,
+        "total_s": total / 1e9,
+        "unscoped_s": unscoped / 1e9,
+        "unmatched_s": sum(unmatched.values()) / 1e9,
+        "unmatched": sorted(([k, v / 1e9] for k, v in unmatched.items()),
+                            key=lambda kv: -kv[1]),
+        "scopes": sorted(([k, f / 1e9, b / 1e9]
+                          for k, (f, b) in by_scope.items()),
+                         key=lambda x: -(x[1] + x[2])),
+    }
+
+
+def load_device_events(xplane_path: str):
+    """``(ops, modules)``: the ``XLA Ops`` and ``XLA Modules`` lines of
+    a profiler trace's first ``/device:TPU:`` plane, as
+    ``[[name, start_ns, dur_ns]]``. Raises ``LookupError`` where the
+    trace has no such plane (a session on the CPU)."""
+    from jax.profiler import ProfileData
+
+    for p in ProfileData.from_file(xplane_path).planes:
+        if p.name.startswith("/device:TPU:"):
+            lines = {line.name: [[e.name, float(e.start_ns),
+                                  float(e.duration_ns)] for e in line.events]
+                     for line in p.lines
+                     if line.name in ("XLA Ops", "XLA Modules")}
+            return lines.get("XLA Ops", []), lines.get("XLA Modules", [])
+    raise LookupError(f"no /device:TPU: plane in {xplane_path}")
 
 
 # --- stall/deadlock detection (pre-existing surface) ----------------------
