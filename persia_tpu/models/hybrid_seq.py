@@ -83,7 +83,7 @@ twice). One body at one size is all the step holds of the grouped
 product, whose grid visits only the tiles of rows in use.
 
 Recomputation: each layer is wrapped in ``nn.remat``, so the backward
-pass holds one (batch, T, hidden) input a layer, (batch, T, streams,
+pass holds one (batch, T, hidden) input a layer, (batch, T, streams *
 hidden) over several streams, and rebuilds a layer's internals when it
 reaches it (one forward more a step). An attention layer also keeps the
 flash kernel's ``out`` and ``lse`` (``flash_attention.RESIDUAL_NAMES``,
@@ -687,15 +687,22 @@ def _near_identity_init(streams):
 
 class _HyperLayer(nn.Module):
     """A sublayer over ``streams`` residual streams (manifold-constrained
-    hyper-connections): ``x`` (batch, T, streams, hidden) in the compute
-    dtype. From the position's flattened streams ``z``, in float32,
-    ``m = (z phi) rsqrt(mean(z^2) + hyper_eps)`` gives three maps, each
-    with its part of ``phi``, a bias ``b`` and a scale ``a``: ``pre =
-    sigmoid(a m + b)`` (n,) reads the mixer's input out of the streams,
-    ``u = sum_j pre[j] x[j]``; ``post = 2 sigmoid(a m + b)`` (n,) writes
-    its output back to each; and ``res = sinkhorn(clip(a m + b,
-    *clamp))`` (n, n row-major), doubly stochastic, mixes the streams:
-    ``x'[i] = sum_j res[i, j] x[j] + post[i] mixer(RMSNorm(u))``.
+    hyper-connections): ``x`` (batch, T, streams * hidden) in the compute
+    dtype, stream ``j`` the features ``[j hidden, (j + 1) hidden)`` of a
+    position, so that the position's flattened streams ``z`` are its row
+    as it lies (the shape ``ops/hyper_connection``'s kernels read). From
+    ``z``, in float32, ``m = (z phi) rsqrt(mean(z^2) + hyper_eps)``
+    gives three maps, each with its part of ``phi``, a bias ``b`` and a
+    scale ``a``: ``pre = sigmoid(a m + b)`` (n,) reads the mixer's input
+    out of the streams, ``u = sum_j pre[j] x[j]``; ``post = 2 sigmoid(a
+    m + b)`` (n,) writes its output back to each; and ``res =
+    sinkhorn(clip(a m + b, *clamp))`` (n, n row-major), doubly
+    stochastic, mixes the streams: ``x'[i] = sum_j res[i, j] x[j] +
+    post[i] mixer(RMSNorm(u))``. Every pass over the state is a kernel
+    of ``ops/hyper_connection`` (``read_out``: ``m``, ``pre`` and ``u``
+    from one read, ``u`` in float32 for the mixer's norm, which works in
+    float32; ``mix``: ``x'``; their backward passes), ``post`` and
+    ``res`` plain ``jnp`` on the few numbers a position has of them.
 
     A map's three parameters are leaves of their own
     (``hyper_<map>_phi``, ``_bias``, ``_scale``): over the identical
@@ -717,7 +724,7 @@ class _HyperLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x):
-        n, hidden, cd = self.streams, x.shape[-1], self.compute_dtype
+        n, hidden = self.streams, x.shape[-1] // self.streams
 
         def leaves(name, width, bias_init=nn.initializers.zeros):
             return (self.param(f"hyper_{name}_phi", _kernel_init(),
@@ -729,33 +736,28 @@ class _HyperLayer(nn.Module):
 
         phi, bias, scale = zip(leaves("pre", n), leaves("post", n),
                                leaves("res", n * n, _near_identity_init(n)))
-        norm = RMSNorm(self.eps, cd, name="norm")
+        norm = RMSNorm(self.eps, self.compute_dtype, name="norm")
         if self.is_initializing():      # declares, runs no map or mixer
-            self.mixer(norm(x[..., 0, :]))
+            self.mixer(norm(x[..., :hidden]))
             return x
-        with jax.named_scope("hyper_connection"):
-            with jax.named_scope("hyper_maps"):
-                z = x.reshape(*x.shape[:-2], n * hidden).astype(F32)
-                m = (jnp.dot(z, jnp.concatenate(phi, axis=-1),
-                             precision=lax.Precision.HIGHEST)
-                     * lax.rsqrt(jnp.mean(z * z, axis=-1, keepdims=True)
-                                 + self.hyper_eps))
-                pre, post, res = (
-                    a * part + b for a, b, part in zip(
-                        scale, bias, jnp.split(m, [n, 2 * n], axis=-1)))
-                pre, post = jax.nn.sigmoid(pre), 2 * jax.nn.sigmoid(post)
-                res = sinkhorn(
-                    jnp.clip(res, *self.clamp).reshape(*m.shape[:-1], n, n),
-                    self.sinkhorn_iters, self.hyper_eps)
-            with jax.named_scope("hyper_mix"):
-                u = sum(pre[..., j, None] * x[..., j, :] for j in range(n))
+        from persia_tpu.ops import hyper_connection
+
+        with jax.named_scope("hyper_connection"), \
+                jax.named_scope("hyper_maps"):
+            u, m, carry = hyper_connection.read_out(
+                x, jnp.concatenate(phi, axis=-1), scale[0], bias[0],
+                streams=n, eps=self.hyper_eps)
+            post, res = (a * part + b for a, b, part in zip(
+                scale[1:], bias[1:], jnp.split(m, [n, 2 * n], axis=-1)[1:]))
+            post = 2 * jax.nn.sigmoid(post)
+            res = sinkhorn(
+                jnp.clip(res, *self.clamp).reshape(*m.shape[:-1], n, n),
+                self.sinkhorn_iters, self.hyper_eps)
         with jax.named_scope(self.scope_name):
-            y = self.mixer(norm(u.astype(cd)))
+            y = self.mixer(norm(u))
         with jax.named_scope("hyper_connection"), \
                 jax.named_scope("hyper_mix"):
-            mixed = sum(res[..., :, j, None] * x[..., None, j, :]
-                        for j in range(n))
-            return (mixed + post[..., None] * y[..., None, :]).astype(cd)
+            return hyper_connection.mix(carry, y, res, post)
 
 
 class NextPrediction(nn.Module):
@@ -855,6 +857,9 @@ class HybridSequenceTower(nn.Module):
                    self.pattern + self.pattern[-2:] * self.mtp_depth)
         return {"tower_layers": self.pattern,
                 "attention_residuals_kept": kept,
+                # the sublayers whose hyper-connection runs
+                # ops/hyper_connection's kernels: all, or none
+                "hyper_fused_sublayers": len(self.pattern) * hyper,
                 "experts_held": tuple(self.experts_held),
                 "experts_routed": self.experts_routed,
                 "expert_matrices":
@@ -925,13 +930,13 @@ class HybridSequenceTower(nn.Module):
 
         if streams > 1:
             with jax.named_scope("hyper_expand"):   # every stream the row
-                h = jnp.broadcast_to(h[:, :, None, :],
-                                     (*h.shape[:2], streams, h.shape[-1]))
+                h = jnp.concatenate([h] * streams, axis=-1)
         for i, kind in enumerate(self.pattern):
             h = layer_of(kind, name=f"layer_{i}")(h)
         if streams > 1:
             with jax.named_scope("hyper_contract"):
-                h = jnp.sum(h.astype(F32), axis=2).astype(self.compute_dtype)
+                h = sum(h[..., j * self.hidden:(j + 1) * self.hidden].astype(
+                    F32) for j in range(streams)).astype(self.compute_dtype)
         with jax.named_scope("item_head"):
             u = RMSNorm(self.eps, self.compute_dtype, name="final_norm")(h)
             w = self.param("item_head", _kernel_init(),

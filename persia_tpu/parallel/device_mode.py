@@ -31,7 +31,10 @@ length). The hybrid sequence tower gives ``tower_layers`` (its pattern),
 square-relu experts, 3 for silu-gated ones), ``mtp_depth``,
 ``residual_streams``, ``sinkhorn_iters`` (0 at one stream),
 ``attention_residuals_kept`` (the attention layers whose kernel's
-``out`` and ``lse`` its ``nn.remat`` policy keeps) and, where its
+``out`` and ``lse`` its ``nn.remat`` policy keeps),
+``hyper_fused_sublayers`` (the sublayers whose hyper-connection runs
+``ops/hyper_connection``'s kernels: all of them over several residual
+streams, 0 over one) and, where its
 pattern has attention, ``key_width`` and ``value_width``.
 
 The step keeps its own account (:class:`DeviceStep`, which

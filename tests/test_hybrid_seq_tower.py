@@ -453,7 +453,8 @@ def test_the_configuration_states_the_parameters_it_runs():
                                  "mtp_depth": 0, "residual_streams": 1,
                                  "sinkhorn_iters": 0, "key_width": 128,
                                  "value_width": 128,
-                                 "attention_residuals_kept": 1}
+                                 "attention_residuals_kept": 1,
+                                 "hyper_fused_sublayers": 0}
 
 
 def test_kernels_roofline_is_the_algorithm_s_need_at_the_rows_routed():

@@ -404,11 +404,14 @@ def test_the_build_is_tagged_and_the_step_carries_its_scopes(built):
     assert tags["key_width"] == 24 and tags["value_width"] == 16
     assert tags["mtp_depth"] == 0 and tags["expert_matrices"] == 3
     assert tags["attention_residuals_kept"] == 2     # the two `L` layers
+    # every sublayer's connection runs ops/hyper_connection's kernels
+    assert tags["hyper_fused_sublayers"] == 4
     gauges = metrics.default_registry()
     for name, value in (("residual_streams", 4), ("sinkhorn_iters", 20),
                         ("key_width", 24), ("value_width", 16),
                         ("tower_layers", 4), ("experts_held", 4),
-                        ("attention_residuals_kept", 2)):
+                        ("attention_residuals_kept", 2),
+                        ("hyper_fused_sublayers", 4)):
         assert gauges.gauge(f"device_mode_{name}").value == value
     ids, label = _feed(*_batches(1)[0])
     with built["mesh"]:
@@ -495,7 +498,8 @@ def test_the_configuration_states_the_parameters_it_runs():
         "tower_layers": "LDLELELELE", "experts_held": tuple(range(8)),
         "experts_routed": 64, "expert_matrices": 3, "mtp_depth": 0,
         "residual_streams": 4, "sinkhorn_iters": 20, "key_width": 192,
-        "value_width": 128, "attention_residuals_kept": 5}
+        "value_width": 128, "attention_residuals_kept": 5,
+        "hyper_fused_sublayers": 10}
     assert tower.rope_scaling == hybrid_seq.YarnRule(64, 4096, 32, 1, 1, 1)
     model = placement.build_model(sz)
     shapes = jax.eval_shape(

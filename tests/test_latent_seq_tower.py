@@ -456,7 +456,8 @@ def test_the_configuration_states_the_parameters_it_runs():
                                  "mtp_depth": 1, "residual_streams": 1,
                                  "sinkhorn_iters": 0, "key_width": 256,
                                  "value_width": 256,
-                                 "attention_residuals_kept": 6}
+                                 "attention_residuals_kept": 6,
+                                 "hyper_fused_sublayers": 0}
     model = placement.build_model(sz)
     shapes = jax.eval_shape(
         lambda: model.init(jax.random.key(0), [],
