@@ -1,8 +1,8 @@
-"""Hybrid sequence tower: state-space mixers, sparse experts beside a
-shared expert, grouped-query and latent attention and a gated dense
-feed-forward over a history of item ids, with an item head for next-item
-prediction and, optionally, one multi-token-prediction module for the
-item after next.
+"""Hybrid sequence tower: state-space and delta-rule mixers, sparse
+experts beside a shared expert, grouped-query and latent attention and a
+gated dense feed-forward over a history of item ids, with an item head
+for next-item prediction and, optionally, one multi-token-prediction
+module for the item after next.
 
 A generative recommender: the history is the sequence, the item table
 (a ``DeviceEmbeddingCollection`` slot with ``pooling="none"``) is the
@@ -35,11 +35,19 @@ transformer block, attention then a feed-forward, is two letters):
        layers those carry position.
 ``L``  causal multi-head latent attention (:class:`LatentAttention`):
        low-rank query and key-value projections with a norm on each
-       latent, and rotary position embedding (:func:`rotary`, plain or
-       under a :class:`YarnRule`) on a decoupled part of every query
-       head and on one key shared by all the heads; the same flash
-       kernel, queries and keys at the head's whole key width and
-       values at their own.
+       latent (or one direct query projection, ``latent_q_rank`` None),
+       and rotary position embedding (:func:`rotary`, plain or under a
+       :class:`YarnRule`) on a decoupled part of every query head and
+       on one key shared by all the heads (or none, ``latent_positions``
+       False: those features enter the scores as projected, and
+       position is the ``K`` layers' to carry); the same flash kernel,
+       queries and keys at the head's whole key width and values at
+       their own.
+``K``  Kimi Delta Attention (:class:`DeltaAttention`): query, key and
+       value projections each through a short causal convolution and
+       SiLU, queries and keys normed a head, a decay a key channel and
+       a step size a head from the input, the gated delta rule
+       (``ops.kda_scan``), a gated norm a head, an output projection.
 ``D``  a gated dense feed-forward (:class:`GatedFeedForward`).
 
 ``mtp_depth`` 1 adds a :class:`NextPrediction` module after the last
@@ -108,6 +116,7 @@ import numpy as np
 from flax import linen as nn
 from jax import lax
 
+from persia_tpu.ops.kda_scan import kda_gate, kda_scan
 from persia_tpu.ops.ssm_scan import ssm_scan
 
 F32 = jnp.float32
@@ -151,6 +160,15 @@ def _dt_bias_init(dt_min, dt_max, dt_floor):
     return init
 
 
+def _causal_conv(x, taps):
+    """Depthwise over (batch, T, channels), float32: tap ``j`` of
+    ``taps`` (kernel, channels) reads position ``t - (kernel - 1) + j``,
+    zeros before the history's start."""
+    kernel, t = taps.shape[0], x.shape[1]
+    x = jnp.pad(x.astype(F32), ((0, 0), (kernel - 1, 0), (0, 0)))
+    return sum(taps[j] * x[:, j:j + t] for j in range(kernel))
+
+
 class SSMMixer(nn.Module):
     """Mamba-2 mixer over (batch, T, hidden)."""
 
@@ -189,12 +207,7 @@ class SSMMixer(nn.Module):
 
         zxbcdt = _dense(u, w_in, cd)
         z, xbc, dt = jnp.split(zxbcdt, [inner, inner + conv_dim], axis=-1)
-        # causal depthwise convolution: tap k reads position t - (K-1) + k
-        xbc = jnp.pad(xbc.astype(F32),
-                      ((0, 0), (self.conv_kernel - 1, 0), (0, 0)))
-        xbc = sum(conv_w[k] * xbc[:, k:k + t]
-                  for k in range(self.conv_kernel)) + conv_b
-        xbc = nn.silu(xbc).astype(cd)
+        xbc = nn.silu(_causal_conv(xbc, conv_w) + conv_b).astype(cd)
         x, b, c = jnp.split(xbc, [inner, inner + gn], axis=-1)
         x = x.reshape(bs, t, self.heads, self.head_dim)
         dt = jax.nn.softplus(dt.astype(F32) + dt_bias)
@@ -208,6 +221,87 @@ class SSMMixer(nn.Module):
         y = _rms(y.reshape(bs, t, self.groups, inner // self.groups),
                  self.eps).reshape(bs, t, inner) * norm_w
         return _dense(y, w_out, cd)
+
+
+class DeltaAttention(nn.Module):
+    """Kimi Delta Attention over (batch, T, hidden): ``heads`` heads
+    whose keys and values are both ``head_dim`` wide.
+
+    ``q``, ``k``, ``v`` = ``silu(conv(u W))``, each with its own
+    projection and its own ``conv_kernel`` taps (no bias); a head's
+    query and key over ``sqrt(sum of squares + L2_EPS)``, the query
+    times ``head_dim^(-1/2)``. The decay, a value a key channel, in
+    float32: ``g = -exp(A_log[head]) softplus((u f_a) f_b + dt_bias)``
+    through a bottleneck as wide as a head; the step size ``beta =
+    sigmoid(u b_proj)``, one a head. ``o`` is the gated delta rule's
+    (``ops.kda_scan``: the state forgets a channel at a time and is
+    corrected, under each key, towards the value). The mixer returns
+    ``(RMSNorm_head(o) o_norm * sigmoid((u g_a) g_b)) o_proj``."""
+
+    heads: int = 32
+    head_dim: int = 128
+    conv_kernel: int = 4
+    chunk: int = 64
+    eps: float = 1e-5
+    dt_limits: Sequence[float] = (1e-3, 1e-1, 1e-4)   # min, max, floor
+    out_scale: float = 1.0
+    compute_dtype: Any = jnp.bfloat16
+
+    L2_EPS = 1e-6       # under the root of a head's sum of squares
+
+    @nn.compact
+    def __call__(self, u):
+        bs, t, hidden = u.shape
+        cd, heads, hd = self.compute_dtype, self.heads, self.head_dim
+        inner = heads * hd
+        proj, conv = {}, {}
+        for name in "qkv":
+            proj[name] = self.param(f"{name}_proj", _kernel_init(),
+                                    (hidden, inner), F32)
+            conv[name] = self.param(
+                f"{name}_conv",
+                nn.initializers.uniform(2.0 / np.sqrt(self.conv_kernel)),
+                (self.conv_kernel, inner), F32)
+        f_a = self.param("f_a", _kernel_init(), (hidden, hd), F32)
+        f_b = self.param("f_b", _kernel_init(), (hd, inner), F32)
+        dt_bias = self.param("dt_bias", _dt_bias_init(*self.dt_limits),
+                             (inner,), F32)
+        a_log = self.param("A_log", _a_log_init, (heads,), F32)
+        b_proj = self.param("b_proj", _kernel_init(), (hidden, heads), F32)
+        g_a = self.param("g_a", _kernel_init(), (hidden, hd), F32)
+        g_b = self.param("g_b", _kernel_init(), (hd, inner), F32)
+        o_norm = self.param("o_norm", nn.initializers.ones, (hd,), F32)
+        w_out = self.param("o_proj", _kernel_init(self.out_scale),
+                           (inner, hidden), F32)
+        if self.is_initializing():
+            return jnp.zeros_like(u)
+
+        def by_head(x):
+            return x.reshape(bs, t, heads, hd)
+
+        def l2(x):
+            return x * lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True)
+                                 + self.L2_EPS)
+
+        with jax.named_scope("kda_project"):
+            q, k, v = (_dense(u, proj[name], cd) for name in "qkv")
+        with jax.named_scope("kda_conv"):
+            q, k, v = (by_head(nn.silu(_causal_conv(x, conv[name])))
+                       for name, x in zip("qkv", (q, k, v)))
+            q, k, v = ((l2(q) * hd ** -0.5).astype(cd), l2(k).astype(cd),
+                       v.astype(cd))
+        with jax.named_scope("kda_gates"):
+            g = kda_gate(by_head(_dense(_dense(u, f_a, cd), f_b, cd, out=F32)),
+                         a_log, dt_bias)
+            beta = jax.nn.sigmoid(_dense(u, b_proj, cd, out=F32))
+            gate = jax.nn.sigmoid(_dense(_dense(u, g_a, cd), g_b, cd,
+                                         out=F32))
+        with jax.named_scope("kda_scan"):
+            o = kda_scan(q, k, v, g, beta, chunk=self.chunk,
+                         compute_dtype=cd)
+        with jax.named_scope("kda_out"):
+            y = (_rms(o, self.eps) * o_norm).reshape(bs, t, inner) * gate
+            return _dense(y, w_out, cd)
 
 
 def route(scores, per_token, scaling):
@@ -567,10 +661,15 @@ class LatentAttention(nn.Module):
     kernel takes the two apart, neither padded to the other).
     ``rope_scaling`` is None or a :class:`YarnRule`, which changes the
     rotary frequencies and, through :func:`softmax_scale`, the factor the
-    kernel multiplies the scores by."""
+    kernel multiplies the scores by. ``q_rank`` None is a model without
+    the low-rank query: one direct projection ``q_proj`` to every head's
+    ``[nope | rope]``. ``positions`` False is a model whose latent
+    attention carries no position (other layers do): the ``rope_dim``
+    features of every query head and the one shared key of that width
+    enter the scores as projected, and nothing is rotated."""
 
     heads: int = 20
-    q_rank: int = 768
+    q_rank: Any = 768               # None: no low-rank query
     kv_rank: int = 512
     nope_dim: int = 192
     rope_dim: int = 64
@@ -580,17 +679,23 @@ class LatentAttention(nn.Module):
     out_scale: float = 1.0
     compute_dtype: Any = jnp.bfloat16
     rope_scaling: Any = None
+    positions: bool = True
 
     @nn.compact
     def __call__(self, u):
         bs, t, hidden = u.shape
         cd, heads = self.compute_dtype, self.heads
         nope, rope, vd = self.nope_dim, self.rope_dim, self.v_dim
-        q_a = self.param("q_a", _kernel_init(), (hidden, self.q_rank), F32)
-        q_norm = self.param("q_norm", nn.initializers.ones, (self.q_rank,),
-                            F32)
-        q_b = self.param("q_b", _kernel_init(),
-                         (self.q_rank, heads * (nope + rope)), F32)
+        if self.q_rank is None:
+            q_proj = self.param("q_proj", _kernel_init(),
+                                (hidden, heads * (nope + rope)), F32)
+        else:
+            q_a = self.param("q_a", _kernel_init(), (hidden, self.q_rank),
+                             F32)
+            q_norm = self.param("q_norm", nn.initializers.ones,
+                                (self.q_rank,), F32)
+            q_b = self.param("q_b", _kernel_init(),
+                             (self.q_rank, heads * (nope + rope)), F32)
         kv_a = self.param("kv_a", _kernel_init(),
                           (hidden, self.kv_rank + rope), F32)
         kv_norm = self.param("kv_norm", nn.initializers.ones,
@@ -603,22 +708,36 @@ class LatentAttention(nn.Module):
             return jnp.zeros_like(u)
         from persia_tpu.ops.flash_attention import flash_attention_masked
 
+        rule = self.rope_scaling
         with jax.named_scope("latent_project"):
-            c_q = (_rms(_dense(u, q_a, cd), self.eps) * q_norm).astype(cd)
-            q = _dense(c_q, q_b, cd).reshape(bs, t, heads, nope + rope)
+            if self.q_rank is None:
+                q = _dense(u, q_proj, cd)
+            else:
+                c_q = (_rms(_dense(u, q_a, cd), self.eps)
+                       * q_norm).astype(cd)
+                q = _dense(c_q, q_b, cd)
+            q = q.reshape(bs, t, heads, nope + rope)
             c_kv, k_rope = jnp.split(_dense(u, kv_a, cd), [self.kv_rank],
                                      axis=-1)
             c_kv = (_rms(c_kv, self.eps) * kv_norm).astype(cd)
             kv = _dense(c_kv, kv_b, cd).reshape(bs, t, heads, nope + vd)
-        with jax.named_scope("rotary"):
-            rule = self.rope_scaling
-            q_rope = rotary(q[..., nope:], self.rope_theta, rule).astype(cd)
-            k_rope = rotary(k_rope[:, :, None, :], self.rope_theta,
-                            rule).astype(cd)
-            q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
-            k = jnp.concatenate(
-                [kv[..., :nope],
-                 jnp.broadcast_to(k_rope, (bs, t, heads, rope))], axis=-1)
+
+            def keys(k_rope):   # a head's [nope | the one shared key]
+                return jnp.concatenate(
+                    [kv[..., :nope],
+                     jnp.broadcast_to(k_rope, (bs, t, heads, rope))],
+                    axis=-1)
+
+            if not self.positions:      # as projected
+                k = keys(k_rope[:, :, None, :])
+        if self.positions:
+            with jax.named_scope("rotary"):
+                q_rope = rotary(q[..., nope:], self.rope_theta,
+                                rule).astype(cd)
+                k_rope = rotary(k_rope[:, :, None, :], self.rope_theta,
+                                rule).astype(cd)
+                q = jnp.concatenate([q[..., :nope], q_rope], axis=-1)
+                k = keys(k_rope)
         q, k, v = (y.transpose(0, 2, 1, 3) for y in (q, k, kv[..., nope:]))
         with jax.named_scope("flash_attention"):    # the calls' name in a trace
             out = flash_attention_masked(
@@ -826,7 +945,7 @@ class HybridSequenceTower(nn.Module):
     expert_activation: str = "relu2"
     dense_width: int = 10240
     latent_heads: int = 20
-    latent_q_rank: int = 768
+    latent_q_rank: Any = 768        # None: one direct query projection
     latent_kv_rank: int = 512
     latent_nope_dim: int = 192
     latent_rope_dim: int = 64
@@ -838,9 +957,14 @@ class HybridSequenceTower(nn.Module):
     sinkhorn_iters: int = 20
     hyper_eps: float = 1e-6
     hyper_clamp: Sequence[float] = (-30.0, 30.0)
+    latent_positions: bool = True   # False: nothing rotates in `L`
+    kda_heads: int = 32
+    kda_head_dim: int = 128
+    kda_chunk: int = 64
 
     SCOPES = {"M": "ssm_mixer", "E": "experts", "*": "attention",
-              "L": "latent_attention", "D": "dense_ffn"}
+              "L": "latent_attention", "D": "dense_ffn",
+              "K": "kda_attention"}
 
     def step_tags(self):
         """What ``make_device_mode_trainer`` tags its build with."""
@@ -855,8 +979,17 @@ class HybridSequenceTower(nn.Module):
         # keeps its kernel's out and lse across nn.remat
         kept = sum(kind in "*L" for kind in
                    self.pattern + self.pattern[-2:] * self.mtp_depth)
+        kda = self.pattern.count("K")
         return {"tower_layers": self.pattern,
                 "attention_residuals_kept": kept,
+                # the delta-rule layers, their heads and their chunk
+                "kda_layers": kda,
+                "kda_heads": self.kda_heads * bool(kda),
+                "kda_chunk": self.kda_chunk * bool(kda),
+                # whether attention itself carries position (rotary
+                # keys in `L`), or leaves it to the other layers
+                "attention_positions":
+                    int("L" in self.pattern and self.latent_positions),
                 # the sublayers whose hyper-connection runs
                 # ops/hyper_connection's kernels: all, or none
                 "hyper_fused_sublayers": len(self.pattern) * hyper,
@@ -895,7 +1028,13 @@ class HybridSequenceTower(nn.Module):
                                    self.latent_kv_rank, self.latent_nope_dim,
                                    self.latent_rope_dim, self.latent_v_dim,
                                    self.rope_theta, self.eps, out_scale, cd,
-                                   self.rope_scaling, parent=None)
+                                   self.rope_scaling, self.latent_positions,
+                                   parent=None)
+        if kind == "K":
+            return DeltaAttention(self.kda_heads, self.kda_head_dim,
+                                  self.conv_kernel, self.kda_chunk, self.eps,
+                                  out_scale=out_scale, compute_dtype=cd,
+                                  parent=None)
         if kind == "D":
             return GatedFeedForward(self.dense_width, out_scale, cd,
                                     parent=None)

@@ -34,7 +34,11 @@ square-relu experts, 3 for silu-gated ones), ``mtp_depth``,
 ``out`` and ``lse`` its ``nn.remat`` policy keeps),
 ``hyper_fused_sublayers`` (the sublayers whose hyper-connection runs
 ``ops/hyper_connection``'s kernels: all of them over several residual
-streams, 0 over one) and, where its
+streams, 0 over one), ``kda_layers`` (its delta-rule layers, with their
+``kda_heads`` and the ``kda_chunk`` their recurrence runs in; all three
+0 without such a layer), ``attention_positions`` (1 where latent
+attention rotates a part of its queries and keys, 0 where attention
+carries no position and leaves it to the other layers) and, where its
 pattern has attention, ``key_width`` and ``value_width``.
 
 The step keeps its own account (:class:`DeviceStep`, which

@@ -454,7 +454,10 @@ def test_the_configuration_states_the_parameters_it_runs():
                                  "sinkhorn_iters": 0, "key_width": 128,
                                  "value_width": 128,
                                  "attention_residuals_kept": 1,
-                                 "hyper_fused_sublayers": 0}
+                                 "hyper_fused_sublayers": 0,
+                                 "kda_layers": 0, "kda_heads": 0,
+                                 "kda_chunk": 0,
+                                 "attention_positions": 0}
 
 
 def test_kernels_roofline_is_the_algorithm_s_need_at_the_rows_routed():

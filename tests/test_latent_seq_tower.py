@@ -457,7 +457,10 @@ def test_the_configuration_states_the_parameters_it_runs():
                                  "sinkhorn_iters": 0, "key_width": 256,
                                  "value_width": 256,
                                  "attention_residuals_kept": 6,
-                                 "hyper_fused_sublayers": 0}
+                                 "hyper_fused_sublayers": 0,
+                                 "kda_layers": 0, "kda_heads": 0,
+                                 "kda_chunk": 0,
+                                 "attention_positions": 1}
     model = placement.build_model(sz)
     shapes = jax.eval_shape(
         lambda: model.init(jax.random.key(0), [],

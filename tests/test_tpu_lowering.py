@@ -12,6 +12,11 @@ take a mask on the chip. Two guards, both run here on the CPU:
   unsupported layout changes). Skipped only where libtpu is not
   installed; with libtpu, any failure to get the topology fails.
 
+The hybrid sequence tower's ``K`` layer (Kimi Delta Attention, plain XLA:
+a 32-step map over heads' diagonal blocks inside a map over groups of
+heads, a carry over chunks, both under ``jax.checkpoint``) goes through
+the same two guards at the published widths, forward and backward.
+
 Neither replaces the compiled-and-compared check on the chip
 (``chip_smoke.py`` kernel phase, ``test_compiled_on_tpu``): values only
 come from a run.
@@ -112,6 +117,60 @@ def test_custom_block_sizes_lower_for_tpu():
         assert "tpu_custom_call" in exported.mlir_module()
 
 
+def _delta_layer(t):
+    """The gradient of a ``K`` layer of the hybrid sequence tower at the
+    published widths (hidden 2304, 32 heads of 128, chunk 64) over ``t``
+    positions, under the tower's ``nn.remat``, and its abstract
+    arguments."""
+    from flax import linen as nn
+
+    from persia_tpu.models import hybrid_seq
+
+    def layer(of=hybrid_seq._Layer):
+        return of(hybrid_seq.DeltaAttention(parent=None), "kda_attention",
+                  1e-5, jnp.bfloat16)
+
+    h = jax.ShapeDtypeStruct((1, t, 2304), jnp.bfloat16)
+    params = jax.eval_shape(lambda: layer().init(
+        jax.random.key(0), jnp.zeros((1, 64, 2304), jnp.bfloat16)))
+
+    def grad(params, h):
+        return jax.grad(lambda p, h: jnp.sum(
+            layer(nn.remat(hybrid_seq._Layer)).apply(p, h).astype(
+                jnp.float32)), argnums=(0, 1))(params, h)
+
+    return grad, params, h
+
+
+def test_the_delta_rule_layer_cross_lowers_for_tpu():
+    grad, params, h = _delta_layer(1000)        # pads to whole chunks
+    exported = jax.export.export(jax.jit(grad), platforms=["tpu"])(params, h)
+    text = exported.mlir_module()
+    assert "stablehlo.while" in text            # the maps and the carry
+    assert "tpu_custom_call" not in text        # no kernel of its own
+
+
+def _aot_compile_delta() -> int:
+    """Subprocess body: the ``K`` layer's gradient for a v5e, no chip."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2")
+    sharding = SingleDeviceSharding(topo.devices[0])
+    grad, params, h = _delta_layer(2048)
+    placed = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        (params, h))
+    try:
+        mem = jax.jit(grad).lower(*placed).compile().memory_analysis()
+        print(f"COMPILED kda layer: {mem.temp_size_in_bytes} temporary bytes")
+        return 0
+    except Exception as e:  # noqa: BLE001
+        print(f"REFUSED kda layer: {str(e)[:600]}")
+        return 1
+
+
 def _aot_compile_all() -> int:
     """Subprocess body: compile every variant for a v5e with no chip."""
     from jax.experimental import topologies
@@ -137,7 +196,7 @@ def _aot_compile_all() -> int:
     return failed
 
 
-def test_attention_aot_compiles_for_v5e():
+def _aot_subprocess(*what):
     # the one legitimate skip: no TPU compiler in this installation.
     # With libtpu present, a topology it cannot describe is a failure —
     # a skip would put back the false green this file exists to end
@@ -147,14 +206,23 @@ def test_attention_aot_compiles_for_v5e():
     # libtpu wants these named when there is no TPU metadata to read
     env.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
     env.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
-    r = subprocess.run([sys.executable, os.path.abspath(__file__)],
+    r = subprocess.run([sys.executable, os.path.abspath(__file__), *what],
                        env=env, capture_output=True, text=True,
                        timeout=600)
     assert r.returncode == 0 and "REFUSED" not in r.stdout, (
         r.stdout[-4000:] + r.stderr[-2000:])
-    assert r.stdout.count("COMPILED") == 8 * len(AOT_SHAPES), (
-        r.stdout[-4000:])
+    return r.stdout
+
+
+def test_attention_aot_compiles_for_v5e():
+    out = _aot_subprocess()
+    assert out.count("COMPILED") == 8 * len(AOT_SHAPES), out[-4000:]
+
+
+def test_the_delta_rule_layer_aot_compiles_for_v5e():
+    assert "COMPILED kda layer" in _aot_subprocess("delta")
 
 
 if __name__ == "__main__":
-    sys.exit(_aot_compile_all())
+    sys.exit(_aot_compile_delta() if sys.argv[1:] == ["delta"]
+             else _aot_compile_all())
