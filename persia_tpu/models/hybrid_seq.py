@@ -98,7 +98,14 @@ flash kernel's ``out`` and ``lse`` (``flash_attention.RESIDUAL_NAMES``,
 the remat policy here): (batch, heads, T, value width) more in the
 compute dtype and a float32 row a head, for which the rebuilt layer runs
 its projections and rotations again but not the kernel, whose forward
-call is a quarter of the layer's attention time.
+call is a quarter of the layer's attention time. A ``K`` layer keeps the
+recurrence's output and the states that enter its chunks
+(``kda_scan.RESIDUAL_NAMES``, the same policy): (batch, T, heads, head
+width) in float32 and (batch, heads, T / chunk, head width, head width)
+in the compute dtype, 268 MB a layer at 8192 positions and 32 heads of
+128, for which the rebuilt layer runs its projections, convolutions and
+gates again but not the recurrence's forward kernel, and the backward
+kernel carries no state forward again.
 
 ``init`` declares every parameter and runs no mixer: a trainer that
 initialises eagerly (``make_device_mode_trainer``) would otherwise
@@ -116,7 +123,11 @@ import numpy as np
 from flax import linen as nn
 from jax import lax
 
-from persia_tpu.ops.kda_scan import kda_gate, kda_scan
+from persia_tpu.ops.kda_scan import (
+    RESIDUAL_NAMES as KDA_RESIDUAL_NAMES,
+    kda_gate,
+    kda_scan,
+)
 from persia_tpu.ops.ssm_scan import ssm_scan
 
 F32 = jnp.float32
@@ -296,7 +307,7 @@ class DeltaAttention(nn.Module):
             beta = jax.nn.sigmoid(_dense(u, b_proj, cd, out=F32))
             gate = jax.nn.sigmoid(_dense(_dense(u, g_a, cd), g_b, cd,
                                          out=F32))
-        with jax.named_scope("kda_scan"):
+        with jax.named_scope("kda_scan"):   # the kernels' name in a trace
             o = kda_scan(q, k, v, g, beta, chunk=self.chunk,
                          compute_dtype=cd)
         with jax.named_scope("kda_out"):
@@ -982,8 +993,11 @@ class HybridSequenceTower(nn.Module):
         kda = self.pattern.count("K")
         return {"tower_layers": self.pattern,
                 "attention_residuals_kept": kept,
-                # the delta-rule layers, their heads and their chunk
+                # the delta-rule layers, their heads and their chunk; the
+                # layers whose recurrence runs ops/kda_scan's kernels and
+                # keeps their output across nn.remat: all of them
                 "kda_layers": kda,
+                "kda_fused_layers": kda,
                 "kda_heads": self.kda_heads * bool(kda),
                 "kda_chunk": self.kda_chunk * bool(kda),
                 # whether attention itself carries position (rotary
@@ -1058,7 +1072,7 @@ class HybridSequenceTower(nn.Module):
             from persia_tpu.ops.flash_attention import RESIDUAL_NAMES
             layer = nn.remat(
                 layer, policy=jax.checkpoint_policies.save_only_these_names(
-                    *RESIDUAL_NAMES))
+                    *RESIDUAL_NAMES, *KDA_RESIDUAL_NAMES))
         hyper = () if streams == 1 else (
             streams, self.sinkhorn_iters, self.hyper_eps,
             tuple(self.hyper_clamp))

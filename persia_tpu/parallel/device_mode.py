@@ -35,8 +35,11 @@ square-relu experts, 3 for silu-gated ones), ``mtp_depth``,
 ``hyper_fused_sublayers`` (the sublayers whose hyper-connection runs
 ``ops/hyper_connection``'s kernels: all of them over several residual
 streams, 0 over one), ``kda_layers`` (its delta-rule layers, with their
-``kda_heads`` and the ``kda_chunk`` their recurrence runs in; all three
-0 without such a layer), ``attention_positions`` (1 where latent
+``kda_heads`` and the ``kda_chunk`` their recurrence runs in, and
+``kda_fused_layers``, those of them whose recurrence runs
+``ops/kda_scan``'s kernels and whose output the ``nn.remat`` policy
+keeps: all of them; all four 0 without such a layer),
+``attention_positions`` (1 where latent
 attention rotates a part of its queries and keys, 0 where attention
 carries no position and leaves it to the other layers) and, where its
 pattern has attention, ``key_width`` and ``value_width``.

@@ -455,8 +455,8 @@ def test_the_configuration_states_the_parameters_it_runs():
                                  "value_width": 128,
                                  "attention_residuals_kept": 1,
                                  "hyper_fused_sublayers": 0,
-                                 "kda_layers": 0, "kda_heads": 0,
-                                 "kda_chunk": 0,
+                                 "kda_layers": 0, "kda_fused_layers": 0,
+                                 "kda_heads": 0, "kda_chunk": 0,
                                  "attention_positions": 0}
 
 

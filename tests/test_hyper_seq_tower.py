@@ -499,8 +499,8 @@ def test_the_configuration_states_the_parameters_it_runs():
         "experts_routed": 64, "expert_matrices": 3, "mtp_depth": 0,
         "residual_streams": 4, "sinkhorn_iters": 20, "key_width": 192,
         "value_width": 128, "attention_residuals_kept": 5,
-        "hyper_fused_sublayers": 10, "kda_layers": 0, "kda_heads": 0,
-        "kda_chunk": 0, "attention_positions": 1}
+        "hyper_fused_sublayers": 10, "kda_layers": 0, "kda_fused_layers": 0,
+        "kda_heads": 0, "kda_chunk": 0, "attention_positions": 1}
     assert tower.rope_scaling == hybrid_seq.YarnRule(64, 4096, 32, 1, 1, 1)
     model = placement.build_model(sz)
     shapes = jax.eval_shape(

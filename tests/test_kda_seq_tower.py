@@ -376,12 +376,14 @@ def test_the_build_is_tagged_and_the_step_carries_its_scopes(built):
     assert tags["tower_layers"] == "KDKEKELEKE"
     assert (tags["kda_layers"], tags["kda_heads"], tags["kda_chunk"]) == (
         4, 2, 32)
+    assert tags["kda_fused_layers"] == 4     # every `K` layer's kernels
     assert tags["attention_positions"] == 0
     assert tags["key_width"] == 24 and tags["value_width"] == 16
     assert tags["attention_residuals_kept"] == 1     # the one `L` layer
     assert tags["experts_routed"] == 16 and tags["expert_matrices"] == 3
     gauges = metrics.default_registry()
-    for name, value in (("kda_layers", 4), ("kda_heads", 2),
+    for name, value in (("kda_layers", 4), ("kda_fused_layers", 4),
+                        ("kda_heads", 2),
                         ("kda_chunk", 32), ("attention_positions", 0),
                         ("tower_layers", 10), ("experts_held", 4),
                         ("key_width", 24), ("value_width", 16)):
@@ -403,6 +405,21 @@ def test_the_build_is_tagged_and_the_step_carries_its_scopes(built):
                    "layer_3/experts/mixer/experts_grouped"):
         assert nested in text, nested
     assert "rotary" not in text
+
+
+@pytest.mark.parametrize("cell_name", [
+    "nemotron-3-nano-30b-a3b.device-histories8k",
+    "glm-4.7-flash.device-histories8k",
+    "xing4.0-29b-a4b.device-histories8k"])
+def test_a_tower_without_a_delta_rule_layer_fuses_none(cell_name):
+    """``kda_fused_layers`` counts the ``K`` layers, whose recurrence is
+    ``ops/kda_scan``'s kernels: none in the other sequence cells."""
+    man = manifest.Manifest(manifest.repo_root(BENCH_DIR))
+    _, cell, config, _ = man.cell(cell_name)
+    other = importlib.import_module(f"placements.{cell['placement']}")
+    tags = other.build_model(other.weights.sizes_of(config)).tower.step_tags()
+    assert "K" not in tags["tower_layers"]
+    assert (tags["kda_layers"], tags["kda_fused_layers"]) == (0, 0)
 
 
 def test_routed_rows_counts_the_four_expert_layers(built):
@@ -429,8 +446,8 @@ def test_the_configuration_states_the_parameters_it_runs():
         "experts_routed": 256, "expert_matrices": 3, "mtp_depth": 0,
         "residual_streams": 1, "sinkhorn_iters": 0, "key_width": 192,
         "value_width": 128, "attention_residuals_kept": 1,
-        "hyper_fused_sublayers": 0, "kda_layers": 4, "kda_heads": 32,
-        "kda_chunk": 64, "attention_positions": 0}
+        "hyper_fused_sublayers": 0, "kda_layers": 4, "kda_fused_layers": 4,
+        "kda_heads": 32, "kda_chunk": 64, "attention_positions": 0}
     model = placement.build_model(sz)
     shapes = jax.eval_shape(
         lambda: model.init(jax.random.key(0), [],
@@ -568,15 +585,15 @@ def test_the_readers_read_one_attention_layer_and_four_expert_layers():
         pytest.approx(4 * nbytes / 819e9)
 
 
-def test_the_cell_is_in_the_manifest_with_its_three_readers():
+def test_the_cell_is_in_the_manifest_with_its_four_readers():
     man = manifest.Manifest(manifest.repo_root(BENCH_DIR))
     assert man.validate()
     entry, cell, config, _ = man.cell(CELL)
     assert (entry["chips"], entry["traffic"], cell["placement"]) == (
         1, "histories8k", "device_seq_kda")
     names = {m["name"] for m in man.metrics_of(CELL, "per_layer")}
+    # the recurrence is kernels with a trace group of their own
     assert {"mfu.kimi-linear-48b-a3b", "flash_roofline.kimi-linear-48b-a3b",
-            "grouped_roofline.kimi-linear-48b-a3b"} <= names
-    # the recurrence is plain XLA: no trace group, so no roofline entry
-    assert "kda_roofline.kimi-linear-48b-a3b" not in names
+            "grouped_roofline.kimi-linear-48b-a3b",
+            "kda_roofline.kimi-linear-48b-a3b"} <= names
     assert set(cell["limits_why"]) >= set(cell["limits"])

@@ -458,8 +458,8 @@ def test_the_configuration_states_the_parameters_it_runs():
                                  "value_width": 256,
                                  "attention_residuals_kept": 6,
                                  "hyper_fused_sublayers": 0,
-                                 "kda_layers": 0, "kda_heads": 0,
-                                 "kda_chunk": 0,
+                                 "kda_layers": 0, "kda_fused_layers": 0,
+                                 "kda_heads": 0, "kda_chunk": 0,
                                  "attention_positions": 1}
     model = placement.build_model(sz)
     shapes = jax.eval_shape(

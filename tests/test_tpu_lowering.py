@@ -12,10 +12,12 @@ take a mask on the chip. Two guards, both run here on the CPU:
   unsupported layout changes). Skipped only where libtpu is not
   installed; with libtpu, any failure to get the topology fails.
 
-The hybrid sequence tower's ``K`` layer (Kimi Delta Attention, plain XLA:
-a 32-step map over heads' diagonal blocks inside a map over groups of
-heads, a carry over chunks, both under ``jax.checkpoint``) goes through
-the same two guards at the published widths, forward and backward.
+The hybrid sequence tower's ``K`` layer (Kimi Delta Attention, whose
+recurrence is ``ops/kda_scan``'s two Pallas kernels) goes through the
+same two guards at the published widths, forward and backward under the
+tower's ``nn.remat``; so do the two kernels alone at the shape the
+delta-rule cell of the benchmark runs a layer (1 x 8192 x 32 x 128,
+chunk 64).
 
 Neither replaces the compiled-and-compared check on the chip
 (``chip_smoke.py`` kernel phase, ``test_compiled_on_tpu``): values only
@@ -134,41 +136,90 @@ def _delta_layer(t):
     params = jax.eval_shape(lambda: layer().init(
         jax.random.key(0), jnp.zeros((1, 64, 2304), jnp.bfloat16)))
 
+    from persia_tpu.ops.kda_scan import RESIDUAL_NAMES
+
+    remat = nn.remat(
+        hybrid_seq._Layer,
+        policy=jax.checkpoint_policies.save_only_these_names(*RESIDUAL_NAMES))
+
     def grad(params, h):
         return jax.grad(lambda p, h: jnp.sum(
-            layer(nn.remat(hybrid_seq._Layer)).apply(p, h).astype(
-                jnp.float32)), argnums=(0, 1))(params, h)
+            layer(remat).apply(p, h).astype(jnp.float32)),
+            argnums=(0, 1))(params, h)
 
     return grad, params, h
 
 
-def test_the_delta_rule_layer_cross_lowers_for_tpu():
+def _delta_kernels(grad, sharding=None):
+    """``kda_scan`` compiled (not interpreted), or the gradient of all
+    its five inputs, at the delta-rule cell's shape a layer, and its
+    abstract arguments."""
+    from persia_tpu.ops.kda_scan import kda_scan
+
+    def op(*xs):
+        return kda_scan(*xs, chunk=64, interpret=False)
+
+    def gradient(*xs):
+        return jax.grad(lambda *ys: jnp.sum(op(*ys)),
+                        argnums=(0, 1, 2, 3, 4))(*xs)
+
+    wide = (1, 8192, 32, 128)
+    avals = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+             for shape, dtype in [(wide, jnp.bfloat16)] * 3
+             + [(wide, jnp.float32), (wide[:3], jnp.float32)]]
+    return (gradient if grad else op), avals
+
+
+def test_the_delta_rule_layer_cross_lowers_for_tpu(monkeypatch):
+    # the mixer's op asks the default backend whether to compile or to
+    # interpret, and here that is the CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     grad, params, h = _delta_layer(1000)        # pads to whole chunks
     exported = jax.export.export(jax.jit(grad), platforms=["tpu"])(params, h)
     text = exported.mlir_module()
-    assert "stablehlo.while" in text            # the maps and the carry
-    assert "tpu_custom_call" not in text        # no kernel of its own
+    # the recurrence is kernels: one forward, kept across nn.remat, and
+    # one backward, and no loop of XLA's carries a state
+    assert text.count("tpu_custom_call") == 2
+    assert "stablehlo.while" not in text
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "gradient"])
+def test_the_delta_rule_kernels_cross_lower_at_the_cell_s_shape(grad):
+    fn, avals = _delta_kernels(grad)
+    text = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *avals).mlir_module()
+    assert text.count("tpu_custom_call") == 1 + grad
 
 
 def _aot_compile_delta() -> int:
-    """Subprocess body: the ``K`` layer's gradient for a v5e, no chip."""
+    """Subprocess body: the ``K`` layer's gradient, and the recurrence's
+    kernels alone at the cell's shape, for a v5e, no chip."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
     topo = topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2")
     sharding = SingleDeviceSharding(topo.devices[0])
+    jax.default_backend = lambda: "tpu"     # the mixer's op: compile
     grad, params, h = _delta_layer(2048)
     placed = jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
         (params, h))
-    try:
-        mem = jax.jit(grad).lower(*placed).compile().memory_analysis()
-        print(f"COMPILED kda layer: {mem.temp_size_in_bytes} temporary bytes")
-        return 0
-    except Exception as e:  # noqa: BLE001
-        print(f"REFUSED kda layer: {str(e)[:600]}")
-        return 1
+    failed = 0
+    for name, fn, avals in (
+            ("kda layer", grad, placed),
+            ("kda forward kernel", *_delta_kernels(False, sharding)),
+            ("kda gradient kernels", *_delta_kernels(True, sharding))):
+        try:
+            compiled = jax.jit(fn).lower(*avals).compile()
+            assert "tpu_custom_call" in compiled.as_text()
+            mem = compiled.memory_analysis()
+            print(f"COMPILED {name}: {mem.temp_size_in_bytes} temporary "
+                  f"bytes")
+        except Exception as e:  # noqa: BLE001 — each reported
+            failed += 1
+            print(f"REFUSED {name}: {str(e)[:600]}")
+    return failed
 
 
 def _aot_compile_all() -> int:
@@ -220,7 +271,9 @@ def test_attention_aot_compiles_for_v5e():
 
 
 def test_the_delta_rule_layer_aot_compiles_for_v5e():
-    assert "COMPILED kda layer" in _aot_subprocess("delta")
+    out = _aot_subprocess("delta")
+    for what in ("layer", "forward kernel", "gradient kernels"):
+        assert f"COMPILED kda {what}" in out, out[-4000:]
 
 
 if __name__ == "__main__":

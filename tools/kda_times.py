@@ -1,13 +1,16 @@
 """Time the Kimi Delta Attention recurrence alone on the chip.
 
     python3 tools/kda_times.py [--tree DIR] [--shapes 1x8192x32x128]
-        [--chunks 32,64,128] [--head-groups 8] [--out FILE]
+        [--chunks 32,64,128] [--out FILE]
 
 For each shape (batch x T x heads x head width, keys and values alike;
 default: what the delta-rule sequence cell of the benchmark runs a
 layer) and each chunk, the host-clock time of ``ops.kda_scan.kda_scan``
-forward and of its whole gradient (all five inputs; the forward is run
-again inside it, under the op's own checkpoint), over ``--iters`` calls
+forward (one Pallas call, which also writes the states that enter the
+chunks) and of its whole gradient (all five inputs: the forward call,
+then the backward call, which rebuilds each chunk's matrices from its
+inputs and the kept states; nothing names what is kept here, so this is
+the time of a layer whose forward ran once), over ``--iters`` calls
 that end in ``block_until_ready``, beside ``least_ms``: the larger of
 the recurrence's operations over the bf16 peak and the bytes of its
 inputs, its output and their gradients over the HBM peak
@@ -35,7 +38,6 @@ def main() -> int:
         os.path.abspath(__file__))))
     ap.add_argument("--shapes", default="1x8192x32x128")
     ap.add_argument("--chunks", default="32,64,128")
-    ap.add_argument("--head-groups", default="8")
     ap.add_argument("--iters", type=int, default=5)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
@@ -99,25 +101,23 @@ def main() -> int:
             {"kda_heads": heads, "kda_head_dim": hd, "pattern": "K"}, t, bs,
             peaks)
         for chunk in (int(c) for c in args.chunks.split(",")):
-            for group in (int(n) for n in args.head_groups.split(",")):
-                line = {"tree": args.tree, "device": dev.device_kind,
-                        "batch": bs, "t": t, "heads": heads, "width": hd,
-                        "chunk": chunk, "head_group": group,
-                        "least_ms": least * 1e3}
+            line = {"tree": args.tree, "device": dev.device_kind,
+                    "batch": bs, "t": t, "heads": heads, "width": hd,
+                    "chunk": chunk, "least_ms": least * 1e3}
 
-                def op(*xs):
-                    return kda_scan(*xs, chunk=chunk, head_group=group)
+            def op(*xs):
+                return kda_scan(*xs, chunk=chunk)
 
-                try:
-                    line["fwd_ms"], out = timed(jax.jit(op), q, k, v, g, beta)
-                    line["grad_ms"], grads = timed(jax.jit(jax.grad(
-                        lambda *xs: jnp.sum(op(*xs) * do),
-                        argnums=(0, 1, 2, 3, 4))), q, k, v, g, beta)
-                    line["digest"] = digest(out, *grads)
-                except Exception as e:  # noqa: BLE001 — a size refused
-                    line["refused"] = str(e)[:300]
-                lines.append(line)
-                print(json.dumps(line), flush=True)
+            try:
+                line["fwd_ms"], out = timed(jax.jit(op), q, k, v, g, beta)
+                line["grad_ms"], grads = timed(jax.jit(jax.grad(
+                    lambda *xs: jnp.sum(op(*xs) * do),
+                    argnums=(0, 1, 2, 3, 4))), q, k, v, g, beta)
+                line["digest"] = digest(out, *grads)
+            except Exception as e:  # noqa: BLE001 — a size refused
+                line["refused"] = str(e)[:300]
+            lines.append(line)
+            print(json.dumps(line), flush=True)
     if args.out:
         os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
         with open(args.out, "w") as f:
