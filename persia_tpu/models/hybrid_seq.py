@@ -1,6 +1,7 @@
 """Hybrid sequence tower: state-space and delta-rule mixers, sparse
-experts beside a shared expert, grouped-query and latent attention and a
-gated dense feed-forward over a history of item ids, with an item head
+experts beside a shared expert (or none), grouped-query, latent and
+indexer-selected attention and a gated dense feed-forward over a
+history of item ids, with an item head
 for next-item prediction and, optionally, one multi-token-prediction
 module for the item after next.
 
@@ -49,6 +50,16 @@ transformer block, attention then a feed-forward, is two letters):
        a step size a head from the input, the gated delta rule
        (``ops.kda_scan``), a gated norm a head, an output projection.
 ``D``  a gated dense feed-forward (:class:`GatedFeedForward`).
+``S``  causal grouped-query attention over the keys a learned indexer
+       selects (:class:`SelectedAttention`): queries and keys normed a
+       head and rotated whole, an indexer of its own (``ops.
+       sparse_select``) that scores every causal key for every query,
+       the ``select_topk`` best of them attended through the flash
+       kernel under a mask a (query, key)
+       (``flash_attention_selected``), and the indexer's alignment loss
+       as a second output: a tower with an ``S`` returns ``(logits,
+       index_loss)``, the loss summed over its ``S`` layers
+       (``parallel.train.next_item_cross_entropy_indexed``).
 
 ``mtp_depth`` 1 adds a :class:`NextPrediction` module after the last
 layer: it merges the last hidden state (before the final norm) with the
@@ -105,7 +116,12 @@ width) in float32 and (batch, heads, T / chunk, head width, head width)
 in the compute dtype, 268 MB a layer at 8192 positions and 32 heads of
 128, for which the rebuilt layer runs its projections, convolutions and
 gates again but not the recurrence's forward kernel, and the backward
-kernel carries no state forward again.
+kernel carries no state forward again. An ``S`` layer keeps, besides
+``out`` and ``lse``, its selection ((batch, T, T) int8, 67 MB at 8192)
+and its alignment loss's gradients to the indexer's queries, keys and
+weights (``sparse_select.RESIDUAL_NAMES``): the rebuilt layer runs its
+projections, norms and rotations again but neither the index scores and
+the selection nor the pass over the target.
 
 ``init`` declares every parameter and runs no mixer: a trainer that
 initialises eagerly (``make_device_mode_trainer``) would otherwise
@@ -127,6 +143,9 @@ from persia_tpu.ops.kda_scan import (
     RESIDUAL_NAMES as KDA_RESIDUAL_NAMES,
     kda_gate,
     kda_scan,
+)
+from persia_tpu.ops.sparse_select import (
+    RESIDUAL_NAMES as SELECT_RESIDUAL_NAMES,
 )
 from persia_tpu.ops.ssm_scan import ssm_scan
 
@@ -475,9 +494,17 @@ def _dispatch_bwd(rows, per_token, activation, saved, ct):
 dispatch_pairs.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
+# a router's scores over all the routed experts, float32
+SCORINGS = {"sigmoid": jax.nn.sigmoid,
+            "softmax": functools.partial(jax.nn.softmax, axis=-1)}
+
+
 class SparseExperts(nn.Module):
     """The held experts' part of a routed expert layer, plus the shared
-    expert (module docstring: the contract)."""
+    expert (module docstring: the contract). ``scoring`` names the
+    router's rule, an entry of ``SCORINGS``; ``shared_width`` 0 is a
+    model without a shared expert: no ``shared_w1``, ``shared_w2`` and
+    no ``experts_shared`` scope."""
 
     experts_routed: int = 128
     experts_held: Sequence[int] = tuple(range(8))
@@ -488,6 +515,7 @@ class SparseExperts(nn.Module):
     out_scale: float = 1.0
     compute_dtype: Any = jnp.bfloat16
     activation: str = "relu2"       # an entry of ACTIVATIONS
+    scoring: str = "sigmoid"        # an entry of SCORINGS
 
     @nn.compact
     def __call__(self, u):
@@ -500,17 +528,18 @@ class SparseExperts(nn.Module):
                         (held, hidden, wider * self.expert_width), F32)
         w2 = self.param("w2", _kernel_init(self.out_scale),
                         (held, self.expert_width, hidden), F32)
-        s1 = self.param("shared_w1", _kernel_init(),
-                        (hidden, wider * self.shared_width), F32)
-        s2 = self.param("shared_w2", _kernel_init(self.out_scale),
-                        (self.shared_width, hidden), F32)
+        if self.shared_width:
+            s1 = self.param("shared_w1", _kernel_init(),
+                            (hidden, wider * self.shared_width), F32)
+            s2 = self.param("shared_w2", _kernel_init(self.out_scale),
+                            (self.shared_width, hidden), F32)
         if self.is_initializing():
             return jnp.zeros_like(u)
         tokens = u.reshape(bs * t, hidden)
         n = tokens.shape[0]
 
         with jax.named_scope("experts_route"):
-            scores = jax.nn.sigmoid(jnp.dot(
+            scores = SCORINGS[self.scoring](jnp.dot(
                 tokens.astype(F32), w_r, precision=lax.Precision.HIGHEST))
             chosen, weight = route(scores, self.per_token, self.scaling)
             # where each chosen expert sits among the held ones; `held`
@@ -531,6 +560,8 @@ class SparseExperts(nn.Module):
                 weight.reshape(-1), jnp.pad(order, (0, pad)), sizes, cap,
                 self.per_token, self.activation)
 
+        if not self.shared_width:
+            return routed.astype(cd).reshape(bs, t, hidden)
         with jax.named_scope("experts_shared"):
             shared = _dense(act(_dense(tokens, s1, cd)), s2, cd)
         return (routed + shared.astype(F32)).astype(cd).reshape(
@@ -578,6 +609,115 @@ class GroupedQueryAttention(nn.Module):
             out = flash_attention_masked(q, k, v, causal=True)
         out = out.transpose(0, 2, 1, 3).reshape(bs, t, self.heads * hd)
         return _dense(out, wo, cd)
+
+
+class SelectedAttention(nn.Module):
+    """Causal grouped-query attention over the ``topk`` keys a learned
+    indexer selects for each query (a sparse-attention indexer of the
+    DeepSeek-V3.2-Exp kind beside a Qwen3-MoE attention block): returns
+    ``(output, index_loss)``.
+
+    Attention: ``q``, ``k``, ``v`` projections without bias, an RMSNorm
+    a head on ``q`` and ``k`` (``q_norm``, ``k_norm`` over ``head_dim``),
+    :func:`rotary` over the whole of every query and key head, softmax
+    over the selected keys only (``ops.flash_attention.
+    flash_attention_selected``), an output projection.
+
+    The indexer reads ``stop_gradient`` of the layer's input: ``q_i =
+    u index_q`` (``index_heads`` heads of ``index_dim``), one key ``k_i
+    = LayerNorm(u index_k)`` for all of them (``index_k_scale``,
+    ``index_k_bias``), ``w = u index_w`` times ``index_heads^(-1/2)
+    index_dim^(-1/2)``, the first ``index_rope_dim`` features of every
+    ``q_i`` head and of ``k_i`` rotated, and ``I[t, s] = sum_j w[t, j]
+    relu(q_i[t, j] . k_i[s])`` (``ops.sparse_select``, in tiles of
+    ``index_tile`` queries). Query ``t`` attends the ``min(t + 1,
+    topk)`` keys ``s <= t`` of largest ``I``; no gradient passes through
+    the choice. The indexer learns from ``index_loss`` alone, the
+    divergence of its softmax over the selected keys from the
+    attention's own head-averaged probabilities there (a constant), a
+    mean over the queries; everything else learns from the output
+    alone."""
+
+    heads: int = 32
+    kv_heads: int = 4
+    head_dim: int = 128
+    index_heads: int = 16
+    index_dim: int = 64
+    index_rope_dim: int = 32
+    topk: int = 2048
+    index_tile: int = 512
+    rope_theta: float = 1e7
+    eps: float = 1e-6
+    out_scale: float = 1.0
+    compute_dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, u):
+        bs, t, hidden = u.shape
+        cd, hd = self.compute_dtype, self.head_dim
+        ih, idim = self.index_heads, self.index_dim
+        wq = self.param("q_proj", _kernel_init(), (hidden, self.heads * hd),
+                        F32)
+        wk = self.param("k_proj", _kernel_init(),
+                        (hidden, self.kv_heads * hd), F32)
+        wv = self.param("v_proj", _kernel_init(),
+                        (hidden, self.kv_heads * hd), F32)
+        q_norm = self.param("q_norm", nn.initializers.ones, (hd,), F32)
+        k_norm = self.param("k_norm", nn.initializers.ones, (hd,), F32)
+        wo = self.param("o_proj", _kernel_init(self.out_scale),
+                        (self.heads * hd, hidden), F32)
+        index_q = self.param("index_q", _kernel_init(), (hidden, ih * idim),
+                             F32)
+        index_k = self.param("index_k", _kernel_init(), (hidden, idim), F32)
+        k_scale = self.param("index_k_scale", nn.initializers.ones, (idim,),
+                             F32)
+        k_bias = self.param("index_k_bias", nn.initializers.zeros, (idim,),
+                            F32)
+        index_w = self.param("index_w", _kernel_init(), (hidden, ih), F32)
+        if self.is_initializing():
+            return jnp.zeros_like(u), jnp.zeros((), F32)
+        from persia_tpu.ops import sparse_select
+        from persia_tpu.ops.flash_attention import flash_attention_selected
+
+        def turned(x, width):   # the first `width` features of each head
+            return jnp.concatenate(
+                [rotary(x[..., :width], self.rope_theta),
+                 x[..., width:].astype(F32)], axis=-1)
+
+        with jax.named_scope("select_project"):
+            q = _rms(_dense(u, wq, cd).reshape(bs, t, self.heads, hd),
+                     self.eps) * q_norm
+            k = _rms(_dense(u, wk, cd).reshape(bs, t, self.kv_heads, hd),
+                     self.eps) * k_norm
+            v = _dense(u, wv, cd).reshape(bs, t, self.kv_heads, hd)
+            still = lax.stop_gradient(u)
+            q_i = _dense(still, index_q, cd, out=F32).reshape(
+                bs, t, ih, idim)
+            k_i = _dense(still, index_k, cd, out=F32)
+            k_i = k_i - jnp.mean(k_i, axis=-1, keepdims=True)
+            k_i = _rms(k_i, self.eps) * k_scale + k_bias
+            w = _dense(still, index_w, cd, out=F32) * (ih * idim) ** -0.5
+        with jax.named_scope("rotary"):
+            q, k = (turned(x, hd).astype(cd).transpose(0, 2, 1, 3)
+                    for x in (q, k))
+            q_i = turned(q_i, self.index_rope_dim).astype(cd)
+            k_i = turned(k_i[:, :, None, :],
+                         self.index_rope_dim)[:, :, 0].astype(cd)
+        tile = sparse_select.tile_of(t, self.index_tile)
+        select = sparse_select.select_keys(
+            *map(lax.stop_gradient, (q_i, k_i, w)), self.topk, tile)
+        self.sow("selections", "selected", select)
+        group = self.heads // self.kv_heads
+        with jax.named_scope("flash_attention"):    # the calls' name in a trace
+            out, lse = flash_attention_selected(
+                q, jnp.repeat(k, group, axis=1),
+                jnp.repeat(v.transpose(0, 2, 1, 3), group, axis=1), select)
+        loss = sparse_select.alignment_loss(
+            q_i, k_i, w, *map(lax.stop_gradient, (q, k, lse)), select,
+            hd ** -0.5, tile)
+        with jax.named_scope("select_out"):
+            out = out.transpose(0, 2, 1, 3).reshape(bs, t, self.heads * hd)
+            return _dense(out, wo, cd), loss
 
 
 class YarnRule(NamedTuple):
@@ -778,7 +918,9 @@ class GatedFeedForward(nn.Module):
 
 
 class _Layer(nn.Module):
-    """``h + mixer(RMSNorm(h))`` under the mixer's scope name."""
+    """``h + mixer(RMSNorm(h))`` under the mixer's scope name; a mixer
+    that returns ``(output, loss)`` (``S``) gives ``(h + output,
+    loss)``."""
 
     mixer: nn.Module
     scope_name: str
@@ -789,7 +931,10 @@ class _Layer(nn.Module):
     def __call__(self, h):
         with jax.named_scope(self.scope_name):
             u = RMSNorm(self.eps, self.compute_dtype, name="norm")(h)
-            return h + self.mixer(u)
+            y = self.mixer(u)
+            if isinstance(y, tuple):
+                return h + y[0], y[1]
+            return h + y
 
 
 def sinkhorn(logits, iters, eps):
@@ -972,10 +1117,16 @@ class HybridSequenceTower(nn.Module):
     kda_heads: int = 32
     kda_head_dim: int = 128
     kda_chunk: int = 64
+    expert_scoring: str = "sigmoid"     # an entry of SCORINGS
+    index_heads: int = 16           # `S`: the indexer beside attn_*
+    index_dim: int = 64
+    index_rope_dim: int = 32
+    select_topk: int = 2048
+    index_tile: int = 512
 
     SCOPES = {"M": "ssm_mixer", "E": "experts", "*": "attention",
               "L": "latent_attention", "D": "dense_ffn",
-              "K": "kda_attention"}
+              "K": "kda_attention", "S": "selected_attention"}
 
     def step_tags(self):
         """What ``make_device_mode_trainer`` tags its build with."""
@@ -983,14 +1134,15 @@ class HybridSequenceTower(nn.Module):
         if "L" in self.pattern:
             widths = (self.latent_nope_dim + self.latent_rope_dim,
                       self.latent_v_dim)
-        elif "*" in self.pattern:
+        elif "*" in self.pattern or "S" in self.pattern:
             widths = (self.attn_head_dim, self.attn_head_dim)
         hyper = self.residual_streams > 1
         # attention layers, the prediction module's among them: each
         # keeps its kernel's out and lse across nn.remat
-        kept = sum(kind in "*L" for kind in
+        kept = sum(kind in "*LS" for kind in
                    self.pattern + self.pattern[-2:] * self.mtp_depth)
         kda = self.pattern.count("K")
+        selected = self.pattern.count("S")
         return {"tower_layers": self.pattern,
                 "attention_residuals_kept": kept,
                 # the delta-rule layers, their heads and their chunk; the
@@ -1003,7 +1155,16 @@ class HybridSequenceTower(nn.Module):
                 # whether attention itself carries position (rotary
                 # keys in `L`), or leaves it to the other layers
                 "attention_positions":
-                    int("L" in self.pattern and self.latent_positions),
+                    int("L" in self.pattern and self.latent_positions
+                        or selected > 0),
+                # the layers whose attention is over the keys an indexer
+                # selects, how many a query, and the indexer's heads;
+                # each keeps its selection and its alignment loss's
+                # gradients across nn.remat
+                "selected_layers": selected,
+                "select_topk": self.select_topk * bool(selected),
+                "index_heads": self.index_heads * bool(selected),
+                "expert_scoring": self.expert_scoring,
                 # the sublayers whose hyper-connection runs
                 # ops/hyper_connection's kernels: all, or none
                 "hyper_fused_sublayers": len(self.pattern) * hyper,
@@ -1032,7 +1193,7 @@ class HybridSequenceTower(nn.Module):
                                  self.shared_width, self.routed_scaling,
                                  out_scale, cd,
                                  activation=self.expert_activation,
-                                 parent=None)
+                                 scoring=self.expert_scoring, parent=None)
         if kind == "*":
             return GroupedQueryAttention(self.attn_heads, self.attn_kv_heads,
                                          self.attn_head_dim, out_scale, cd,
@@ -1052,6 +1213,12 @@ class HybridSequenceTower(nn.Module):
         if kind == "D":
             return GatedFeedForward(self.dense_width, out_scale, cd,
                                     parent=None)
+        if kind == "S":
+            return SelectedAttention(
+                self.attn_heads, self.attn_kv_heads, self.attn_head_dim,
+                self.index_heads, self.index_dim, self.index_rope_dim,
+                self.select_topk, self.index_tile, self.rope_theta,
+                self.eps, out_scale, cd, parent=None)
         raise ValueError(f"pattern {self.pattern!r}: unknown layer {kind!r}")
 
     @nn.compact
@@ -1064,6 +1231,10 @@ class HybridSequenceTower(nn.Module):
         streams = self.residual_streams
         if streams > 1 and self.mtp_depth:
             raise ValueError("no prediction module over residual streams")
+        selected = "S" in self.pattern
+        if selected and (streams > 1 or self.mtp_depth):
+            raise ValueError("selected attention runs over one residual "
+                             "stream and without a prediction module")
         # output projections start smaller the deeper the stack
         # (the published rescale_prenorm_residual)
         out_scale = 1.0 / len(self.pattern)
@@ -1072,7 +1243,8 @@ class HybridSequenceTower(nn.Module):
             from persia_tpu.ops.flash_attention import RESIDUAL_NAMES
             layer = nn.remat(
                 layer, policy=jax.checkpoint_policies.save_only_these_names(
-                    *RESIDUAL_NAMES, *KDA_RESIDUAL_NAMES))
+                    *RESIDUAL_NAMES, *KDA_RESIDUAL_NAMES,
+                    *SELECT_RESIDUAL_NAMES))
         hyper = () if streams == 1 else (
             streams, self.sinkhorn_iters, self.hyper_eps,
             tuple(self.hyper_clamp))
@@ -1084,8 +1256,12 @@ class HybridSequenceTower(nn.Module):
         if streams > 1:
             with jax.named_scope("hyper_expand"):   # every stream the row
                 h = jnp.concatenate([h] * streams, axis=-1)
+        index_loss = jnp.zeros((), F32)     # summed over the `S` layers
         for i, kind in enumerate(self.pattern):
             h = layer_of(kind, name=f"layer_{i}")(h)
+            if kind == "S":
+                h, part = h
+                index_loss = index_loss + part
         if streams > 1:
             with jax.named_scope("hyper_contract"):
                 h = sum(h[..., j * self.hidden:(j + 1) * self.hidden].astype(
@@ -1095,6 +1271,8 @@ class HybridSequenceTower(nn.Module):
             w = self.param("item_head", _kernel_init(),
                            (self.hidden, self.vocab), F32)
             logits = _dense(u, w, self.compute_dtype, out=F32)
+        if selected:
+            return logits, index_loss
         if not self.mtp_depth:
             return logits
         with jax.named_scope("mtp"):
@@ -1104,13 +1282,11 @@ class HybridSequenceTower(nn.Module):
         return logits, ahead
 
 
-def routed_rows(model, params, non_id_tensors, id_tensors):
-    """For one batch, the rows routed to each held expert of each expert
-    layer of ``model`` (a ``DeviceModeModel`` over this tower), as a
-    (expert layers, held) int32 array in layer order."""
+def sown(model, params, non_id_tensors, id_tensors, *collections):
+    """What the tower's layers sowed into each of ``collections`` over
+    one batch (one forward pass), each stacked in layer order."""
     _, state = model.apply({"params": params}, non_id_tensors, id_tensors,
-                           train=False, mutable=["intermediates"])
-    found = jax.tree_util.tree_flatten_with_path(state["intermediates"])[0]
+                           train=False, mutable=list(collections))
 
     def layer_of(path):     # the prediction module's layers come last
         keys = [getattr(k, "key", "") for k in path]
@@ -1118,5 +1294,32 @@ def routed_rows(model, params, non_id_tensors, id_tensors):
                 min(int(k.split("_")[1]) for k in keys
                     if k.startswith("layer_")))
 
-    found = sorted(found, key=lambda kv: layer_of(kv[0]))
-    return jnp.stack([leaf for _, leaf in found])
+    def stacked(collection):
+        found = jax.tree_util.tree_flatten_with_path(state[collection])[0]
+        found = sorted(found, key=lambda kv: layer_of(kv[0]))
+        return jnp.stack([leaf for _, leaf in found])
+
+    return [stacked(c) for c in collections]
+
+
+def routed_rows(model, params, non_id_tensors, id_tensors):
+    """For one batch, the rows routed to each held expert of each expert
+    layer of ``model`` (a ``DeviceModeModel`` over this tower), as a
+    (expert layers, held) int32 array in layer order."""
+    return sown(model, params, non_id_tensors, id_tensors,
+                "intermediates")[0]
+
+
+def selected_keys(model, params, non_id_tensors, id_tensors):
+    """For one batch, the keys each query of each ``S`` layer attends, as
+    the step itself would select them at these parameters: (``S``
+    layers, batch, T, T) int8 in layer order, 1 where query ``t``
+    attends key ``s``."""
+    return sown(model, params, non_id_tensors, id_tensors, "selections")[0]
+
+
+def selected_pairs(model, params, non_id_tensors, id_tensors):
+    """For one batch, the (query, key) pairs each ``S`` layer selected:
+    (``S`` layers,) int32 in layer order."""
+    return jnp.sum(selected_keys(model, params, non_id_tensors, id_tensors),
+                   axis=(1, 2, 3), dtype=jnp.int32)

@@ -24,7 +24,11 @@ rectangle has 256 in 512 x 512 blocks, 36 of 64 in the 1024 x 1024 that
 ``_blocks`` picks, and only the 16 (8) that straddle the diagonal pay
 for the mask (``interior`` pairs run the same body without the iotas,
 the comparisons and the select). A non-causal call gets its whole
-rectangle from the same function.
+rectangle from the same function. ``flash_attention_selected`` walks
+the causal list too and hands each kernel, beside the positional mask,
+the block of an int8 ``(B, T_q, T_k)`` selection that its scheduled pair
+names (one selection for all the heads): a pair is walked whether or not
+any of its keys is selected.
 
 Kernel shape rules: the head width is the lane axis of every block, and
 there are two of them: queries, keys and their gradients are ``dk``
@@ -142,10 +146,11 @@ def _run_bodies(body, flags, has_edge: bool, has_interior: bool):
 
 
 def _block_mask(edge: bool, qi, ki, block_q, block_k, t_k_real, causal,
-                mask_row, t_q_real=None):
+                mask_row, t_q_real=None, selected=None):
     """The (block_q, block_k) mask of a pair, or None where every score
     is live: positions (padding, causal) only in an edge pair, the
-    ``kv_mask`` row, (1, bk) broadcast, in either kind."""
+    ``kv_mask`` row, (1, bk) broadcast, and the ``select`` block, one
+    entry a (query, key), in either kind."""
     mask = None
     if edge:
         k_pos = ki * block_k + jax.lax.broadcasted_iota(
@@ -161,15 +166,20 @@ def _block_mask(edge: bool, qi, ki, block_q, block_k, t_k_real, causal,
     if mask_row is not None:
         keep = mask_row > 0
         mask = keep if mask is None else jnp.logical_and(mask, keep)
+    if selected is not None:
+        keep = selected.astype(jnp.int32) != 0
+        mask = keep if mask is None else jnp.logical_and(mask, keep)
     return mask
 
 
 def _fwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, *rest,
                 scale: float, causal: bool, block_q: int, block_k: int,
                 t_k_real: int, has_edge: bool, has_interior: bool,
-                with_lse: bool, with_mask: bool):
+                with_lse: bool, with_mask: bool, with_select: bool = False):
     if with_mask:
         mask_ref, rest = rest[0], rest[1:]
+    if with_select:
+        select_ref, rest = rest[0], rest[1:]
     o_ref, rest = rest[0], rest[1:]
     if with_lse:
         lse_ref, acc, m_scr, l_scr = rest
@@ -190,7 +200,8 @@ def _fwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, *rest,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (bq, bk)
         mask = _block_mask(edge, qi, ki, block_q, block_k, t_k_real, causal,
-                           mask_ref[...] if with_mask else None)
+                           mask_ref[...] if with_mask else None,
+                           selected=select_ref[...] if with_select else None)
         if mask is not None:
             s = jnp.where(mask, s, _NEG_INF)
 
@@ -199,7 +210,7 @@ def _fwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, *rest,
         m_new = jnp.maximum(m_prev, m_cur)              # (bq, 128)
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new[:, :1])                   # (bq, bk) f32
-        if with_mask:
+        if with_mask or with_select:
             # a FULLY-masked row has s = m_new = NEG_INF everywhere, so
             # the subtraction above degenerates to exp(0)=1; zero it
             # (l stays 0 -> output 0, matching reference_attention)
@@ -303,6 +314,18 @@ def _spec_family(block_q, block_k, dk, dv, h):
     )
 
 
+def _select_operand(select, block_q, block_k, h):
+    """A (B, T_q, T_k) int8 selection, one entry a (query, key) and
+    shared by the ``h`` heads, padded to whole blocks with zeros (a
+    padded pair attends nothing), and the spec that hands a kernel the
+    block of its scheduled pair."""
+    spec = pl.BlockSpec(
+        (None, block_q, block_k),
+        lambda bh, s, qi, ki, flags, h=h: (bh // h, qi[s], ki[s]))
+    return spec, _pad_t(_pad_t(select.astype(jnp.int8), block_q, axis=1),
+                        block_k, axis=2)
+
+
 def _scheduled_call(kernel, schedule, bh, in_specs, out_specs, out_shape,
                     scratch_shapes, interpret, operands):
     """One kernel over grid (bh, pairs) of ``block_schedule(*schedule)``,
@@ -336,14 +359,16 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool = False,
                                block_q=None, block_k=None,
                                interpret: bool = False,
                                return_lse: bool = False,
-                               kv_mask=None, scale=None):
+                               kv_mask=None, scale=None, select=None):
     """Forward Pallas flash attention. q/k: (B, H, T, Dk), v and the
     output (B, H, T, Dv); ``scale`` multiplies the scores, ``1 /
     sqrt(Dk)`` where it is None.
 
     With ``return_lse`` also returns the (B, H, T) logsumexp residual
     the backward kernels consume. ``kv_mask`` optional (B, T_k) of
-    valid key positions; fully-masked query rows yield 0."""
+    valid key positions, ``select`` optional (B, T_q, T_k) int8 of the
+    keys each query attends (nonzero), shared by the heads;
+    fully-masked query rows yield 0."""
     b, h, t_q, dk = q.shape
     t_k, dv = k.shape[2], v.shape[3]
     block_q, block_k = _blocks(block_q, block_k, t_q, t_k, max(dk, dv),
@@ -354,7 +379,8 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool = False,
     kernel = functools.partial(
         _fwd_kernel, scale=_scale(scale, dk), causal=causal,
         block_q=block_q, block_k=block_k, t_k_real=t_k,
-        with_lse=return_lse, with_mask=kv_mask is not None)
+        with_lse=return_lse, with_mask=kv_mask is not None,
+        with_select=select is not None)
     q_spec, k_spec, v_spec, o_spec, qrow_spec, krow_spec = _spec_family(
         block_q, block_k, dk, dv, h)
     in_specs = [q_spec, k_spec, v_spec]
@@ -363,6 +389,10 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool = False,
         # (B, T_k) f32 0/1; the grid's bh axis maps back to batch bh//h
         in_specs.append(krow_spec)
         operands.append(_mask_rows(kv_mask, block_k))
+    if select is not None:
+        for one, into in zip(_select_operand(select, block_q, block_k, h),
+                             (in_specs, operands)):
+            into.append(one)
     o_shape = jax.ShapeDtypeStruct((*qp.shape[:2], dv), q.dtype)
     if return_lse:
         out_specs = (o_spec, qrow_spec)
@@ -399,15 +429,25 @@ def _masked_p(q, k, lse, mask, *, scale):
     return jnp.where(lse > _NEG_INF / 2, p, 0.0)
 
 
+def _mask_operands(rest, with_mask: bool, with_select: bool):
+    """The ``kv_mask`` row's and the ``select`` block's refs (None where
+    the call has none) off the front of a backward kernel's trailing
+    operands, and what is left."""
+    mask_ref = select_ref = None
+    if with_mask:
+        mask_ref, rest = rest[0], rest[1:]
+    if with_select:
+        select_ref, rest = rest[0], rest[1:]
+    return mask_ref, select_ref, rest
+
+
 def _bwd_dq_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, *rest, scale: float, causal: bool,
                    block_q: int, block_k: int, t_q_real: int,
                    t_k_real: int, has_edge: bool, has_interior: bool,
-                   with_mask: bool):
-    if with_mask:
-        mask_ref, dq_ref, dq_acc = rest
-    else:
-        mask_ref, (dq_ref, dq_acc) = None, rest
+                   with_mask: bool, with_select: bool = False):
+    mask_ref, select_ref, (dq_ref, dq_acc) = _mask_operands(
+        rest, with_mask, with_select)
     qi, ki, flags = _pair(qi_ref, ki_ref, flags_ref)
 
     @pl.when(flags & FIRST != 0)
@@ -421,7 +461,8 @@ def _bwd_dq_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
         delta = jnp.transpose(delta_ref[...])
         mask = _block_mask(edge, qi, ki, block_q, block_k, t_k_real, causal,
                            None if mask_ref is None else mask_ref[...],
-                           t_q_real)
+                           t_q_real,
+                           None if select_ref is None else select_ref[...])
         p = _masked_p(q_ref[0], k_ref[0], lse, mask, scale=scale)
         do = do_ref[0]
         dp = jax.lax.dot_general(                       # dO @ V^T
@@ -443,11 +484,10 @@ def _bwd_dkv_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
                     lse_ref, delta_ref, *rest, scale: float,
                     causal: bool, block_q: int, block_k: int,
                     t_q_real: int, t_k_real: int, has_edge: bool,
-                    has_interior: bool, with_mask: bool):
-    if with_mask:
-        mask_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
-    else:
-        mask_ref, (dk_ref, dv_ref, dk_acc, dv_acc) = None, rest
+                    has_interior: bool, with_mask: bool,
+                    with_select: bool = False):
+    mask_ref, select_ref, (dk_ref, dv_ref, dk_acc, dv_acc) = _mask_operands(
+        rest, with_mask, with_select)
     qi, ki, flags = _pair(qi_ref, ki_ref, flags_ref)
 
     @pl.when(flags & FIRST != 0)
@@ -461,7 +501,8 @@ def _bwd_dkv_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
         delta = jnp.transpose(delta_ref[...])
         mask = _block_mask(edge, qi, ki, block_q, block_k, t_k_real, causal,
                            None if mask_ref is None else mask_ref[...],
-                           t_q_real)
+                           t_q_real,
+                           None if select_ref is None else select_ref[...])
         p = _masked_p(q, k_ref[0], lse, mask, scale=scale)
         do = do_ref[0]
         dv_acc[...] += jax.lax.dot_general(             # P^T @ dO
@@ -486,9 +527,10 @@ def _bwd_dkv_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
 def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
                                block_q=None, block_k=None,
                                interpret: bool = False, kv_mask=None,
-                               scale=None):
+                               scale=None, select=None):
     """Pallas flash-attention backward: (dq, dk, dv), the first two at
-    the key width, the third at the value width.
+    the key width, the third at the value width; ``kv_mask`` and
+    ``select`` as the forward call had them.
 
     Same schedule as the forward, run twice: dq revisits its q-block
     accumulator along a row of pairs; dk/dv revisit their k-block
@@ -517,13 +559,18 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
     if kv_mask is not None:
         in_specs.append(mask_spec)
         operands.append(_mask_rows(kv_mask, block_k))
+    if select is not None:
+        for one, into in zip(_select_operand(select, block_q, block_k, h),
+                             (in_specs, operands)):
+            into.append(one)
 
     def call(kernel, by_key, out_specs, out_shape, scratch_shapes):
         return _scheduled_call(
             functools.partial(
                 kernel, scale=_scale(scale, dk), causal=causal,
                 block_q=block_q, block_k=block_k, t_q_real=t_q,
-                t_k_real=t_k, with_mask=kv_mask is not None),
+                t_k_real=t_k, with_mask=kv_mask is not None,
+                with_select=select is not None),
             (t_q, t_k, block_q, block_k, causal, by_key), b * h, in_specs,
             out_specs, out_shape, scratch_shapes, interpret, operands)
 
@@ -622,3 +669,48 @@ def flash_attention_masked(q, k, v, kv_mask=None, causal: bool = False,
     return _flash_attention_masked(
         q, k, v, kv_mask.astype(jnp.float32), causal, block_q, block_k,
         interpret, scale)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _flash_attention_selected(q, k, v, select, block_q, block_k, interpret,
+                              scale):
+    return flash_attention_fwd_pallas(
+        q, k, v, causal=True, block_q=block_q, block_k=block_k,
+        interpret=interpret, return_lse=True, scale=scale, select=select)
+
+
+def _fas_fwd(q, k, v, select, block_q, block_k, interpret, scale):
+    out, lse = _named_residuals(*flash_attention_fwd_pallas(
+        q, k, v, causal=True, block_q=block_q, block_k=block_k,
+        interpret=interpret, return_lse=True, scale=scale, select=select))
+    return (out, lse), (q, k, v, out, lse, select)
+
+
+def _fas_bwd(block_q, block_k, interpret, scale, res, g):
+    q, k, v, out, lse, select = res
+    dq, dk, dv = flash_attention_bwd_pallas(
+        q, k, v, out, lse, g[0], causal=True, block_q=block_q,
+        block_k=block_k, interpret=interpret, scale=scale, select=select)
+    return dq, dk, dv, None
+
+
+_flash_attention_selected.defvjp(_fas_fwd, _fas_bwd)
+
+
+def flash_attention_selected(q, k, v, select, block_q=None, block_k=None,
+                             interpret="auto", scale=None):
+    """Causal attention over the keys ``select`` names: (B, T, T) int8,
+    nonzero where query ``t`` attends key ``s``, one selection for all
+    the heads; an entry above the diagonal attends nothing whatever it
+    holds. The same three kernels over the same causal schedule as
+    :func:`flash_attention`, each handed the selection's block of its
+    scheduled pair beside the positional mask: a pair is walked whether
+    or not any of its keys is selected. Returns ``(out, lse)``: the
+    output and the float32 logsumexp of every query's selected scores
+    (B, H, T), for a caller that rebuilds the probabilities;
+    ``lse`` takes no cotangent (read it under ``stop_gradient``). Both
+    carry ``RESIDUAL_NAMES``; the selection has no gradient."""
+    if interpret == "auto":
+        interpret = jax.default_backend() != "tpu"
+    return _flash_attention_selected(q, k, v, select.astype(jnp.int8),
+                                     block_q, block_k, interpret, scale)

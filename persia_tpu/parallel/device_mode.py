@@ -26,7 +26,8 @@ A tower may describe its own build (optional; the DLRM-family towers do
 not): a method ``step_tags() -> {name: int or sequence}``. What it
 returns becomes tags of the span ``trainer/build_device_step`` as given,
 and gauges ``device_mode_<name>`` (an int as it is, a sequence by its
-length). The hybrid sequence tower gives ``tower_layers`` (its pattern),
+length, a word that names a choice as a gauge of 1 labelled
+``choice=<word>``). The hybrid sequence tower gives ``tower_layers`` (its pattern),
 ``experts_held``, ``experts_routed``, ``expert_matrices`` (2 for
 square-relu experts, 3 for silu-gated ones), ``mtp_depth``,
 ``residual_streams``, ``sinkhorn_iters`` (0 at one stream),
@@ -40,8 +41,13 @@ streams, 0 over one), ``kda_layers`` (its delta-rule layers, with their
 ``ops/kda_scan``'s kernels and whose output the ``nn.remat`` policy
 keeps: all of them; all four 0 without such a layer),
 ``attention_positions`` (1 where latent
-attention rotates a part of its queries and keys, 0 where attention
-carries no position and leaves it to the other layers) and, where its
+attention rotates a part of its queries and keys or selected attention
+rotates them whole, 0 where attention
+carries no position and leaves it to the other layers),
+``selected_layers`` (the layers whose attention is over the keys a
+learned indexer selects, with ``select_topk``, the keys a query, and
+``index_heads``; all three 0 without such a layer), ``expert_scoring``
+(the router's rule, ``sigmoid`` or ``softmax``: a word) and, where its
 pattern has attention, ``key_width`` and ``value_width``.
 
 The step keeps its own account (:class:`DeviceStep`, which
@@ -328,10 +334,18 @@ def make_device_mode_trainer(
         tower = getattr(model, "tower", None)
         described = tower.step_tags() if hasattr(tower, "step_tags") else {}
         built.tag(**described)
+        # a word that names a choice (every str but the pattern, which
+        # is a sequence of layers) is a gauge of 1 labelled with it
+        chosen = {name: v for name, v in described.items()
+                  if isinstance(v, str) and name != "tower_layers"}
         counts.update({name: v if isinstance(v, int) else len(v)
-                       for name, v in described.items()})
+                       for name, v in described.items()
+                       if name not in chosen})
         for name, n in counts.items():
             metrics.default_registry().gauge(f"device_mode_{name}").set(n)
+        for name, word in chosen.items():
+            metrics.default_registry().gauge(
+                f"device_mode_{name}", labels={"choice": word}).set(1)
 
     def account(step):
         return DeviceStep(jax.jit(step, donate_argnums=(0, 1)), mesh,

@@ -66,6 +66,19 @@ def next_items_cross_entropy(logits, target: jnp.ndarray,
             + ahead_weight * next_item_cross_entropy(ahead, shifted))
 
 
+def next_item_cross_entropy_indexed(outputs, target: jnp.ndarray,
+                                    index_loss_weight: float = 1.0
+                                    ) -> jnp.ndarray:
+    """The loss of a tower whose attention is over keys an indexer
+    selects: ``outputs`` is the pair (logits, the indexer's alignment
+    loss summed over the tower's layers); the cross entropy as above
+    plus ``index_loss_weight`` times that term, which is the only one
+    the indexer's parameters learn from."""
+    logits, index_loss = outputs
+    return (next_item_cross_entropy(logits, target)
+            + index_loss_weight * index_loss)
+
+
 def _rebuild_embedding_inputs(
     emb_values: Sequence[jnp.ndarray], emb_indices: Sequence[Optional[jnp.ndarray]]
 ) -> List[Any]:

@@ -457,7 +457,9 @@ def test_the_configuration_states_the_parameters_it_runs():
                                  "hyper_fused_sublayers": 0,
                                  "kda_layers": 0, "kda_fused_layers": 0,
                                  "kda_heads": 0, "kda_chunk": 0,
-                                 "attention_positions": 0}
+                                 "attention_positions": 0,
+                                 "selected_layers": 0, "select_topk": 0,
+                                 "index_heads": 0, "expert_scoring": "sigmoid"}
 
 
 def test_kernels_roofline_is_the_algorithm_s_need_at_the_rows_routed():

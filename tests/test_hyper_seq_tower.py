@@ -500,7 +500,9 @@ def test_the_configuration_states_the_parameters_it_runs():
         "residual_streams": 4, "sinkhorn_iters": 20, "key_width": 192,
         "value_width": 128, "attention_residuals_kept": 5,
         "hyper_fused_sublayers": 10, "kda_layers": 0, "kda_fused_layers": 0,
-        "kda_heads": 0, "kda_chunk": 0, "attention_positions": 1}
+        "kda_heads": 0, "kda_chunk": 0, "attention_positions": 1,
+        "selected_layers": 0, "select_topk": 0,
+        "index_heads": 0, "expert_scoring": "sigmoid"}
     assert tower.rope_scaling == hybrid_seq.YarnRule(64, 4096, 32, 1, 1, 1)
     model = placement.build_model(sz)
     shapes = jax.eval_shape(

@@ -460,7 +460,9 @@ def test_the_configuration_states_the_parameters_it_runs():
                                  "hyper_fused_sublayers": 0,
                                  "kda_layers": 0, "kda_fused_layers": 0,
                                  "kda_heads": 0, "kda_chunk": 0,
-                                 "attention_positions": 1}
+                                 "attention_positions": 1,
+                                 "selected_layers": 0, "select_topk": 0,
+                                 "index_heads": 0, "expert_scoring": "sigmoid"}
     model = placement.build_model(sz)
     shapes = jax.eval_shape(
         lambda: model.init(jax.random.key(0), [],

@@ -19,6 +19,13 @@ tower's ``nn.remat``; so do the two kernels alone at the shape the
 delta-rule cell of the benchmark runs a layer (1 x 8192 x 32 x 128,
 chunk 64).
 
+The ``S`` layer (attention over the keys a learned indexer selects:
+the flash kernels handed an int8 selection block a scheduled pair, the
+indexer plain XLA by tiles) goes through the same two guards at the
+published widths (hidden 2048, 32 / 4 heads of 128, 16 index heads of
+64, 2048 keys a query), and so does ``flash_attention_selected`` alone
+at the selected-attention cell's shape (1 x 32 x 8192 x 128).
+
 Neither replaces the compiled-and-compared check on the chip
 (``chip_smoke.py`` kernel phase, ``test_compiled_on_tpu``): values only
 come from a run.
@@ -191,6 +198,111 @@ def test_the_delta_rule_kernels_cross_lower_at_the_cell_s_shape(grad):
     assert text.count("tpu_custom_call") == 1 + grad
 
 
+def _selected_layer(t):
+    """The gradient of an ``S`` layer of the hybrid sequence tower at the
+    published widths over ``t`` positions, its output and its alignment
+    loss both, under the tower's ``nn.remat`` and its policy, and its
+    abstract arguments."""
+    from flax import linen as nn
+
+    from persia_tpu.models import hybrid_seq
+    from persia_tpu.ops.flash_attention import RESIDUAL_NAMES
+
+    def layer(of=hybrid_seq._Layer):
+        return of(hybrid_seq.SelectedAttention(parent=None),
+                  "selected_attention", 1e-6, jnp.bfloat16)
+
+    h = jax.ShapeDtypeStruct((1, t, 2048), jnp.bfloat16)
+    params = jax.eval_shape(lambda: layer().init(
+        jax.random.key(0), jnp.zeros((1, 64, 2048), jnp.bfloat16)))
+    remat = nn.remat(
+        hybrid_seq._Layer,
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *RESIDUAL_NAMES, *hybrid_seq.SELECT_RESIDUAL_NAMES))
+
+    def grad(params, h):
+        def scalar(p, h):
+            out, loss = layer(remat).apply(p, h)
+            return jnp.sum(out.astype(jnp.float32)) + loss
+        return jax.grad(scalar, argnums=(0, 1))(params, h)
+
+    return grad, params, h
+
+
+def _selected_kernels(grad, sharding=None):
+    """``flash_attention_selected`` compiled (not interpreted), or the
+    gradient of its three inputs, at the selected-attention cell's shape
+    a layer, and its abstract arguments."""
+    from persia_tpu.ops.flash_attention import flash_attention_selected
+
+    def op(q, k, v, select):
+        return flash_attention_selected(q, k, v, select, interpret=False)
+
+    def gradient(q, k, v, select):
+        return jax.grad(lambda *xs: jnp.sum(
+            op(*xs, select)[0].astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+
+    wide = (1, 32, 8192, 128)
+    avals = [jax.ShapeDtypeStruct(wide, jnp.bfloat16, sharding=sharding)
+             for _ in range(3)]
+    avals.append(jax.ShapeDtypeStruct((1, 8192, 8192), jnp.int8,
+                                      sharding=sharding))
+    return (gradient if grad else op), avals
+
+
+def test_the_selected_attention_layer_cross_lowers_for_tpu(monkeypatch):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    grad, params, h = _selected_layer(1000)     # pads to whole blocks
+    exported = jax.export.export(jax.jit(grad), platforms=["tpu"])(params, h)
+    text = exported.mlir_module()
+    # the flash kernel's forward, kept across nn.remat, and its two
+    # backward calls; the indexer is XLA's loops over tiles of queries
+    assert text.count("tpu_custom_call") == 3
+    assert "stablehlo.while" in text
+
+
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "gradient"])
+def test_the_selected_kernels_cross_lower_at_the_cell_s_shape(grad):
+    fn, avals = _selected_kernels(grad)
+    text = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *avals).mlir_module()
+    assert text.count("tpu_custom_call") == 1 + 2 * grad
+
+
+def _aot_compile_selected() -> int:
+    """Subprocess body: the ``S`` layer's gradient, and the flash
+    kernels under a selection alone at the cell's shape, for a v5e, no
+    chip."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2")
+    sharding = SingleDeviceSharding(topo.devices[0])
+    jax.default_backend = lambda: "tpu"     # the mixer's op: compile
+    grad, params, h = _selected_layer(4096)
+    placed = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        (params, h))
+    failed = 0
+    for name, fn, avals in (
+            ("selected layer", grad, placed),
+            ("selected forward kernel", *_selected_kernels(False, sharding)),
+            ("selected gradient kernels", *_selected_kernels(True,
+                                                             sharding))):
+        try:
+            compiled = jax.jit(fn).lower(*avals).compile()
+            assert "tpu_custom_call" in compiled.as_text()
+            mem = compiled.memory_analysis()
+            print(f"COMPILED {name}: {mem.temp_size_in_bytes} temporary "
+                  f"bytes")
+        except Exception as e:  # noqa: BLE001 — each reported
+            failed += 1
+            print(f"REFUSED {name}: {str(e)[:600]}")
+    return failed
+
+
 def _aot_compile_delta() -> int:
     """Subprocess body: the ``K`` layer's gradient, and the recurrence's
     kernels alone at the cell's shape, for a v5e, no chip."""
@@ -276,6 +388,13 @@ def test_the_delta_rule_layer_aot_compiles_for_v5e():
         assert f"COMPILED kda {what}" in out, out[-4000:]
 
 
+def test_the_selected_attention_layer_aot_compiles_for_v5e():
+    out = _aot_subprocess("selected")
+    for what in ("layer", "forward kernel", "gradient kernels"):
+        assert f"COMPILED selected {what}" in out, out[-4000:]
+
+
 if __name__ == "__main__":
-    sys.exit(_aot_compile_delta() if sys.argv[1:] == ["delta"]
-             else _aot_compile_all())
+    sys.exit({("delta",): _aot_compile_delta,
+              ("selected",): _aot_compile_selected}.get(
+                  tuple(sys.argv[1:]), _aot_compile_all)())
