@@ -146,6 +146,7 @@ from persia_tpu.ops.kda_scan import (
 )
 from persia_tpu.ops.sparse_select import (
     RESIDUAL_NAMES as SELECT_RESIDUAL_NAMES,
+    query_block,
 )
 from persia_tpu.ops.ssm_scan import ssm_scan
 
@@ -1160,8 +1161,13 @@ class HybridSequenceTower(nn.Module):
                 # the layers whose attention is over the keys an indexer
                 # selects, how many a query, and the indexer's heads;
                 # each keeps its selection and its alignment loss's
-                # gradients across nn.remat
+                # gradients across nn.remat; those of them whose index
+                # scores run ops/sparse_select's kernels (a history the
+                # tile divides, of a length a block of keys divides): all
+                # where the indexer's tile admits a block, or none
                 "selected_layers": selected,
+                "index_fused_layers": selected * bool(
+                    query_block(self.index_tile)),
                 "select_topk": self.select_topk * bool(selected),
                 "index_heads": self.index_heads * bool(selected),
                 "expert_scoring": self.expert_scoring,

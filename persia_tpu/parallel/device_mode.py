@@ -45,8 +45,12 @@ attention rotates a part of its queries and keys or selected attention
 rotates them whole, 0 where attention
 carries no position and leaves it to the other layers),
 ``selected_layers`` (the layers whose attention is over the keys a
-learned indexer selects, with ``select_topk``, the keys a query, and
-``index_heads``; all three 0 without such a layer), ``expert_scoring``
+learned indexer selects, with ``select_topk``, the keys a query,
+``index_heads``, and ``index_fused_layers``, those of them whose
+indexer's tile admits the block of ``ops/sparse_select``'s kernels, so
+that over a history of whole tiles and whole key blocks their index
+scores and the scores' pullback run there: all or none; all four 0
+without such a layer), ``expert_scoring``
 (the router's rule, ``sigmoid`` or ``softmax``: a word) and, where its
 pattern has attention, ``key_width`` and ``value_width``.
 

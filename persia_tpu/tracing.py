@@ -691,7 +691,9 @@ def device_time_by_scope(ops, modules, table, depth=None) -> Dict:
     Returns, in seconds: ``steps`` (runs of that module, a cut one
     counted as the fraction it is), ``total_s`` (the sum of all self
     times, which is the union of the device's busy intervals),
-    ``unscoped_s`` (instructions without a scope), ``unmatched_s`` and
+    ``unscoped_s`` and ``unscoped`` ``[[instruction without its number,
+    s]]`` (instructions without a scope, by the compiler's name for
+    them), ``unmatched_s`` and
     ``unmatched`` ``[[instruction or program, s]]`` (events the table
     lacks: counted, never dropped), and ``scopes`` ``[[path, forward_s,
     backward_s]]``, longest first; the scopes, ``unscoped_s`` and
@@ -713,7 +715,8 @@ def device_time_by_scope(ops, modules, table, depth=None) -> Dict:
 
     by_scope: Dict[str, List[float]] = {}
     unmatched: Dict[str, float] = {}
-    unscoped = total = 0.0
+    unscoped: Dict[str, float] = {}
+    total = 0.0
     for (name, start, _), own in zip(ops, _self_times(ops)):
         total += own
         instruction = other_program(start) or _instruction_of(name)
@@ -723,7 +726,8 @@ def device_time_by_scope(ops, modules, table, depth=None) -> Dict:
             continue
         path, backward = found
         if not path:
-            unscoped += own
+            family = instruction.split(".")[0]
+            unscoped[family] = unscoped.get(family, 0.0) + own
             continue
         if depth:
             path = "/".join(path.split("/")[-depth:])
@@ -731,7 +735,9 @@ def device_time_by_scope(ops, modules, table, depth=None) -> Dict:
     return {
         "steps": steps,
         "total_s": total / 1e9,
-        "unscoped_s": unscoped / 1e9,
+        "unscoped_s": sum(unscoped.values()) / 1e9,
+        "unscoped": sorted(([k, v / 1e9] for k, v in unscoped.items()),
+                           key=lambda kv: -kv[1]),
         "unmatched_s": sum(unmatched.values()) / 1e9,
         "unmatched": sorted(([k, v / 1e9] for k, v in unmatched.items()),
                             key=lambda kv: -kv[1]),

@@ -193,6 +193,7 @@ def test_device_time_by_scope_by_hand():
     assert got["total_s"] == pytest.approx(80e-9)
     assert got["total_s"] == pytest.approx(_busy_ns(OPS) / 1e9)
     assert got["unscoped_s"] == pytest.approx(10e-9)
+    assert got["unscoped"] == [["copy", pytest.approx(10e-9)]]
     assert got["unmatched_s"] == pytest.approx(10e-9)
     assert got["unmatched"] == [["stray.9", pytest.approx(10e-9)]]
     assert got["scopes"] == [

@@ -458,7 +458,8 @@ def test_the_configuration_states_the_parameters_it_runs():
                                  "kda_layers": 0, "kda_fused_layers": 0,
                                  "kda_heads": 0, "kda_chunk": 0,
                                  "attention_positions": 0,
-                                 "selected_layers": 0, "select_topk": 0,
+                                 "selected_layers": 0,
+                                 "index_fused_layers": 0, "select_topk": 0,
                                  "index_heads": 0, "expert_scoring": "sigmoid"}
 
 

@@ -501,7 +501,7 @@ def test_the_configuration_states_the_parameters_it_runs():
         "value_width": 128, "attention_residuals_kept": 5,
         "hyper_fused_sublayers": 10, "kda_layers": 0, "kda_fused_layers": 0,
         "kda_heads": 0, "kda_chunk": 0, "attention_positions": 1,
-        "selected_layers": 0, "select_topk": 0,
+        "selected_layers": 0, "index_fused_layers": 0, "select_topk": 0,
         "index_heads": 0, "expert_scoring": "sigmoid"}
     assert tower.rope_scaling == hybrid_seq.YarnRule(64, 4096, 32, 1, 1, 1)
     model = placement.build_model(sz)

@@ -448,7 +448,7 @@ def test_the_configuration_states_the_parameters_it_runs():
         "value_width": 128, "attention_residuals_kept": 1,
         "hyper_fused_sublayers": 0, "kda_layers": 4, "kda_fused_layers": 4,
         "kda_heads": 32, "kda_chunk": 64, "attention_positions": 0,
-        "selected_layers": 0, "select_topk": 0,
+        "selected_layers": 0, "index_fused_layers": 0, "select_topk": 0,
         "index_heads": 0, "expert_scoring": "sigmoid"}
     model = placement.build_model(sz)
     shapes = jax.eval_shape(

@@ -197,8 +197,8 @@ def test_the_accepted_towers_keep_their_trees_and_their_programs(cell_name):
     model = other.build_model(sz)
     tags = model.tower.step_tags()
     assert tags["expert_scoring"] == "sigmoid"
-    assert (tags["selected_layers"], tags["select_topk"],
-            tags["index_heads"]) == (0, 0, 0)
+    assert (tags["selected_layers"], tags["index_fused_layers"],
+            tags["select_topk"], tags["index_heads"]) == (0, 0, 0, 0)
     loss = (other.loss_of(sz) if hasattr(other, "loss_of")
             else next_item_cross_entropy)
     mesh = make_mesh((1, 1), devices=jax.devices()[:1])
@@ -416,6 +416,11 @@ def test_the_build_is_tagged_and_the_step_carries_its_scopes(built):
     assert tags["tower_layers"] == "SESE"
     assert (tags["selected_layers"], tags["select_topk"],
             tags["index_heads"]) == (2, 8, 4)
+    # the indexer's tile of 16 admits the kernels' block of queries (a
+    # tile of 8 admits none: the plain form whatever the history)
+    assert tags["index_fused_layers"] == 2
+    assert placement.build_tower(dict(SZ, index_tile=8)).step_tags()[
+        "index_fused_layers"] == 0
     assert tags["expert_scoring"] == "softmax"
     assert tags["attention_positions"] == 1
     assert tags["key_width"] == 16 and tags["value_width"] == 16
@@ -423,7 +428,7 @@ def test_the_build_is_tagged_and_the_step_carries_its_scopes(built):
     assert tags["experts_routed"] == 16 and tags["expert_matrices"] == 3
     gauges = metrics.default_registry()
     for name, value in (("selected_layers", 2), ("select_topk", 8),
-                        ("index_heads", 4), ("attention_positions", 1),
+                        ("index_fused_layers", 2), ("index_heads", 4), ("attention_positions", 1),
                         ("tower_layers", 4), ("experts_held", 4),
                         ("key_width", 16), ("value_width", 16)):
         assert gauges.gauge(f"device_mode_{name}").value == value
@@ -488,7 +493,8 @@ def test_the_configuration_states_the_parameters_it_runs():
         "value_width": 128, "attention_residuals_kept": 6,
         "hyper_fused_sublayers": 0, "kda_layers": 0, "kda_fused_layers": 0,
         "kda_heads": 0, "kda_chunk": 0, "attention_positions": 1,
-        "selected_layers": 6, "select_topk": 2048, "index_heads": 16,
+        "selected_layers": 6, "index_fused_layers": 6, "select_topk": 2048,
+        "index_heads": 16,
         "expert_scoring": "softmax"}
     model = placement.build_model(sz)
     shapes = jax.eval_shape(
@@ -622,21 +628,34 @@ def test_the_readers_read_six_layers_of_each_kind():
         / 197e12)
     # the index scores' least time: three products of 16 x 64 a causal
     # pair, 1.05 ms a layer
-    assert costs.index_least_seconds(sz, 8192, 1, r.peaks) == \
-        pytest.approx(6 * 2 * 3 * 1024 * 8192 * 8193 / 2 / 197e12)
+    index_least = costs.index_least_seconds(sz, 8192, 1, r.peaks)
+    assert index_least == pytest.approx(
+        6 * 2 * 3 * 1024 * 8192 * 8193 / 2 / 197e12)
+    # over the kernels' group, and the same under a fusion's kind (the
+    # pullback's call, wrapped in a fusion named after it); a step whose
+    # index scores are no kernel (the parent's) has nothing to read
+    index = _reader("index_roofline.keye-vl-2.0-30b-a3b")
+    assert index.read(r) is None
+    r.trace = dict(r.trace, ops=r.trace["ops"] + [
+        ("index_scores", 0.1), ("index_scores:kCustom", 0.15),
+        ("index_scores_not", 9.0)])
+    assert index.read(r) == pytest.approx(100 * index_least * 10 / 0.25)
+    assert index.read(r) < 100 and index.read(_reading(trace=None)) is None
 
 
-def test_the_cell_is_in_the_manifest_with_its_three_readers():
+def test_the_cell_is_in_the_manifest_with_its_four_readers():
     man = manifest.Manifest(manifest.repo_root(BENCH_DIR))
     assert man.validate()
     entry, cell, config, _ = man.cell(CELL)
     assert (entry["chips"], entry["traffic"], cell["placement"]) == (
         1, "histories8k", "device_seq_sparse")
     names = {m["name"] for m in man.metrics_of(CELL, "per_layer")}
-    # the indexer is plain XLA: no trace group, no index_roofline
+    # the index scores are kernels with a trace group of their own
     assert {"mfu.keye-vl-2.0-30b-a3b", "flash_roofline.keye-vl-2.0-30b-a3b",
-            "grouped_roofline.keye-vl-2.0-30b-a3b"} <= names
-    assert not any(n.startswith("index_roofline") for n in names)
+            "grouped_roofline.keye-vl-2.0-30b-a3b",
+            "index_roofline.keye-vl-2.0-30b-a3b"} <= names
+    assert [m["workloads"] for m in man.metrics_of(CELL, "per_layer")
+            if m["name"].startswith("index_roofline")] == [[CELL]]
     assert set(cell["limits_why"]) >= set(cell["limits"])
     assert cell["sizes"] == man.cell(
         "kimi-linear-48b-a3b.device-histories8k")[1]["sizes"]

@@ -21,10 +21,14 @@ chunk 64).
 
 The ``S`` layer (attention over the keys a learned indexer selects:
 the flash kernels handed an int8 selection block a scheduled pair, the
-indexer plain XLA by tiles) goes through the same two guards at the
-published widths (hidden 2048, 32 / 4 heads of 128, 16 index heads of
-64, 2048 keys a query), and so does ``flash_attention_selected`` alone
-at the selected-attention cell's shape (1 x 32 x 8192 x 128).
+index scores and their pullback ``ops/sparse_select``'s two kernels
+where the history is whole tiles and whole key blocks, plain XLA by
+tiles elsewhere) goes through the same two guards at the published
+widths (hidden 2048, 32 / 4 heads of 128, 16 index heads of 64, 2048
+keys a query), and so do ``flash_attention_selected`` alone at the
+selected-attention cell's shape (1 x 32 x 8192 x 128) and the index
+scores' kernels at the cell's (16 x 64, a tile of 512 queries against
+8192 keys, bfloat16, the tile's position traced).
 
 Neither replaces the compiled-and-compared check on the chip
 (``chip_smoke.py`` kernel phase, ``test_compiled_on_tpu``): values only
@@ -251,14 +255,44 @@ def _selected_kernels(grad, sharding=None):
     return (gradient if grad else op), avals
 
 
-def test_the_selected_attention_layer_cross_lowers_for_tpu(monkeypatch):
+def _index_kernels(grad, sharding=None):
+    """``sparse_select.index_scores`` compiled (not interpreted) over a
+    tile at a traced position, or its pullback to all three inputs, at
+    the selected-attention cell's shape a tile, and its abstract
+    arguments."""
+    from persia_tpu.ops import sparse_select
+
+    def op(q_t, k_i, w_t, start):
+        return sparse_select.index_scores(q_t, k_i, w_t, start,
+                                          interpret=False)
+
+    def gradient(q_t, k_i, w_t, start, to_scores):
+        return jax.vjp(lambda *xs: op(*xs, start), q_t, k_i, w_t)[1](
+            to_scores)
+
+    avals = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+             for shape, dtype in (((1, 512, 16, 64), jnp.bfloat16),
+                                  ((1, 8192, 64), jnp.bfloat16),
+                                  ((1, 512, 16), jnp.float32),
+                                  ((), jnp.int32),
+                                  ((1, 512, 8192), jnp.float32))]
+    return (gradient, avals) if grad else (op, avals[:4])
+
+
+@pytest.mark.parametrize("t,calls", [(1000, 3), (1024, 6)],
+                         ids=["plain_indexer", "index_kernels"])
+def test_the_selected_attention_layer_cross_lowers_for_tpu(monkeypatch, t,
+                                                           calls):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    grad, params, h = _selected_layer(1000)     # pads to whole blocks
+    grad, params, h = _selected_layer(t)    # 1000 pads to whole blocks
     exported = jax.export.export(jax.jit(grad), platforms=["tpu"])(params, h)
     text = exported.mlir_module()
     # the flash kernel's forward, kept across nn.remat, and its two
-    # backward calls; the indexer is XLA's loops over tiles of queries
-    assert text.count("tpu_custom_call") == 3
+    # backward calls; over 1000 positions (tiles of 500) the indexer is
+    # XLA's loops over tiles of queries, over 1024 the loops' bodies hold
+    # the index scores' kernel where a tile selects and where the
+    # alignment loss reads them, and the pullback
+    assert text.count("tpu_custom_call") == calls
     assert "stablehlo.while" in text
 
 
@@ -270,10 +304,20 @@ def test_the_selected_kernels_cross_lower_at_the_cell_s_shape(grad):
     assert text.count("tpu_custom_call") == 1 + 2 * grad
 
 
+@pytest.mark.parametrize("grad", [False, True], ids=["forward", "gradient"])
+def test_the_index_kernels_cross_lower_at_the_cell_s_shape(grad):
+    fn, avals = _index_kernels(grad)
+    text = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *avals).mlir_module()
+    # one call either way: the pullback rebuilds the products itself and
+    # keeps nothing of the forward call, which goes unused here
+    assert text.count("tpu_custom_call") == 1
+
+
 def _aot_compile_selected() -> int:
     """Subprocess body: the ``S`` layer's gradient, and the flash
-    kernels under a selection alone at the cell's shape, for a v5e, no
-    chip."""
+    kernels under a selection and the index scores' kernels alone at the
+    cell's shape, for a v5e, no chip."""
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
@@ -290,7 +334,11 @@ def _aot_compile_selected() -> int:
             ("selected layer", grad, placed),
             ("selected forward kernel", *_selected_kernels(False, sharding)),
             ("selected gradient kernels", *_selected_kernels(True,
-                                                             sharding))):
+                                                             sharding)),
+            ("selected index forward kernel", *_index_kernels(False,
+                                                              sharding)),
+            ("selected index gradient kernels", *_index_kernels(True,
+                                                                sharding))):
         try:
             compiled = jax.jit(fn).lower(*avals).compile()
             assert "tpu_custom_call" in compiled.as_text()
@@ -390,7 +438,8 @@ def test_the_delta_rule_layer_aot_compiles_for_v5e():
 
 def test_the_selected_attention_layer_aot_compiles_for_v5e():
     out = _aot_subprocess("selected")
-    for what in ("layer", "forward kernel", "gradient kernels"):
+    for what in ("layer", "forward kernel", "gradient kernels",
+                 "index forward kernel", "index gradient kernels"):
         assert f"COMPILED selected {what}" in out, out[-4000:]
 
 
