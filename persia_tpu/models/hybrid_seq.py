@@ -146,7 +146,6 @@ from persia_tpu.ops.kda_scan import (
 )
 from persia_tpu.ops.sparse_select import (
     RESIDUAL_NAMES as SELECT_RESIDUAL_NAMES,
-    query_block,
 )
 from persia_tpu.ops.short_conv import short_conv, short_conv_plain
 from persia_tpu.ops.ssm_scan import ssm_scan
@@ -1120,60 +1119,6 @@ class HybridSequenceTower(nn.Module):
     SCOPES = {"M": "ssm_mixer", "E": "experts", "*": "attention",
               "L": "latent_attention", "D": "dense_ffn",
               "K": "kda_attention", "S": "selected_attention"}
-
-    def step_tags(self):
-        """What ``make_device_mode_trainer`` tags its build with."""
-        widths = ()     # of attention's keys and values, where there is any
-        if "L" in self.pattern:
-            widths = (self.latent_nope_dim + self.latent_rope_dim,
-                      self.latent_v_dim)
-        elif "*" in self.pattern or "S" in self.pattern:
-            widths = (self.attn_head_dim, self.attn_head_dim)
-        hyper = self.residual_streams > 1
-        # attention layers, the prediction module's among them: each
-        # keeps its kernel's out and lse across nn.remat
-        kept = sum(kind in "*LS" for kind in
-                   self.pattern + self.pattern[-2:] * self.mtp_depth)
-        kda = self.pattern.count("K")
-        selected = self.pattern.count("S")
-        return {"tower_layers": self.pattern,
-                "attention_residuals_kept": kept,
-                # the delta-rule layers, their heads and their chunk; the
-                # layers whose recurrence runs ops/kda_scan's kernels and
-                # keeps their output across nn.remat: all of them
-                "kda_layers": kda,
-                "kda_fused_layers": kda,
-                "kda_heads": self.kda_heads * bool(kda),
-                "kda_chunk": self.kda_chunk * bool(kda),
-                # whether attention itself carries position (rotary
-                # keys in `L`), or leaves it to the other layers
-                "attention_positions":
-                    int("L" in self.pattern and self.latent_positions
-                        or selected > 0),
-                # the layers whose attention is over the keys an indexer
-                # selects, how many a query, and the indexer's heads;
-                # each keeps its selection and its alignment loss's
-                # gradients across nn.remat; those of them whose index
-                # scores run ops/sparse_select's kernels (a history the
-                # tile divides, of a length a block of keys divides): all
-                # where the indexer's tile admits a block, or none
-                "selected_layers": selected,
-                "index_fused_layers": selected * bool(
-                    query_block(self.index_tile)),
-                "select_topk": self.select_topk * bool(selected),
-                "index_heads": self.index_heads * bool(selected),
-                "expert_scoring": self.expert_scoring,
-                # the sublayers whose hyper-connection runs
-                # ops/hyper_connection's kernels: all, or none
-                "hyper_fused_sublayers": len(self.pattern) * hyper,
-                "experts_held": tuple(self.experts_held),
-                "experts_routed": self.experts_routed,
-                "expert_matrices":
-                    1 + ACTIVATIONS[self.expert_activation].wider,
-                "mtp_depth": self.mtp_depth,
-                "residual_streams": self.residual_streams,
-                "sinkhorn_iters": self.sinkhorn_iters * hyper,
-                **dict(zip(("key_width", "value_width"), widths))}
 
     def _mixer(self, kind, out_scale):
         """Unbound, so that the layer it is handed to adopts it."""

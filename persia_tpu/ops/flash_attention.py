@@ -76,7 +76,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from persia_tpu import metrics
+from persia_tpu import metrics, ops
 
 _NEG_INF = -1e30  # large-finite: -inf NaNs the m-update on all-masked rows
 _LANES = 128
@@ -661,8 +661,7 @@ def flash_attention_masked(q, k, v, kv_mask=None, causal: bool = False,
     is zero). ``interpret="auto"`` compiles on TPU and falls back to
     the Pallas interpreter elsewhere (CPU tests). ``scale`` multiplies
     the scores: a Python number, ``1 / sqrt(key width)`` where None."""
-    if interpret == "auto":
-        interpret = jax.default_backend() != "tpu"
+    interpret = ops.interpret(interpret)
     if kv_mask is None:
         return flash_attention(q, k, v, causal, block_q, block_k, interpret,
                                scale)
@@ -710,7 +709,6 @@ def flash_attention_selected(q, k, v, select, block_q=None, block_k=None,
     (B, H, T), for a caller that rebuilds the probabilities;
     ``lse`` takes no cotangent (read it under ``stop_gradient``). Both
     carry ``RESIDUAL_NAMES``; the selection has no gradient."""
-    if interpret == "auto":
-        interpret = jax.default_backend() != "tpu"
     return _flash_attention_selected(q, k, v, select.astype(jnp.int8),
-                                     block_q, block_k, interpret, scale)
+                                     block_q, block_k,
+                                     ops.interpret(interpret), scale)

@@ -14,11 +14,12 @@ counts the trailing rows that belong to no held expert. No tile of theirs
 is visited and their output rows are zero.
 """
 
-import jax
 import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu.megablox import gmm as _gmm
 from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm as _gmm_plain
 from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm as _tgmm_plain
+
+from persia_tpu import ops
 
 
 # rows, contraction and output columns of a tile; never tuned on a chip
@@ -42,13 +43,12 @@ def grouped_matmul(lhs, rhs, group_sizes):
     ``group_sizes`` (held + 1,) int32, summing to m. Returns (m, n) in
     ``lhs``'s dtype, float32 accumulation inside. Compiled on a TPU, run
     by the Pallas interpreter elsewhere (the CPU tests)."""
-    interpret = jax.default_backend() != "tpu"
     m = lhs.shape[0]
     tiling, pad, group_sizes = _tiles(lhs, rhs, group_sizes)
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
     out = _gmm(lhs, rhs, group_sizes, lhs.dtype, tiling, None, None,
-               False, interpret)
+               False, ops.interpret())
     return out[:m] if pad else out
 
 
@@ -59,7 +59,7 @@ def grouped_matmul_pullback(lhs, rhs, group_sizes, grad):
     with the matrices transposed and the grouped outer product, at the
     forward's tiles. For a caller that writes its derivative out (a loop
     whose trip count is read on the device has none of JAX's making)."""
-    interpret = jax.default_backend() != "tpu"
+    interpret = ops.interpret()
     m = lhs.shape[0]
     tiling, pad, group_sizes = _tiles(lhs, rhs, group_sizes)
     if pad:

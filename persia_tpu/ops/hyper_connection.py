@@ -70,6 +70,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from persia_tpu import ops
+
 F32, BF16 = jnp.float32, jnp.bfloat16
 _LANES = 128
 
@@ -85,11 +87,6 @@ FEATURES = 896
 # not, for 5 % of their time (PERF.md §6 "PR 38")
 READ_VMEM_LIMIT = 48 * 1024 * 1024
 MIX_BWD_VMEM_LIMIT = 32 * 1024 * 1024
-
-
-def _auto(interpret):
-    return jax.default_backend() != "tpu" if interpret == "auto" \
-        else interpret
 
 
 def _round_up(x, to):
@@ -569,7 +566,7 @@ def read_out(x, phi, scale, bias, *, streams, eps, interpret="auto"):
     one pass)."""
     lead = x.shape[:-1]
     u, m, carry = _read_out(x.reshape(-1, x.shape[-1]), phi, scale, bias,
-                            streams, float(eps), _auto(interpret))
+                            streams, float(eps), ops.interpret(interpret))
     return (u.reshape(*lead, -1), m.reshape(*lead, -1),
             carry.reshape(x.shape))
 
@@ -580,5 +577,5 @@ def mix(carry, y, res, post, *, interpret="auto"):
     n = post.shape[-1]
     out = _mix(carry.reshape(-1, carry.shape[-1]),
                y.reshape(-1, y.shape[-1]), res.reshape(-1, n * n),
-               post.reshape(-1, n), _auto(interpret))
+               post.reshape(-1, n), ops.interpret(interpret))
     return out.reshape(carry.shape)

@@ -120,6 +120,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from persia_tpu import ops
+
 BLOCK = 16
 F32 = jnp.float32
 # chunks a grid step: what the kernels work at once (above); and the
@@ -482,11 +484,6 @@ def _backward_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref,
     db_ref[0, 0] = jnp.swapaxes(dbeta, 1, 2).reshape(chunks, chunk)
 
 
-def _auto(interpret):
-    return jax.default_backend() != "tpu" if interpret == "auto" \
-        else interpret
-
-
 def _steps(t, chunk):
     """Chunks a grid step, and the grid steps that cover ``t``."""
     per = min(CHUNKS_A_STEP, -(-t // chunk))
@@ -607,5 +604,5 @@ def kda_scan(q, k, v, g, beta, chunk: int = 64,
 
     o = _kda(flat(q), flat(k), flat(v), flat(g.astype(F32)),
              flat(beta.astype(F32)), chunk, jnp.dtype(compute_dtype),
-             _auto(interpret))
+             ops.interpret(interpret))
     return o[:, :t].reshape(bs, t, heads, -1)

@@ -59,6 +59,8 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from persia_tpu import ops
+
 F32 = jnp.float32
 L2_EPS = 1e-6       # under the root of a head's sum of squares
 LANES = 128
@@ -269,4 +271,4 @@ def short_conv(x, taps, head_dim=None, scale=1.0, out_dtype=jnp.bfloat16):
     if blocks is None:
         return short_conv_plain(x, taps, None, head_dim, scale, out_dtype)
     return _short_conv(x, taps, head_dim, float(scale), jnp.dtype(out_dtype),
-                       blocks, jax.default_backend() != "tpu")
+                       blocks, ops.interpret())

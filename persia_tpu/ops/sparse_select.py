@@ -52,6 +52,8 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from persia_tpu import ops
+
 F32 = jnp.float32
 
 # the selection, and the alignment loss's gradients to q_i, k_i and w:
@@ -289,11 +291,9 @@ def _kernel_operands(q_i, k_i, w, start, interpret):
     blocks = index_blocks(q_i.shape[1], k_i.shape[1])
     if blocks is None:
         return None
-    if interpret == "auto":
-        interpret = jax.default_backend() != "tpu"
     start = k_i.shape[1] if start is None else start
     return (w.astype(F32), jnp.asarray(start, jnp.int32).reshape(1), blocks,
-            interpret)
+            ops.interpret(interpret))
 
 
 def index_scores(q_i, k_i, w, start=None, interpret="auto"):

@@ -22,38 +22,6 @@ whole tree and ``optimizer.update`` over every table. The gauges
 ``device_mode_row_update_tables`` and ``device_mode_dense_update_tables``
 say which one a build took.
 
-A tower may describe its own build (optional; the DLRM-family towers do
-not): a method ``step_tags() -> {name: int or sequence}``. What it
-returns becomes tags of the span ``trainer/build_device_step`` as given,
-and gauges ``device_mode_<name>`` (an int as it is, a sequence by its
-length, a word that names a choice as a gauge of 1 labelled
-``choice=<word>``). The hybrid sequence tower gives ``tower_layers`` (its pattern),
-``experts_held``, ``experts_routed``, ``expert_matrices`` (2 for
-square-relu experts, 3 for silu-gated ones), ``mtp_depth``,
-``residual_streams``, ``sinkhorn_iters`` (0 at one stream),
-``attention_residuals_kept`` (the attention layers whose kernel's
-``out`` and ``lse`` its ``nn.remat`` policy keeps),
-``hyper_fused_sublayers`` (the sublayers whose hyper-connection runs
-``ops/hyper_connection``'s kernels: all of them over several residual
-streams, 0 over one), ``kda_layers`` (its delta-rule layers, with their
-``kda_heads`` and the ``kda_chunk`` their recurrence runs in, and
-``kda_fused_layers``, those of them whose recurrence runs
-``ops/kda_scan``'s kernels and whose output the ``nn.remat`` policy
-keeps: all of them; all four 0 without such a layer),
-``attention_positions`` (1 where latent
-attention rotates a part of its queries and keys or selected attention
-rotates them whole, 0 where attention
-carries no position and leaves it to the other layers),
-``selected_layers`` (the layers whose attention is over the keys a
-learned indexer selects, with ``select_topk``, the keys a query,
-``index_heads``, and ``index_fused_layers``, those of them whose
-indexer's tile admits the block of ``ops/sparse_select``'s kernels, so
-that over a history of whole tiles and whole key blocks their index
-scores and the scores' pullback run there: all or none; all four 0
-without such a layer), ``expert_scoring``
-(the router's rule, ``sigmoid`` or ``softmax``: a word) and, where its
-pattern has attention, ``key_width`` and ``value_width``.
-
 The step keeps its own account (:class:`DeviceStep`, which
 ``make_device_mode_trainer`` returns in the jitted function's place): a
 ``trainer/dispatch`` span a call with the device buffers that go in and
@@ -99,9 +67,8 @@ class DeviceModeModel(nn.Module):
     """Dense tower + device embedding tables as one module.
 
     ``slot_specs``: sequence of (name, vocab_size, dim) for the hashed
-    HBM tables; ``tower``: a model-zoo module instance, which may have
-    ``step_tags()`` (this module's docstring says what for). ``rows``,
-    if given, mirrors the tables' place in the parameters
+    HBM tables; ``tower``: a model-zoo module instance. ``rows``, if
+    given, mirrors the tables' place in the parameters
     (:meth:`table_rows`) and holds their values already gathered.
     """
 
@@ -332,24 +299,8 @@ def make_device_mode_trainer(
         counts = {"row_update_tables": len(tables) if by_row else 0,
                   "dense_update_tables": 0 if by_row else len(tables)}
         built.tag(**counts)
-        # what the tower says of its own build, where it has step_tags()
-        # (optional: the module's docstring): tags as given, and as
-        # gauges the count of what a tag lists
-        tower = getattr(model, "tower", None)
-        described = tower.step_tags() if hasattr(tower, "step_tags") else {}
-        built.tag(**described)
-        # a word that names a choice (every str but the pattern, which
-        # is a sequence of layers) is a gauge of 1 labelled with it
-        chosen = {name: v for name, v in described.items()
-                  if isinstance(v, str) and name != "tower_layers"}
-        counts.update({name: v if isinstance(v, int) else len(v)
-                       for name, v in described.items()
-                       if name not in chosen})
         for name, n in counts.items():
             metrics.default_registry().gauge(f"device_mode_{name}").set(n)
-        for name, word in chosen.items():
-            metrics.default_registry().gauge(
-                f"device_mode_{name}", labels={"choice": word}).set(1)
 
     def account(step):
         return DeviceStep(jax.jit(step, donate_argnums=(0, 1)), mesh,

@@ -4,9 +4,11 @@ tower's ``nn.remat``: the two residuals carry names
 names, and the recomputed layer no longer runs the kernel's forward.
 Counted in the gradient's jaxpr, compared bit for bit with the default
 policy, and, outside any ``jax.checkpoint``, compared with the text the
-unnamed kernel lowers to. CPU, interpret mode, small widths."""
+unnamed kernel lowers to. And which ops' kernels each form of the
+tower engages at all. CPU, interpret mode, small widths."""
 
 import functools
+import os
 import re
 
 import jax
@@ -21,7 +23,12 @@ F32 = jnp.float32
 WIDTHS = dict(
     hidden=32, vocab=64, attn_heads=4, attn_kv_heads=2, attn_head_dim=16,
     latent_heads=2, latent_q_rank=16, latent_kv_rank=16, latent_nope_dim=16,
-    latent_rope_dim=8, latent_v_dim=16, dense_width=32, compute_dtype=F32)
+    latent_rope_dim=8, latent_v_dim=16, dense_width=32, ssm_heads=2,
+    ssm_head_dim=16, ssm_groups=1, ssm_state=16, chunk=16, experts_routed=8,
+    experts_held=(0, 1), experts_per_token=2, expert_width=16,
+    shared_width=16, kda_heads=1, kda_head_dim=128, kda_chunk=16,
+    index_heads=2, index_dim=16, index_rope_dim=8, select_topk=8,
+    compute_dtype=F32)
 # the three forms a tower's attention layer takes, with its attention
 # layers counted: grouped-query attention and latent attention under
 # `_Layer` (the latter with a prediction module, whose last two layers
@@ -36,12 +43,12 @@ EVERY_FORM = pytest.mark.parametrize("form", sorted(FORMS))
 KERNELS = ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel")
 
 
-def _tower_loss(form):
-    """A tower of ``form``, its parameters and a loss over them."""
-    how, layers = FORMS[form]
-    tower = hybrid_seq.HybridSequenceTower(**WIDTHS, **how)
-    rows = jnp.asarray(
-        np.random.default_rng(7).normal(size=(2, 40, WIDTHS["hidden"])), F32)
+def _tower_loss(how, length=40):
+    """The parameters of a tower of the fields ``how`` and a loss over
+    them, at histories of ``length``."""
+    tower = hybrid_seq.HybridSequenceTower(**{**WIDTHS, **how})
+    rows = jnp.asarray(np.random.default_rng(7).normal(
+        size=(2, length, WIDTHS["hidden"])), F32)
     inputs = [(rows, jnp.ones(rows.shape[:2], bool))]
     params = tower.init(jax.random.key(0), [], inputs)
 
@@ -49,7 +56,7 @@ def _tower_loss(form):
         out = tower.apply(params, [], inputs)
         return sum(jnp.mean(y ** 2) for y in jax.tree_util.tree_leaves(out))
 
-    return tower, params, loss, layers
+    return params, loss
 
 
 def _keep_nothing(monkeypatch):
@@ -60,13 +67,17 @@ def _keep_nothing(monkeypatch):
 
 
 def _pallas_kernels(jaxpr, found=None):
-    """The kernel function's name of every ``pallas_call`` equation of
-    ``jaxpr``, through the bodies of ``remat``, ``custom_vjp``, ``scan``,
-    ``while``, ``cond`` and ``pjit`` equations."""
+    """``(module, function)`` of the kernel of every ``pallas_call``
+    equation of ``jaxpr``, through the bodies of ``remat``,
+    ``custom_vjp``, ``scan``, ``while``, ``cond`` and ``pjit`` equations:
+    ``kda_scan`` and ``short_conv`` both name a ``_forward_kernel``."""
     found = [] if found is None else found
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
-            found.append(eqn.params["jaxpr"].debug_info.func_name)
+            info = eqn.params["jaxpr"].debug_info
+            path = info.func_src_info.rsplit(" at ", 1)[1].rsplit(":", 1)[0]
+            found.append((os.path.splitext(os.path.basename(path))[0],
+                          info.func_name))
             continue
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else [value]:
@@ -78,7 +89,7 @@ def _pallas_kernels(jaxpr, found=None):
 
 def _flash_calls(loss, params):
     kernels = _pallas_kernels(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
-    return [kernels.count(name) for name in KERNELS]
+    return [kernels.count(("flash_attention", name)) for name in KERNELS]
 
 
 @EVERY_FORM
@@ -89,8 +100,8 @@ def test_the_gradient_s_kernel_calls_an_attention_layer(form, forwards,
     recomputed layer ran the forward a second time."""
     if forwards == 2:
         _keep_nothing(monkeypatch)
-    tower, params, loss, layers = _tower_loss(form)
-    assert tower.step_tags()["attention_residuals_kept"] == layers
+    how, layers = FORMS[form]
+    params, loss = _tower_loss(how)
     assert _flash_calls(loss, params) == [forwards * layers, layers, layers]
 
 
@@ -102,10 +113,11 @@ def test_loss_and_gradients_are_the_default_policy_s_bit_for_bit(
     ``out`` is the array the recomputation would have made. Inside one
     program XLA:CPU fuses an interpreted kernel's body with what stands
     around it, differently in the two programs (1e-9 of a gradient)."""
-    _, params, loss, _ = _tower_loss(form)
+    how, _ = FORMS[form]
+    params, loss = _tower_loss(how)
     kept = jax.value_and_grad(loss)(params)
     _keep_nothing(monkeypatch)
-    _, params, loss, _ = _tower_loss(form)
+    params, loss = _tower_loss(how)
     rebuilt = jax.value_and_grad(loss)(params)
     flat = jax.tree_util.tree_flatten_with_path
     for (path, a), (_, b) in zip(flat(kept)[0], flat(rebuilt)[0]):
@@ -113,9 +125,36 @@ def test_loss_and_gradients_are_the_default_policy_s_bit_for_bit(
                                       err_msg=jax.tree_util.keystr(path))
 
 
-def test_a_tower_without_attention_keeps_nothing():
-    tower = hybrid_seq.HybridSequenceTower(**WIDTHS, pattern="MEDE")
-    assert tower.step_tags()["attention_residuals_kept"] == 0
+# --- which ops' kernels a form engages ----------------------------------------
+
+# the modules whose kernels the gradient calls, over histories of 128,
+# which a block of every op divides: each op decides from its shapes
+# whether its kernels take them (`gmm` is the experts' grouped product,
+# JAX's megablox; the `M` layer's scan and convolution have none)
+ENGAGES = {
+    "grouped_query": (dict(pattern="*D"), {"flash_attention"}),
+    "state_space_experts": (dict(pattern="MEDE"), {"gmm"}),
+    "delta_rule": (dict(pattern="KE"), {"kda_scan", "short_conv", "gmm"}),
+    # heads of 32 are no block of the convolutions': their plain form
+    "delta_rule_narrow": (dict(pattern="KE", kda_head_dim=32),
+                          {"kda_scan", "gmm"}),
+    "selected": (dict(pattern="SE", index_tile=16),
+                 {"sparse_select", "flash_attention", "gmm"}),
+    # a tile of 8 admits no block of queries: the index scores' plain form
+    "selected_tile_8": (dict(pattern="SE", index_tile=8),
+                        {"flash_attention", "gmm"}),
+    "latent_hyper": (dict(pattern="LD", residual_streams=4,
+                          sinkhorn_iters=3),
+                     {"hyper_connection", "flash_attention"}),
+}
+
+
+@pytest.mark.parametrize("form", sorted(ENGAGES))
+def test_the_gradient_calls_the_kernels_of_the_ops_the_form_engages(form):
+    how, modules = ENGAGES[form]
+    params, loss = _tower_loss(how, length=128)
+    kernels = _pallas_kernels(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+    assert {module for module, _ in kernels} == modules
 
 
 # --- outside a jax.checkpoint the names are the identity ----------------------

@@ -380,18 +380,10 @@ def test_three_trainer_steps_match_the_reference(built):
     assert check.compare(still, theirs)[0]["change_gap"] == 1.0
 
 
-def test_the_build_is_tagged_and_the_step_carries_its_scopes(built):
-    tags = built["span"].tags
-    assert tags["tower_layers"] == "ME*E"
-    assert tags["experts_held"] == (0, 1, 2, 3)
-    assert tags["experts_routed"] == 16
-    assert tags["dense_update_tables"] == 1      # Adam: the dense step
-    assert tags["attention_residuals_kept"] == 1     # the one `*` layer
+def test_the_step_carries_its_scopes(built):
+    assert built["span"].tags["dense_update_tables"] == 1   # Adam: dense
     gauges = metrics.default_registry()
-    for name, value in (("tower_layers", 4), ("experts_held", 4),
-                        ("experts_routed", 16), ("row_update_tables", 0),
-                        ("dense_update_tables", 1),
-                        ("attention_residuals_kept", 1)):
+    for name, value in (("row_update_tables", 0), ("dense_update_tables", 1)):
         assert gauges.gauge(f"device_mode_{name}").value == value
     ids, label = _feed(*_batches(1)[0])
     with built["mesh"]:
@@ -447,21 +439,6 @@ def test_the_configuration_states_the_parameters_it_runs():
     assert sz["experts_held"] == list(range(8)) and sz["vocab"] == 16384
     assert set(config["reduced"]) == {"num_hidden_layers",
                                       "n_routed_experts", "vocab_size"}
-    tower = device_seq.build_tower(sz)
-    assert tower.step_tags() == {"tower_layers": "MEMEM*EME",
-                                 "experts_held": tuple(range(8)),
-                                 "experts_routed": 128, "expert_matrices": 2,
-                                 "mtp_depth": 0, "residual_streams": 1,
-                                 "sinkhorn_iters": 0, "key_width": 128,
-                                 "value_width": 128,
-                                 "attention_residuals_kept": 1,
-                                 "hyper_fused_sublayers": 0,
-                                 "kda_layers": 0, "kda_fused_layers": 0,
-                                 "kda_heads": 0, "kda_chunk": 0,
-                                 "attention_positions": 0,
-                                 "selected_layers": 0,
-                                 "index_fused_layers": 0, "select_topk": 0,
-                                 "index_heads": 0, "expert_scoring": "sigmoid"}
 
 
 def test_kernels_roofline_is_the_algorithm_s_need_at_the_rows_routed():

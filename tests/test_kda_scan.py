@@ -227,8 +227,8 @@ def test_a_delta_rule_layer_s_gradient_runs_the_forward_once(forwards):
     params = layer.init(jax.random.key(1), h)
     kernels = _pallas_kernels(jax.make_jaxpr(jax.value_and_grad(
         lambda p: jnp.sum(layer.apply(p, h) ** 2)))(params).jaxpr)
-    assert kernels.count("_forward_kernel") == forwards
-    assert kernels.count("_backward_kernel") == 1
+    assert kernels.count(("kda_scan", "_forward_kernel")) == forwards
+    assert kernels.count(("kda_scan", "_backward_kernel")) == 1
     assert len(kernels) == forwards + 1
 
 

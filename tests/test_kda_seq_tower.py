@@ -215,7 +215,6 @@ def test_the_accepted_latent_towers_lower_to_the_parent_s_program(cell_name):
     sz = other.weights.sizes_of(config, cell["rehearsal"]["tower"])
     model = other.build_model(sz)
     assert model.tower.latent_positions and model.tower.latent_q_rank
-    assert model.tower.step_tags()["attention_positions"] == 1
     loss = (other.loss_of(sz) if hasattr(other, "loss_of")
             else next_item_cross_entropy)
     mesh = make_mesh((1, 1), devices=jax.devices()[:1])
@@ -371,23 +370,10 @@ def test_three_trainer_steps_match_the_reference(built):
     assert numbers["change_gap"] < 5e-2, (numbers, where)
 
 
-def test_the_build_is_tagged_and_the_step_carries_its_scopes(built):
-    tags = built["span"].tags
-    assert tags["tower_layers"] == "KDKEKELEKE"
-    assert (tags["kda_layers"], tags["kda_heads"], tags["kda_chunk"]) == (
-        4, 2, 32)
-    assert tags["kda_fused_layers"] == 4     # every `K` layer's kernels
-    assert tags["attention_positions"] == 0
-    assert tags["key_width"] == 24 and tags["value_width"] == 16
-    assert tags["attention_residuals_kept"] == 1     # the one `L` layer
-    assert tags["experts_routed"] == 16 and tags["expert_matrices"] == 3
-    gauges = metrics.default_registry()
-    for name, value in (("kda_layers", 4), ("kda_fused_layers", 4),
-                        ("kda_heads", 2),
-                        ("kda_chunk", 32), ("attention_positions", 0),
-                        ("tower_layers", 10), ("experts_held", 4),
-                        ("key_width", 24), ("value_width", 16)):
-        assert gauges.gauge(f"device_mode_{name}").value == value
+def test_the_step_carries_its_scopes(built):
+    assert built["span"].tags["dense_update_tables"] == 1   # Adam: dense
+    assert metrics.default_registry().gauge(
+        "device_mode_dense_update_tables").value == 1
     ids, label = _feed(*_batches(1)[0])
     with built["mesh"]:
         text = built["step"].lower(built["params"], built["opt_state"], [],
@@ -405,21 +391,6 @@ def test_the_build_is_tagged_and_the_step_carries_its_scopes(built):
                    "layer_3/experts/mixer/experts_grouped"):
         assert nested in text, nested
     assert "rotary" not in text
-
-
-@pytest.mark.parametrize("cell_name", [
-    "nemotron-3-nano-30b-a3b.device-histories8k",
-    "glm-4.7-flash.device-histories8k",
-    "xing4.0-29b-a4b.device-histories8k"])
-def test_a_tower_without_a_delta_rule_layer_fuses_none(cell_name):
-    """``kda_fused_layers`` counts the ``K`` layers, whose recurrence is
-    ``ops/kda_scan``'s kernels: none in the other sequence cells."""
-    man = manifest.Manifest(manifest.repo_root(BENCH_DIR))
-    _, cell, config, _ = man.cell(cell_name)
-    other = importlib.import_module(f"placements.{cell['placement']}")
-    tags = other.build_model(other.weights.sizes_of(config)).tower.step_tags()
-    assert "K" not in tags["tower_layers"]
-    assert (tags["kda_layers"], tags["kda_fused_layers"]) == (0, 0)
 
 
 def test_routed_rows_counts_the_four_expert_layers(built):
@@ -440,16 +411,6 @@ def test_the_configuration_states_the_parameters_it_runs():
     assert sz["q_rank"] is None and sz["positions"] is False
     assert set(config["reduced"]) == {"num_hidden_layers", "num_experts",
                                       "vocab_size"}
-    tower = placement.build_tower(sz)
-    assert tower.step_tags() == {
-        "tower_layers": "KDKEKELEKE", "experts_held": tuple(range(8)),
-        "experts_routed": 256, "expert_matrices": 3, "mtp_depth": 0,
-        "residual_streams": 1, "sinkhorn_iters": 0, "key_width": 192,
-        "value_width": 128, "attention_residuals_kept": 1,
-        "hyper_fused_sublayers": 0, "kda_layers": 4, "kda_fused_layers": 4,
-        "kda_heads": 32, "kda_chunk": 64, "attention_positions": 0,
-        "selected_layers": 0, "index_fused_layers": 0, "select_topk": 0,
-        "index_heads": 0, "expert_scoring": "sigmoid"}
     model = placement.build_model(sz)
     shapes = jax.eval_shape(
         lambda: model.init(jax.random.key(0), [],

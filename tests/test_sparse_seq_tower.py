@@ -200,10 +200,6 @@ def test_the_accepted_towers_keep_their_trees_and_their_programs(cell_name):
     other = importlib.import_module(f"placements.{cell['placement']}")
     sz = other.weights.sizes_of(config, cell["rehearsal"]["tower"])
     model = other.build_model(sz)
-    tags = model.tower.step_tags()
-    assert tags["expert_scoring"] == "sigmoid"
-    assert (tags["selected_layers"], tags["index_fused_layers"],
-            tags["select_topk"], tags["index_heads"]) == (0, 0, 0, 0)
     loss = (other.loss_of(sz) if hasattr(other, "loss_of")
             else next_item_cross_entropy)
     mesh = make_mesh((1, 1), devices=jax.devices()[:1])
@@ -416,30 +412,10 @@ def test_the_probe_reads_the_selection_the_step_makes(built):
     assert costs.selected_pairs(48, SZ["topk"]) == count.sum()
 
 
-def test_the_build_is_tagged_and_the_step_carries_its_scopes(built):
-    tags = built["span"].tags
-    assert tags["tower_layers"] == "SESE"
-    assert (tags["selected_layers"], tags["select_topk"],
-            tags["index_heads"]) == (2, 8, 4)
-    # the indexer's tile of 16 admits the kernels' block of queries (a
-    # tile of 8 admits none: the plain form whatever the history)
-    assert tags["index_fused_layers"] == 2
-    assert placement.build_tower(dict(SZ, index_tile=8)).step_tags()[
-        "index_fused_layers"] == 0
-    assert tags["expert_scoring"] == "softmax"
-    assert tags["attention_positions"] == 1
-    assert tags["key_width"] == 16 and tags["value_width"] == 16
-    assert tags["attention_residuals_kept"] == 2     # the two `S` layers
-    assert tags["experts_routed"] == 16 and tags["expert_matrices"] == 3
-    gauges = metrics.default_registry()
-    for name, value in (("selected_layers", 2), ("select_topk", 8),
-                        ("index_fused_layers", 2), ("index_heads", 4), ("attention_positions", 1),
-                        ("tower_layers", 4), ("experts_held", 4),
-                        ("key_width", 16), ("value_width", 16)):
-        assert gauges.gauge(f"device_mode_{name}").value == value
-    # a word is a gauge of 1 under its label, not a gauge of its length
-    assert gauges.gauge("device_mode_expert_scoring",
-                        labels={"choice": "softmax"}).value == 1
+def test_the_step_carries_its_scopes(built):
+    assert built["span"].tags["dense_update_tables"] == 1   # Adam: dense
+    assert metrics.default_registry().gauge(
+        "device_mode_dense_update_tables").value == 1
     ids, label = _feed(*_batches(1)[0])
     with built["mesh"]:
         text = built["step"].lower(built["params"], built["opt_state"], [],
@@ -490,17 +466,6 @@ def test_the_configuration_states_the_parameters_it_runs():
             sz["index_tile"]) == (2048, 16, 64, 512)
     assert set(config["reduced"]) == {"num_hidden_layers", "num_experts",
                                       "vocab_size"}
-    tower = placement.build_tower(sz)
-    assert tower.step_tags() == {
-        "tower_layers": "SESESESESESE", "experts_held": tuple(range(16)),
-        "experts_routed": 128, "expert_matrices": 3, "mtp_depth": 0,
-        "residual_streams": 1, "sinkhorn_iters": 0, "key_width": 128,
-        "value_width": 128, "attention_residuals_kept": 6,
-        "hyper_fused_sublayers": 0, "kda_layers": 0, "kda_fused_layers": 0,
-        "kda_heads": 0, "kda_chunk": 0, "attention_positions": 1,
-        "selected_layers": 6, "index_fused_layers": 6, "select_topk": 2048,
-        "index_heads": 16,
-        "expert_scoring": "softmax"}
     model = placement.build_model(sz)
     shapes = jax.eval_shape(
         lambda: model.init(jax.random.key(0), [],
