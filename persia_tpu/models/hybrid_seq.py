@@ -1,7 +1,7 @@
 """Hybrid sequence tower: state-space and delta-rule mixers, sparse
-experts beside a shared expert (or none), grouped-query, latent and
-indexer-selected attention and a gated dense feed-forward over a
-history of item ids, with an item head
+experts beside a shared expert (or none), grouped-query (full or over a
+sliding window), latent and indexer-selected attention and a gated
+dense feed-forward over a history of item ids, with an item head
 for next-item prediction and, optionally, one multi-token-prediction
 module for the item after next.
 
@@ -34,6 +34,14 @@ transformer block, attention then a feed-forward, is two letters):
 ``*``  causal grouped-query attention through the Pallas flash kernel,
        without a positional encoding: in a pattern with state-space
        layers those carry position.
+``W``  grouped-query attention over a sliding window of ``attn_window``
+       keys (:class:`GroupedQueryAttention` with a ``window``: the
+       flash kernels walk only the band's block pairs), every query and
+       key head rotated whole by plain :func:`rotary` at ``rope_theta``.
+``A``  causal grouped-query attention over every earlier key, rotated
+       whole at ``rope_theta`` under ``rope_scaling`` (a
+       :class:`YarnRule` or None): a published sliding-window model's
+       full layers beside its ``W`` ones.
 ``L``  causal multi-head latent attention (:class:`LatentAttention`):
        low-rank query and key-value projections with a norm on each
        latent (or one direct query projection, ``latent_q_rank`` None),
@@ -566,13 +574,25 @@ class GroupedQueryAttention(nn.Module):
     heads before the flash kernel and autodiff sums their gradients over
     each group: the kernel streams a key block per (head, query block)
     either way, and its block specs stay as every other caller has
-    them."""
+    them.
+
+    ``rope_theta`` None is attention without positions (``*``); a number
+    rotates the whole of every query and key head by :func:`rotary`,
+    under ``rope_scaling`` (None or a :class:`YarnRule`, which also sets
+    the scores' factor, :func:`softmax_scale`). ``window`` None attends
+    every earlier key; a number ``w`` the ``w`` latest, itself included
+    (query ``t`` sees key ``s`` where ``t - w < s <= t``), through the
+    kernels' band schedule, whose calls are named ``window_attention``
+    in a trace where the causal ones are ``flash_attention``."""
 
     heads: int = 32
     kv_heads: int = 2
     head_dim: int = 128
     out_scale: float = 1.0
     compute_dtype: Any = jnp.bfloat16
+    window: Any = None
+    rope_theta: Any = None
+    rope_scaling: Any = None
 
     @nn.compact
     def __call__(self, u):
@@ -590,15 +610,26 @@ class GroupedQueryAttention(nn.Module):
             return jnp.zeros_like(u)
         from persia_tpu.ops.flash_attention import flash_attention_masked
 
-        def heads(y, n):    # (bs, t, n * hd) -> (bs, n, t, hd)
-            return y.reshape(bs, t, n, hd).transpose(0, 2, 1, 3)
+        def heads(y, n, turn=False):    # (bs, t, n * hd) -> (bs, n, t, hd)
+            y = y.reshape(bs, t, n, hd)
+            if turn and self.rope_theta is not None:
+                with jax.named_scope("rotary"):
+                    y = rotary(y, self.rope_theta,
+                               self.rope_scaling).astype(cd)
+            return y.transpose(0, 2, 1, 3)
 
-        q = heads(_dense(u, wq, cd), self.heads)
+        q = heads(_dense(u, wq, cd), self.heads, turn=True)
         group = self.heads // self.kv_heads
-        k = jnp.repeat(heads(_dense(u, wk, cd), self.kv_heads), group, axis=1)
+        k = jnp.repeat(heads(_dense(u, wk, cd), self.kv_heads, turn=True),
+                       group, axis=1)
         v = jnp.repeat(heads(_dense(u, wv, cd), self.kv_heads), group, axis=1)
-        with jax.named_scope("flash_attention"):    # the calls' name in a trace
-            out = flash_attention_masked(q, k, v, causal=True)
+        scale = (None if self.rope_scaling is None
+                 else softmax_scale(hd, self.rope_scaling))
+        # the calls' name in a trace, one for each kind
+        with jax.named_scope("flash_attention" if self.window is None
+                             else "window_attention"):
+            out = flash_attention_masked(q, k, v, causal=True, scale=scale,
+                                         window=self.window)
         out = out.transpose(0, 2, 1, 3).reshape(bs, t, self.heads * hd)
         return _dense(out, wo, cd)
 
@@ -1115,10 +1146,12 @@ class HybridSequenceTower(nn.Module):
     index_rope_dim: int = 32
     select_topk: int = 2048
     index_tile: int = 512
+    attn_window: Any = None         # `W`: the keys a query sees
 
     SCOPES = {"M": "ssm_mixer", "E": "experts", "*": "attention",
               "L": "latent_attention", "D": "dense_ffn",
-              "K": "kda_attention", "S": "selected_attention"}
+              "K": "kda_attention", "S": "selected_attention",
+              "W": "sliding_attention", "A": "full_attention"}
 
     def _mixer(self, kind, out_scale):
         """Unbound, so that the layer it is handed to adopts it."""
@@ -1141,6 +1174,17 @@ class HybridSequenceTower(nn.Module):
             return GroupedQueryAttention(self.attn_heads, self.attn_kv_heads,
                                          self.attn_head_dim, out_scale, cd,
                                          parent=None)
+        if kind in ("W", "A"):
+            if kind == "W" and self.attn_window is None:
+                raise ValueError(f"pattern {self.pattern!r}: a `W` layer "
+                                 f"needs attn_window")
+            return GroupedQueryAttention(
+                self.attn_heads, self.attn_kv_heads, self.attn_head_dim,
+                out_scale, cd,
+                window=self.attn_window if kind == "W" else None,
+                rope_theta=self.rope_theta,
+                rope_scaling=self.rope_scaling if kind == "A" else None,
+                parent=None)
         if kind == "L":
             return LatentAttention(self.latent_heads, self.latent_q_rank,
                                    self.latent_kv_rank, self.latent_nope_dim,

@@ -24,11 +24,16 @@ rectangle has 256 in 512 x 512 blocks, 36 of 64 in the 1024 x 1024 that
 ``_blocks`` picks, and only the 16 (8) that straddle the diagonal pay
 for the mask (``interior`` pairs run the same body without the iotas,
 the comparisons and the select). A non-causal call gets its whole
-rectangle from the same function. ``flash_attention_selected`` walks
-the causal list too and hands each kernel, beside the positional mask,
-the block of an int8 ``(B, T_q, T_k)`` selection that its scheduled pair
-names (one selection for all the heads): a pair is walked whether or not
-any of its keys is selected.
+rectangle from the same function. A causal call with a ``window`` (query
+``t`` sees key ``s`` only where ``t - window < s``) gets the band: at T
+8192 and window 1024, 15 pairs of 64 a head in 1024 x 1024 blocks, all
+of them edge pairs (the diagonal, and the band's lower edge below it),
+45 of 256 in 512 x 512 blocks, 30 of them edge pairs.
+``flash_attention_selected`` walks the causal list too and hands each
+kernel, beside the positional mask, the block of an int8 ``(B, T_q,
+T_k)`` selection that its scheduled pair names (one selection for all
+the heads): a pair is walked whether or not any of its keys is
+selected.
 
 Kernel shape rules: the head width is the lane axis of every block, and
 there are two of them: queries, keys and their gradients are ``dk``
@@ -92,20 +97,22 @@ RESIDUAL_NAMES = ("flash_attention_out", "flash_attention_lse")
 
 
 def block_schedule(t_q: int, t_k: int, block_q: int, block_k: int,
-                   causal: bool, by_key: bool = False):
+                   causal: bool, by_key: bool = False, window=None):
     """The block pairs one call walks, from its static shapes alone:
     ``(qi, ki, flags)``, three int32 arrays of one length.
 
     A pair is listed exactly when some score of it is live (query and
     key inside their true lengths and, in a causal call, key not after
-    query). It is an ``EDGE`` pair exactly when some score of it is
-    masked: it straddles the diagonal, or is the last key or query
-    block of a padded T. The order is the accumulation order: rows of
-    ``qi`` with ``ki`` ascending (forward, dq) or, ``by_key``, columns
-    of ``ki`` with ``qi`` ascending (dk/dv); ``FIRST`` and ``LAST``
-    mark the ends of each row. A row with nothing live (a causal key
-    block no query reaches, t_q < t_k) keeps one fully masked pair, so
-    its output block is still written, as zeros."""
+    query; under a ``window``, query ``t`` sees key ``s`` only where
+    ``t - window < s``). It is an ``EDGE`` pair exactly when some score
+    of it is masked: it straddles the diagonal or the band's lower edge,
+    or is the last key or query block of a padded T. The order is the
+    accumulation order: rows of ``qi`` with ``ki`` ascending (forward,
+    dq) or, ``by_key``, columns of ``ki`` with ``qi`` ascending (dk/dv);
+    ``FIRST`` and ``LAST`` mark the ends of each row. A row with nothing
+    live (a causal key block no query reaches, t_q < t_k) keeps one
+    fully masked pair, so its output block is still written, as
+    zeros."""
     n_q, n_k = -(-t_q // block_q), -(-t_k // block_k)
     qi, ki = np.meshgrid(np.arange(n_q), np.arange(n_k), indexing="ij")
     q_lo, k_lo = qi * block_q, ki * block_k
@@ -116,6 +123,9 @@ def block_schedule(t_q: int, t_k: int, block_q: int, block_k: int,
     if causal:
         live = q_hi >= k_lo
         edge |= q_lo < k_hi
+    if window is not None:
+        live &= q_lo - k_hi < window
+        edge |= q_hi - k_lo >= window
     if by_key:
         qi, ki, live, edge = qi.T, ki.T, live.T, edge.T
     # dead rows keep the pair nearest the diagonal (masked: EDGE holds)
@@ -146,9 +156,10 @@ def _run_bodies(body, flags, has_edge: bool, has_interior: bool):
 
 
 def _block_mask(edge: bool, qi, ki, block_q, block_k, t_k_real, causal,
-                mask_row, t_q_real=None, selected=None):
+                mask_row, t_q_real=None, selected=None, window=None):
     """The (block_q, block_k) mask of a pair, or None where every score
-    is live: positions (padding, causal) only in an edge pair, the
+    is live: positions (padding, causal, the band's lower edge under a
+    ``window``) only in an edge pair, the
     ``kv_mask`` row, (1, bk) broadcast, and the ``select`` block, one
     entry a (query, key), in either kind."""
     mask = None
@@ -163,6 +174,8 @@ def _block_mask(edge: bool, qi, ki, block_q, block_k, t_k_real, causal,
             mask = jnp.logical_and(q_pos < t_q_real, mask)
         if causal:
             mask = jnp.logical_and(mask, q_pos >= k_pos)
+        if window is not None:
+            mask = jnp.logical_and(mask, q_pos - k_pos < window)
     if mask_row is not None:
         keep = mask_row > 0
         mask = keep if mask is None else jnp.logical_and(mask, keep)
@@ -175,7 +188,8 @@ def _block_mask(edge: bool, qi, ki, block_q, block_k, t_k_real, causal,
 def _fwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, *rest,
                 scale: float, causal: bool, block_q: int, block_k: int,
                 t_k_real: int, has_edge: bool, has_interior: bool,
-                with_lse: bool, with_mask: bool, with_select: bool = False):
+                with_lse: bool, with_mask: bool, with_select: bool = False,
+                window=None):
     if with_mask:
         mask_ref, rest = rest[0], rest[1:]
     if with_select:
@@ -201,7 +215,8 @@ def _fwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, *rest,
             preferred_element_type=jnp.float32) * scale  # (bq, bk)
         mask = _block_mask(edge, qi, ki, block_q, block_k, t_k_real, causal,
                            mask_ref[...] if with_mask else None,
-                           selected=select_ref[...] if with_select else None)
+                           selected=select_ref[...] if with_select else None,
+                           window=window)
         if mask is not None:
             s = jnp.where(mask, s, _NEG_INF)
 
@@ -279,6 +294,14 @@ def _scale(scale, dk):
     return 1.0 / float(dk) ** 0.5 if scale is None else float(scale)
 
 
+def _check_window(window, causal):
+    """A window is a band under the diagonal: a positive key count, in a
+    causal call."""
+    if window is not None and (not causal or window < 1):
+        raise ValueError(f"window {window}: a positive number of keys, "
+                         f"in a causal call")
+
+
 def _mask_rows(kv_mask, block_k):
     """(B, T_k) key-validity mask -> (B, 1, T_k padded) f32 0/1 rows."""
     return _pad_t(kv_mask.astype(jnp.float32), block_k)[:, None, :]
@@ -327,20 +350,28 @@ def _select_operand(select, block_q, block_k, h):
 
 
 def _scheduled_call(kernel, schedule, bh, in_specs, out_specs, out_shape,
-                    scratch_shapes, interpret, operands):
-    """One kernel over grid (bh, pairs) of ``block_schedule(*schedule)``,
-    the pairs prefetched as scalars and the kernel told which of its two
-    bodies the schedule needs at all. Sets the process's gauges of how
-    far the schedule cut the rectangle (a head, of the call built last):
-    pairs walked, pairs a dense grid would have, edge pairs."""
-    pairs = block_schedule(*schedule)
+                    scratch_shapes, interpret, operands, window=None):
+    """One kernel over grid (bh, pairs) of ``block_schedule(*schedule,
+    window=window)``, the pairs prefetched as scalars and the kernel told
+    which of its two bodies the schedule needs at all (and the window).
+    Sets the process's gauges of how far the schedule cut the rectangle
+    (a head, of the call of its kind built last): a causal call's pairs
+    walked, pairs a dense grid would have and edge pairs; a windowed
+    call's pairs walked and edge pairs under ``flash_attention_window_``
+    names, so that a step with both kinds keeps both readings."""
+    pairs = block_schedule(*schedule, window=window)
     t_q, t_k, block_q, block_k = schedule[:4]
     n_edge = int(np.count_nonzero(pairs[2] & EDGE))
-    for name, n in (("walked", pairs[0].size),
-                    ("dense", -(-t_q // block_q) * -(-t_k // block_k)),
-                    ("edge", n_edge)):
-        metrics.default_registry().gauge(
-            f"flash_attention_pairs_{name}").set(n)
+    if window is None:
+        gauges = (("pairs_walked", pairs[0].size),
+                  ("pairs_dense", -(-t_q // block_q) * -(-t_k // block_k)),
+                  ("pairs_edge", n_edge))
+    else:
+        gauges = (("window_pairs_walked", pairs[0].size),
+                  ("window_pairs_edge", n_edge))
+        kernel = functools.partial(kernel, window=window)
+    for name, n in gauges:
+        metrics.default_registry().gauge(f"flash_attention_{name}").set(n)
     return pl.pallas_call(
         functools.partial(kernel, has_edge=n_edge > 0,
                           has_interior=n_edge < pairs[0].size),
@@ -359,10 +390,13 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool = False,
                                block_q=None, block_k=None,
                                interpret: bool = False,
                                return_lse: bool = False,
-                               kv_mask=None, scale=None, select=None):
+                               kv_mask=None, scale=None, select=None,
+                               window=None):
     """Forward Pallas flash attention. q/k: (B, H, T, Dk), v and the
     output (B, H, T, Dv); ``scale`` multiplies the scores, ``1 /
-    sqrt(Dk)`` where it is None.
+    sqrt(Dk)`` where it is None. ``window`` (causal calls only): query
+    ``t`` attends key ``s`` exactly when ``t - window < s <= t``, and
+    the schedule walks only the band's block pairs.
 
     With ``return_lse`` also returns the (B, H, T) logsumexp residual
     the backward kernels consume. ``kv_mask`` optional (B, T_k) of
@@ -371,6 +405,7 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool = False,
     fully-masked query rows yield 0."""
     b, h, t_q, dk = q.shape
     t_k, dv = k.shape[2], v.shape[3]
+    _check_window(window, causal)
     block_q, block_k = _blocks(block_q, block_k, t_q, t_k, max(dk, dv),
                                q.dtype)
     qp = _pad_t(q.reshape(b * h, t_q, dk), block_q)
@@ -406,7 +441,7 @@ def flash_attention_fwd_pallas(q, k, v, causal: bool = False,
         [pltpu.VMEM((block_q, dv), jnp.float32),
          pltpu.VMEM((block_q, _LANES), jnp.float32),
          pltpu.VMEM((block_q, _LANES), jnp.float32)],
-        interpret, operands)
+        interpret, operands, window)
     if return_lse:
         out, lse = res
         return (out[:, :t_q].reshape(b, h, t_q, dv),
@@ -445,7 +480,7 @@ def _bwd_dq_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
                    lse_ref, delta_ref, *rest, scale: float, causal: bool,
                    block_q: int, block_k: int, t_q_real: int,
                    t_k_real: int, has_edge: bool, has_interior: bool,
-                   with_mask: bool, with_select: bool = False):
+                   with_mask: bool, with_select: bool = False, window=None):
     mask_ref, select_ref, (dq_ref, dq_acc) = _mask_operands(
         rest, with_mask, with_select)
     qi, ki, flags = _pair(qi_ref, ki_ref, flags_ref)
@@ -462,7 +497,8 @@ def _bwd_dq_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
         mask = _block_mask(edge, qi, ki, block_q, block_k, t_k_real, causal,
                            None if mask_ref is None else mask_ref[...],
                            t_q_real,
-                           None if select_ref is None else select_ref[...])
+                           None if select_ref is None else select_ref[...],
+                           window)
         p = _masked_p(q_ref[0], k_ref[0], lse, mask, scale=scale)
         do = do_ref[0]
         dp = jax.lax.dot_general(                       # dO @ V^T
@@ -485,7 +521,7 @@ def _bwd_dkv_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
                     causal: bool, block_q: int, block_k: int,
                     t_q_real: int, t_k_real: int, has_edge: bool,
                     has_interior: bool, with_mask: bool,
-                    with_select: bool = False):
+                    with_select: bool = False, window=None):
     mask_ref, select_ref, (dk_ref, dv_ref, dk_acc, dv_acc) = _mask_operands(
         rest, with_mask, with_select)
     qi, ki, flags = _pair(qi_ref, ki_ref, flags_ref)
@@ -502,7 +538,8 @@ def _bwd_dkv_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
         mask = _block_mask(edge, qi, ki, block_q, block_k, t_k_real, causal,
                            None if mask_ref is None else mask_ref[...],
                            t_q_real,
-                           None if select_ref is None else select_ref[...])
+                           None if select_ref is None else select_ref[...],
+                           window)
         p = _masked_p(q, k_ref[0], lse, mask, scale=scale)
         do = do_ref[0]
         dv_acc[...] += jax.lax.dot_general(             # P^T @ dO
@@ -527,10 +564,10 @@ def _bwd_dkv_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, do_ref,
 def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
                                block_q=None, block_k=None,
                                interpret: bool = False, kv_mask=None,
-                               scale=None, select=None):
+                               scale=None, select=None, window=None):
     """Pallas flash-attention backward: (dq, dk, dv), the first two at
-    the key width, the third at the value width; ``kv_mask`` and
-    ``select`` as the forward call had them.
+    the key width, the third at the value width; ``kv_mask``,
+    ``select`` and ``window`` as the forward call had them.
 
     Same schedule as the forward, run twice: dq revisits its q-block
     accumulator along a row of pairs; dk/dv revisit their k-block
@@ -540,6 +577,7 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
     """
     b, h, t_q, dk = q.shape
     t_k, dv = k.shape[2], v.shape[3]
+    _check_window(window, causal)
     block_q, block_k = _blocks(block_q, block_k, t_q, t_k, max(dk, dv),
                                q.dtype)
     # delta_i = rowsum(dO_i * O_i) — cheap XLA elementwise+reduce
@@ -572,7 +610,8 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
                 t_k_real=t_k, with_mask=kv_mask is not None,
                 with_select=select is not None),
             (t_q, t_k, block_q, block_k, causal, by_key), b * h, in_specs,
-            out_specs, out_shape, scratch_shapes, interpret, operands)
+            out_specs, out_shape, scratch_shapes, interpret, operands,
+            window)
 
     g_q = call(_bwd_dq_kernel, False, q_spec,
                jax.ShapeDtypeStruct(qp.shape, q.dtype),
@@ -588,18 +627,22 @@ def flash_attention_bwd_pallas(q, k, v, out, lse, do, causal: bool = False,
             g_v[:, :t_k].reshape(b, h, t_k, dv))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
 def flash_attention(q, k, v, causal: bool = False, block_q=None,
-                    block_k=None, interpret: bool = False, scale=None):
+                    block_k=None, interpret: bool = False, scale=None,
+                    window=None):
     """Flash attention, Pallas forward AND backward.
 
     The forward saves (q, k, v, out, lse); the backward recomputes each
     softmax block in VMEM from those residuals — memory stays O(T)
     end-to-end and nothing quadratic touches HBM in either direction.
+    ``window`` (causal only): each query attends its ``window`` latest
+    keys, itself included.
     """
     return flash_attention_fwd_pallas(q, k, v, causal=causal,
                                       block_q=block_q, block_k=block_k,
-                                      interpret=interpret, scale=scale)
+                                      interpret=interpret, scale=scale,
+                                      window=window)
 
 
 def _named_residuals(out, lse):
@@ -609,18 +652,18 @@ def _named_residuals(out, lse):
                  for x, name in zip((out, lse), RESIDUAL_NAMES))
 
 
-def _fa_fwd(q, k, v, causal, block_q, block_k, interpret, scale):
+def _fa_fwd(q, k, v, causal, block_q, block_k, interpret, scale, window):
     out, lse = _named_residuals(*flash_attention_fwd_pallas(
         q, k, v, causal=causal, block_q=block_q, block_k=block_k,
-        interpret=interpret, return_lse=True, scale=scale))
+        interpret=interpret, return_lse=True, scale=scale, window=window))
     return out, (q, k, v, out, lse)
 
 
-def _fa_bwd(causal, block_q, block_k, interpret, scale, res, g):
+def _fa_bwd(causal, block_q, block_k, interpret, scale, window, res, g):
     q, k, v, out, lse = res
     return flash_attention_bwd_pallas(
         q, k, v, out, lse, g, causal=causal, block_q=block_q,
-        block_k=block_k, interpret=interpret, scale=scale)
+        block_k=block_k, interpret=interpret, scale=scale, window=window)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)
@@ -654,17 +697,21 @@ _flash_attention_masked.defvjp(_fam_fwd, _fam_bwd)
 
 def flash_attention_masked(q, k, v, kv_mask=None, causal: bool = False,
                            block_q=None, block_k=None,
-                           interpret="auto", scale=None):
+                           interpret="auto", scale=None, window=None):
     """`flash_attention` with an optional (B, T_k) key-validity mask —
     the entry the sequence tower / Ulysses paths use (the mask rides as
     f32 0/1 so the custom_vjp plumbing stays all-float; its cotangent
     is zero). ``interpret="auto"`` compiles on TPU and falls back to
     the Pallas interpreter elsewhere (CPU tests). ``scale`` multiplies
-    the scores: a Python number, ``1 / sqrt(key width)`` where None."""
+    the scores: a Python number, ``1 / sqrt(key width)`` where None.
+    ``window`` (a causal call without a mask): query ``t`` attends key
+    ``s`` exactly when ``t - window < s <= t``."""
     interpret = ops.interpret(interpret)
     if kv_mask is None:
         return flash_attention(q, k, v, causal, block_q, block_k, interpret,
-                               scale)
+                               scale, window)
+    if window is not None:
+        raise ValueError("a window is not taken beside a key mask")
     return _flash_attention_masked(
         q, k, v, kv_mask.astype(jnp.float32), causal, block_q, block_k,
         interpret, scale)

@@ -446,3 +446,154 @@ def test_compiled_on_tpu():
         jax.block_until_ready(out)
         dt = (time.perf_counter() - t0) / 5
         print(f"{name}: {dt * 1e3:.2f} ms/call")
+
+
+# --- a sliding window: the band's block pairs, its edge masks ----------------
+
+
+def _brute_force_band(t, block_q, block_k, window):
+    """Per block pair of a causal self-attention call under ``window``,
+    from the full (padded) score mask: is some score live, is some score
+    masked."""
+    n_q, n_k = -(-t // block_q), -(-t // block_k)
+    q_pos = np.arange(n_q * block_q)[:, None]
+    k_pos = np.arange(n_k * block_k)[None, :]
+    mask = ((q_pos < t) & (k_pos < t) & (q_pos >= k_pos)
+            & (q_pos - k_pos < window))
+    blocks = mask.reshape(n_q, block_q, n_k, block_k)
+    return blocks.any(axis=(1, 3)), ~blocks.all(axis=(1, 3))
+
+
+@pytest.mark.parametrize("by_key", [False, True])
+@pytest.mark.parametrize("window", [1, 128, 200, 1024, "T"])
+@pytest.mark.parametrize("block", [128, 256])
+@pytest.mark.parametrize("t", [300, 1024, 2048])
+def test_band_schedule_matches_brute_force_mask(t, block, window, by_key):
+    """Under a window the table holds a pair exactly when some score of
+    it is live and marks it EDGE exactly when some score of it is masked
+    (the diagonal, the band's lower edge, a padded tail); rows are walked
+    in order, each once, first and last marked. A window of T or more is
+    the causal list itself."""
+    window = t if window == "T" else window
+    live, masked = _brute_force_band(t, block, block, window)
+    qi, ki, flags = block_schedule(t, t, block, block, True, by_key,
+                                   window=window)
+    listed = np.zeros_like(live)
+    listed[qi, ki] = True
+    np.testing.assert_array_equal(listed, live)     # no placeholder needed
+    assert len(set(zip(qi.tolist(), ki.tolist()))) == qi.size
+    np.testing.assert_array_equal((flags & EDGE) != 0, masked[qi, ki])
+    row, col = (ki, qi) if by_key else (qi, ki)
+    new_row = np.r_[True, np.diff(row) != 0]
+    np.testing.assert_array_equal(row[new_row], np.arange(live.shape[0]))
+    assert (np.diff(col)[~new_row[1:]] > 0).all()
+    np.testing.assert_array_equal((flags & FIRST) != 0, new_row)
+    np.testing.assert_array_equal((flags & LAST) != 0,
+                                  np.r_[new_row[1:], True])
+    if window >= t:
+        for got, want in zip((qi, ki, flags), block_schedule(
+                t, t, block, block, True, by_key)):
+            np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("shape", SCHEDULE_SHAPES)
+def test_no_window_is_the_causal_list(shape):
+    for by_key in (False, True):
+        for got, want in zip(
+                block_schedule(*shape, True, by_key, window=None),
+                block_schedule(*shape, True, by_key)):
+            np.testing.assert_array_equal(got, want)
+
+
+def test_a_call_without_a_window_lowers_as_before():
+    """``window=None`` passed or left out: the same lowered text, the
+    forward and the whole gradient, so the callers that pass no window
+    run the program they ran before the argument was there."""
+    x = jax.ShapeDtypeStruct((1, 2, 300, 32), jnp.bfloat16)
+
+    def text(**kw):
+        def loss(q, k, v):
+            return flash_attention_masked(
+                q, k, v, causal=True, block_q=128, block_k=128,
+                interpret=True, **kw).astype(jnp.float32).sum()
+        return jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))).lower(
+            x, x, x).as_text()
+
+    assert text(window=None) == text()
+    assert text(window=128) != text()
+
+
+@pytest.mark.parametrize("dh,t,window,block", [
+    (16, 300, 100, 128),    # ragged T, the band over two key blocks
+    (32, 384, 128, 128),    # the band a block wide: no interior pair
+    (32, 300, 1, 128),      # each query itself alone
+    (64, 260, 200, 256),    # one query block, a band over most of it
+])
+def test_windowed_kernels_match_a_masked_softmax(dh, t, window, block):
+    """Forward and all three gradients of the interpreted band kernels
+    against a plain softmax under ``t - window < s <= t``, float32: 2e-5
+    absolute on outputs of order one, 1e-4 on gradients, as the causal
+    tests above."""
+    q, k, v = _qkv(b=1, h=2, t=t, dh=dh, seed=dh + window)
+    w = jnp.asarray(np.random.default_rng(window).normal(size=q.shape),
+                    jnp.float32)
+    pos = np.arange(t)
+    band = jnp.asarray((pos[:, None] >= pos[None, :])
+                       & (pos[:, None] - pos[None, :] < window))
+
+    def plain(q, k, v):
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                       precision="highest") / np.sqrt(dh)
+        s = jnp.where(band[None, None], s, -jnp.inf)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v,
+                          precision="highest")
+
+    def mine(q, k, v):
+        return flash_attention_masked(q, k, v, causal=True, block_q=block,
+                                      block_k=block, interpret=True,
+                                      window=window)
+
+    np.testing.assert_allclose(np.asarray(mine(q, k, v)),
+                               np.asarray(plain(q, k, v)),
+                               rtol=2e-5, atol=2e-5)
+    got = jax.grad(lambda *x: jnp.sum(w * mine(*x)), argnums=(0, 1, 2))(
+        q, k, v)
+    want = jax.grad(lambda *x: jnp.sum(w * plain(*x)), argnums=(0, 1, 2))(
+        q, k, v)
+    for a, b in zip(got, want):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_a_window_is_a_causal_band():
+    q, k, v = _qkv(t=128)
+    for bad in ({"causal": False, "window": 64},
+                {"causal": True, "window": 0}):
+        with pytest.raises(ValueError, match="window"):
+            flash_attention_fwd_pallas(q, k, v, interpret=True, **bad)
+
+
+@pytest.mark.parametrize("block,pairs", [(None, [15, 15]), (512, [45, 30])])
+def test_the_window_gauges_read_the_band_from_the_schedule(block, pairs):
+    """T 8192, window 1024: 15 pairs of 64 a head in the 1024 x 1024
+    blocks ``_blocks`` picks, all of them edge pairs; 45 of 256 in 512s,
+    30 on an edge. Building the call sets the window's own gauges and
+    leaves the causal calls' as the causal call built last set them;
+    nothing runs a kernel."""
+    x = jax.ShapeDtypeStruct((1, 2, 8192, 128), jnp.bfloat16)
+    reg = metrics.default_registry()
+
+    def build(**kw):
+        jax.eval_shape(jax.grad(lambda q, k, v: flash_attention_masked(
+            q, k, v, causal=True, block_q=block, block_k=block,
+            interpret=True, **kw).astype(jnp.float32).sum(),
+            argnums=(0, 1, 2)), x, x, x)
+
+    build()
+    causal = [reg.gauge(f"flash_attention_pairs_{n}").value
+              for n in ("walked", "dense", "edge")]
+    build(window=1024)
+    assert [reg.gauge(f"flash_attention_window_pairs_{n}").value
+            for n in ("walked", "edge")] == pairs
+    assert [reg.gauge(f"flash_attention_pairs_{n}").value
+            for n in ("walked", "dense", "edge")] == causal

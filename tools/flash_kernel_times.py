@@ -2,7 +2,8 @@
 
     python3 tools/flash_kernel_times.py [--tree DIR]
         [--blocks default,512x512,...]
-        [--shapes 20x8192x256,32x8192x128,32x8192x192/128] [--out FILE]
+        [--shapes 20x8192x256,32x8192x128,32x8192x192/128] [--window N]
+        [--out FILE]
 
 For each shape (heads x T x head width, or key width / value width
 where the two differ; batch 1, bfloat16, causal: what the three
@@ -12,7 +13,9 @@ host-clock time of the forward as serving calls it, the forward with
 ``lse`` as training calls it, the backward's dq call and its dk/dv call
 each alone (the other is dead code to the compiler; delta's reduce
 rides with both) and the whole gradient, over ``--iters`` calls that end
-in ``block_until_ready``. ``--tree`` times another checkout's kernels
+in ``block_until_ready``. ``--window N`` times the band calls a
+sliding-window layer makes (query ``t`` sees keys ``t - N < s <= t``)
+in the causal calls' place. ``--tree`` times another checkout's kernels
 (the parent's archive) with the same script; the ``digest`` of outputs
 and gradients says whether two trees agree bit for bit. Refuses to run
 without a TPU: a CPU time says nothing about a kernel.
@@ -34,6 +37,7 @@ def main() -> int:
                     default="20x8192x256,32x8192x128,32x8192x192/128")
     ap.add_argument("--blocks", default="default")
     ap.add_argument("--iters", type=int, default=10)
+    ap.add_argument("--window", type=int, default=None)
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
     sys.path.insert(0, os.path.abspath(args.tree))
@@ -76,12 +80,14 @@ def main() -> int:
                        for width in (dh, dh, dv, dv))
         for blocks in args.blocks.split(","):
             kw = {"causal": True}
+            if args.window is not None:
+                kw["window"] = args.window
             if blocks != "default":
                 kw["block_q"], kw["block_k"] = (
                     int(x) for x in blocks.split("x"))
             line = {"tree": args.tree, "device": dev.device_kind,
                     "heads": h, "t": t, "dh": dh, "dv": dv,
-                    "blocks": blocks}
+                    "blocks": blocks, "window": args.window}
             try:
                 fwd = jax.jit(lambda q, k, v: fa.flash_attention_fwd_pallas(
                     q, k, v, **kw))
@@ -108,9 +114,11 @@ def main() -> int:
                 line["refused"] = str(e)[:300]
             if hasattr(fa, "metrics"):
                 reg = fa.metrics.default_registry()
+                kind = "pairs" if args.window is None else "window_pairs"
                 line["pairs"] = {n: reg.gauge(
-                    f"flash_attention_pairs_{n}").value
-                    for n in ("walked", "dense", "edge")}
+                    f"flash_attention_{kind}_{n}").value
+                    for n in ("walked", "dense", "edge")
+                    if args.window is None or n != "dense"}
             lines.append(line)
             print(json.dumps(line), flush=True)
     if args.out:
