@@ -107,7 +107,11 @@ the same loop, each buffer recomputed and carried back by the grouped
 product's own backward calls (what ``nn.remat`` recomputes of the
 forward loop is then dead code, so a step still runs a buffer forward
 twice). One body at one size is all the step holds of the grouped
-product, whose grid visits only the tiles of rows in use.
+product, whose grid visits only the tiles of rows in use. The rows move
+into the buffer and back onto the tokens through ``ops.expert_rows``'
+kernels, which stop at the buffer's held rows, where the hidden width is
+whole lane tiles and the buffer whole blocks of sixteen rows; through
+XLA's gather and scatter-add elsewhere (the shapes alone decide).
 
 Recomputation: each layer is wrapped in ``nn.remat``, so the backward
 pass holds one (batch, T, hidden) input a layer, (batch, T, streams *
@@ -357,8 +361,8 @@ def pair_buffer_rows(tokens, per_token, held, routed):
 
 def _buffers(order, sizes, rows):
     """How many buffers of ``rows`` pairs the held pairs fill, and
-    ``at(i)``: the i-th buffer's slice of the sorted order and its group
-    sizes (each held group's part of it, then the rest)."""
+    ``at(i)``: the i-th buffer's first position in the sorted order and
+    its group sizes (each held group's part of it, then the rest)."""
     here = sizes[:-1]
     ends = jnp.cumsum(here)
 
@@ -366,10 +370,15 @@ def _buffers(order, sizes, rows):
         lo = i * rows
         part = jnp.clip(jnp.minimum(ends, lo + rows)
                         - jnp.maximum(ends - here, lo), 0)
-        return (lax.dynamic_slice(order, (lo,), (rows,)),
-                jnp.append(part, rows - jnp.sum(part)))
+        return lo, jnp.append(part, rows - jnp.sum(part))
 
     return (ends[-1] + rows - 1) // rows, at
+
+
+def _sorted_pairs(order, weight, per_token):
+    """Each sorted position's token and routing weight, made once for
+    every buffer: a buffer's are then slices of them."""
+    return order // per_token, weight[order]
 
 
 def _relu2(pre):
@@ -411,22 +420,30 @@ ACTIVATIONS = {"relu2": Activation(_relu2, _relu2_pullback, 1),
                "swiglu": Activation(_swiglu, _swiglu_pullback, 2)}
 
 
-def _through_experts(tokens, w1, w2, order, sizes, per_token, activation):
-    """One buffer of sorted pairs through their experts: each pair's
-    token, its rows gathered, before and after the activation, and what
-    the second product gives. Rows past the held groups stay in the
-    buffer (its size is static) but no tile of theirs is multiplied, and
-    they come out zero."""
+def _pair_rows(tokens, rows):
+    """How a buffer's rows move (``ops.expert_rows.PairRows``): Pallas
+    kernels where the shapes tile, XLA's gather and scatter-add
+    elsewhere."""
     # Pallas is imported where a kernel is called: every tower of the
     # zoo is imported together, and the others' start-up does not pay
     # the two seconds it takes
+    from persia_tpu.ops.expert_rows import PairRows
+
+    return PairRows.of(*tokens.shape, rows)
+
+
+def _through_experts(moves, tokens, w1, w2, token_of, sizes, activation):
+    """One buffer of sorted pairs through their experts: its tokens'
+    rows gathered, before and after the activation, and what the second
+    product gives. Rows past the held groups stay in the
+    buffer (its size is static) but no tile of theirs is multiplied, and
+    they come out zero."""
     from persia_tpu.ops.grouped_matmul import grouped_matmul
 
-    token_of = order // per_token
-    rows = jnp.take(tokens, token_of, axis=0)
+    rows = moves.take(tokens, token_of, sizes)
     pre = grouped_matmul(rows, w1, sizes)
     mid = ACTIVATIONS[activation].forward(pre)
-    return token_of, rows, pre, mid, grouped_matmul(mid, w2, sizes)
+    return rows, pre, mid, grouped_matmul(mid, w2, sizes)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6, 7, 8))
@@ -441,13 +458,16 @@ def dispatch_pairs(tokens, w1, w2, weight, order, sizes, rows, per_token,
     derivative is written out below. ``activation`` names an entry of
     ``ACTIVATIONS``."""
     count, at = _buffers(order, sizes, rows)
+    moves = _pair_rows(tokens, rows)
+    token_of, weight = _sorted_pairs(order, weight, per_token)
 
     def one(i, acc):
-        part, part_sizes = at(i)
-        token_of, _, _, _, out = _through_experts(
-            tokens, w1, w2, part, part_sizes, per_token, activation)
-        return acc.at[token_of].add(
-            out.astype(F32) * weight[part][:, None])
+        lo, part_sizes = at(i)
+        part_of = lax.dynamic_slice(token_of, (lo,), (rows,))
+        *_, out = _through_experts(moves, tokens, w1, w2, part_of,
+                                   part_sizes, activation)
+        return moves.add(acc, out, lax.dynamic_slice(weight, (lo,), (rows,)),
+                         part_of, part_sizes)
 
     return lax.fori_loop(0, count, one, jnp.zeros(tokens.shape, F32))
 
@@ -463,32 +483,45 @@ def _dispatch_bwd(rows, per_token, activation, saved, ct):
     """The same loop: each buffer recomputed, the cotangent of its
     tokens' sums gathered and carried back through the weights, the
     second product, the activation and the first, by the grouped
-    product's own two backward calls; sums in float32."""
+    product's own two backward calls; sums in float32. A pair's weight
+    meets one buffer only: its gradient is written at the pair's sorted
+    position and put back in the pairs' order once, after the loop."""
     from persia_tpu.ops.grouped_matmul import grouped_matmul_pullback
 
     tokens, w1, w2, weight, order, sizes = saved
     count, at = _buffers(order, sizes, rows)
+    moves = _pair_rows(tokens, rows)
+    token_of, pair_weight = _sorted_pairs(order, weight, per_token)
 
     def one(i, sums):
-        to_tokens, to_w1, to_w2, to_weight = sums
-        part, part_sizes = at(i)
-        token_of, gathered, pre, mid, out = _through_experts(
-            tokens, w1, w2, part, part_sizes, per_token, activation)
-        g = jnp.take(ct, token_of, axis=0)
-        g_out = (g * weight[part][:, None]).astype(out.dtype)
+        to_tokens, to_w1, to_w2, by_position = sums
+        lo, part_sizes = at(i)
+        part_of = lax.dynamic_slice(token_of, (lo,), (rows,))
+        w = lax.dynamic_slice(pair_weight, (lo,), (rows,))
+        gathered, pre, mid, out = _through_experts(
+            moves, tokens, w1, w2, part_of, part_sizes, activation)
+        g = moves.take(ct, part_of, part_sizes)
+        g_out = (g * w[:, None]).astype(out.dtype)
         g_mid, g_w2 = grouped_matmul_pullback(mid, w2, part_sizes, g_out)
         g_rows, g_w1 = grouped_matmul_pullback(
             gathered, w1, part_sizes,
             ACTIVATIONS[activation].pullback(pre, g_mid))
-        return (to_tokens.at[token_of].add(g_rows.astype(F32)),
+        return (moves.add(to_tokens, g_rows, jnp.ones_like(w), part_of,
+                          part_sizes),
                 to_w1 + g_w1.astype(F32), to_w2 + g_w2.astype(F32),
-                to_weight.at[part].add(
-                    jnp.sum(g * out.astype(F32), axis=-1)))
+                lax.dynamic_update_slice(
+                    by_position, jnp.sum(g * out.astype(F32), axis=-1),
+                    (lo,)))
 
-    inputs = (tokens, w1, w2, weight)
-    sums = lax.fori_loop(0, count, one, tuple(
-        jnp.zeros(x.shape, F32) for x in inputs))
-    return (*(g.astype(x.dtype) for g, x in zip(sums, inputs)), None, None)
+    to_tokens, to_w1, to_w2, by_position = lax.fori_loop(
+        0, count, one, tuple(jnp.zeros(x.shape, F32)
+                             for x in (tokens, w1, w2, order)))
+    pairs = weight.shape[0]
+    to_weight = jnp.zeros(weight.shape, F32).at[order[:pairs]].set(
+        by_position[:pairs], unique_indices=True)
+    return (to_tokens.astype(tokens.dtype),
+            to_w1.astype(w1.dtype), to_w2.astype(w2.dtype),
+            to_weight.astype(weight.dtype), None, None)
 
 
 dispatch_pairs.defvjp(_dispatch_fwd, _dispatch_bwd)

@@ -201,9 +201,11 @@ def test_latent_attention_without_positions_is_plain_softmax():
 # holds the one-stream towers' digests; the glm cell's is the same
 # there.) A later change that alters these programs on purpose records
 # its own digests here.
+# Recorded anew with `dispatch_pairs`' loops (see
+# `tests/test_sparse_seq_tower.py`).
 LATENT = {
-    "glm-4.7-flash.device-histories8k": "453a82043b12ce2c",
-    "xing4.0-29b-a4b.device-histories8k": "34eb17747ebd05f8",
+    "glm-4.7-flash.device-histories8k": "7ac69d38556f125d",
+    "xing4.0-29b-a4b.device-histories8k": "bad54654dc92fa34",
 }
 
 

@@ -36,6 +36,11 @@ selected-attention cell's shape (1 x 32 x 8192 x 128) and the index
 scores' kernels at the cell's (16 x 64, a tile of 512 queries against
 8192 keys, bfloat16, the tile's position traced).
 
+The expert layers' pair-row kernels (``ops/expert_rows``: the token
+rows gathered into a pass's buffer in bfloat16, the cotangent's in
+float32, and the rows summed back onto the tokens) go through both
+guards alone at each sequence cell's tokens, hidden width and buffer.
+
 Neither replaces the compiled-and-compared check on the chip
 (``chip_smoke.py`` kernel phase, ``test_compiled_on_tpu``): values only
 come from a run.
@@ -370,6 +375,67 @@ def test_the_index_kernels_cross_lower_at_the_cell_s_shape(grad):
     assert text.count("tpu_custom_call") == 1
 
 
+# tokens, hidden width and pair-buffer rows of each sequence cell's
+# expert layers
+ROWS_SHAPES = {"nemotron": (8192, 2688, 8192), "glm": (8192, 2048, 8192),
+               "xing": (8192, 3584, 8192), "kimi": (8192, 2304, 8192),
+               "keye": (8192, 2048, 16384), "mellum": (8192, 2304, 16384)}
+
+
+def _row_kernels(name, sharding=None):
+    """A pass's three movements of ``ops/expert_rows`` at a cell's
+    shape, compiled (not interpreted), and their abstract operands."""
+    from persia_tpu.ops import expert_rows
+
+    tokens, hidden, rows = ROWS_SHAPES[name]
+
+    def passes(source, token_of, count, acc, out, weight):
+        return (expert_rows.take_rows(source, token_of, count,
+                                      interpret=False),
+                expert_rows.take_rows(acc, token_of, count,
+                                      interpret=False),
+                expert_rows.add_rows(acc, out, weight, token_of, count,
+                                     interpret=False))
+
+    avals = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+             for shape, dtype in (((tokens, hidden), jnp.bfloat16),
+                                  ((rows,), jnp.int32), ((1,), jnp.int32),
+                                  ((tokens, hidden), jnp.float32),
+                                  ((rows, hidden), jnp.bfloat16),
+                                  ((rows,), jnp.float32))]
+    return passes, avals
+
+
+@pytest.mark.parametrize("name", sorted(ROWS_SHAPES))
+def test_the_row_kernels_cross_lower_at_the_cells_shapes(name):
+    fn, avals = _row_kernels(name)
+    text = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *avals).mlir_module()
+    assert text.count("tpu_custom_call") == 3
+
+
+def _aot_compile_rows() -> int:
+    """Subprocess body: the pair-row kernels at every sequence cell's
+    shape, for a v5e, no chip."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2")
+    sharding = SingleDeviceSharding(topo.devices[0])
+    failed = 0
+    for name in sorted(ROWS_SHAPES):
+        try:
+            fn, avals = _row_kernels(name, sharding)
+            compiled = jax.jit(fn).lower(*avals).compile()
+            assert compiled.as_text().count("tpu_custom_call") == 3
+            print(f"COMPILED rows {name}")
+        except Exception as e:  # noqa: BLE001 — each reported
+            failed += 1
+            print(f"REFUSED rows {name}: {str(e)[:600]}")
+    return failed
+
+
 def _aot_compile_selected() -> int:
     """Subprocess body: the ``S`` layer's gradient, and the flash
     kernels under a selection and the index scores' kernels alone at the
@@ -523,6 +589,12 @@ def test_the_selected_attention_layer_aot_compiles_for_v5e():
         assert f"COMPILED selected {what}" in out, out[-4000:]
 
 
+def test_the_row_kernels_aot_compile_for_v5e():
+    out = _aot_subprocess("rows")
+    for name in sorted(ROWS_SHAPES):
+        assert f"COMPILED rows {name}" in out, out[-4000:]
+
+
 def test_the_conv_kernels_aot_compile_for_v5e():
     out = _aot_subprocess("conv")
     for name, what in itertools.product(sorted(CONV_SHAPES),
@@ -533,5 +605,6 @@ def test_the_conv_kernels_aot_compile_for_v5e():
 if __name__ == "__main__":
     sys.exit({("delta",): _aot_compile_delta,
               ("conv",): _aot_compile_conv,
+              ("rows",): _aot_compile_rows,
               ("selected",): _aot_compile_selected}.get(
                   tuple(sys.argv[1:]), _aot_compile_all)())
