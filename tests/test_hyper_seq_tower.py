@@ -438,11 +438,12 @@ def test_the_step_carries_its_scopes(built):
 # layer's old barrier; new `lse`'s slice and two reshapes moved to the
 # forward pass, the `reduce_precision` to bfloat16 that JAX puts on a kept
 # `out`, and the barrier with the two kept arrays among its operands.
-# Recorded anew with `dispatch_pairs`' loops (see
+# Recorded anew with `dispatch_pairs`' loops, and with the grouped
+# product's transposed call at its own tiling (see
 # `tests/test_sparse_seq_tower.py`).
 ONE_STREAM = {
-    "nemotron-3-nano-30b-a3b.device-histories8k": "a22bcf5d9522290e",
-    "glm-4.7-flash.device-histories8k": "7ac69d38556f125d",
+    "nemotron-3-nano-30b-a3b.device-histories8k": "5bf826c22680da1b",
+    "glm-4.7-flash.device-histories8k": "f1a1e3a8b0f46475",
 }
 
 

@@ -201,11 +201,12 @@ def test_latent_attention_without_positions_is_plain_softmax():
 # holds the one-stream towers' digests; the glm cell's is the same
 # there.) A later change that alters these programs on purpose records
 # its own digests here.
-# Recorded anew with `dispatch_pairs`' loops (see
+# Recorded anew with `dispatch_pairs`' loops, and with the grouped
+# product's transposed call at its own tiling (see
 # `tests/test_sparse_seq_tower.py`).
 LATENT = {
-    "glm-4.7-flash.device-histories8k": "7ac69d38556f125d",
-    "xing4.0-29b-a4b.device-histories8k": "bad54654dc92fa34",
+    "glm-4.7-flash.device-histories8k": "f1a1e3a8b0f46475",
+    "xing4.0-29b-a4b.device-histories8k": "da2ea466a746a8f4",
 }
 
 

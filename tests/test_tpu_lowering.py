@@ -41,6 +41,12 @@ rows gathered into a pass's buffer in bfloat16, the cotangent's in
 float32, and the rows summed back onto the tokens) go through both
 guards alone at each sequence cell's tokens, hidden width and buffer.
 
+So do the expert layers' grouped products (``ops/grouped_matmul``:
+megablox's product, its transposed product and its grouped outer
+product, for both matrices) at each sequence cell's buffer, widths and
+held experts, at the tiling each call is given there: a tiling whose
+blocks overflow the VMEM a call is scoped to is refused here.
+
 Neither replaces the compiled-and-compared check on the chip
 (``chip_smoke.py`` kernel phase, ``test_compiled_on_tpu``): values only
 come from a run.
@@ -64,6 +70,7 @@ from persia_tpu.ops.flash_attention import (  # noqa: E402
     _clamp_block,
     flash_attention_masked,
 )
+from test_grouped_matmul import CELLS as EXPERT_SHAPES  # noqa: E402
 
 # (T, dk, dv, dtype), key width and value width: the smoke's two lengths
 # at the full head width, the latent attention towers' history at a
@@ -436,6 +443,68 @@ def _aot_compile_rows() -> int:
     return failed
 
 
+def _grouped_calls(name, sharding=None):
+    """An expert layer's six megablox calls at a cell's shapes (both
+    products, and both pullbacks: the product with the matrix transposed
+    and the grouped outer product), and their abstract operands. The
+    caller makes ``ops.interpret()`` answer compile."""
+    from persia_tpu.ops.grouped_matmul import (
+        grouped_matmul,
+        grouped_matmul_pullback,
+    )
+
+    rows, hidden, width, wider, held = EXPERT_SHAPES[name]
+    first = wider * width
+
+    def layer(x, mid, w1, w2, sizes, g_pre, g_out):
+        return (grouped_matmul(x, w1, sizes), grouped_matmul(mid, w2, sizes),
+                *grouped_matmul_pullback(mid, w2, sizes, g_out),
+                *grouped_matmul_pullback(x, w1, sizes, g_pre))
+
+    avals = [jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+             for shape, dtype in (((rows, hidden), jnp.bfloat16),
+                                  ((rows, width), jnp.bfloat16),
+                                  ((held, hidden, first), jnp.bfloat16),
+                                  ((held, width, hidden), jnp.bfloat16),
+                                  ((held + 1,), jnp.int32),
+                                  ((rows, first), jnp.bfloat16),
+                                  ((rows, hidden), jnp.bfloat16))]
+    return layer, avals
+
+
+@pytest.mark.parametrize("name", sorted(EXPERT_SHAPES))
+def test_the_grouped_calls_cross_lower_at_the_cells_shapes(monkeypatch,
+                                                           name):
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, avals = _grouped_calls(name)
+    text = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *avals).mlir_module()
+    assert text.count("tpu_custom_call") == 6
+
+
+def _aot_compile_grouped() -> int:
+    """Subprocess body: the grouped products at every sequence cell's
+    shapes, for a v5e, no chip."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    topo = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2")
+    sharding = SingleDeviceSharding(topo.devices[0])
+    jax.default_backend = lambda: "tpu"     # the op: compile
+    failed = 0
+    for name in sorted(EXPERT_SHAPES):
+        try:
+            fn, avals = _grouped_calls(name, sharding)
+            compiled = jax.jit(fn).lower(*avals).compile()
+            assert compiled.as_text().count("tpu_custom_call") == 6
+            print(f"COMPILED grouped {name}")
+        except Exception as e:  # noqa: BLE001 — each reported
+            failed += 1
+            print(f"REFUSED grouped {name}: {str(e)[:600]}")
+    return failed
+
+
 def _aot_compile_selected() -> int:
     """Subprocess body: the ``S`` layer's gradient, and the flash
     kernels under a selection and the index scores' kernels alone at the
@@ -595,6 +664,12 @@ def test_the_row_kernels_aot_compile_for_v5e():
         assert f"COMPILED rows {name}" in out, out[-4000:]
 
 
+def test_the_grouped_calls_aot_compile_for_v5e():
+    out = _aot_subprocess("grouped")
+    for name in sorted(EXPERT_SHAPES):
+        assert f"COMPILED grouped {name}" in out, out[-4000:]
+
+
 def test_the_conv_kernels_aot_compile_for_v5e():
     out = _aot_subprocess("conv")
     for name, what in itertools.product(sorted(CONV_SHAPES),
@@ -606,5 +681,6 @@ if __name__ == "__main__":
     sys.exit({("delta",): _aot_compile_delta,
               ("conv",): _aot_compile_conv,
               ("rows",): _aot_compile_rows,
+              ("grouped",): _aot_compile_grouped,
               ("selected",): _aot_compile_selected}.get(
                   tuple(sys.argv[1:]), _aot_compile_all)())

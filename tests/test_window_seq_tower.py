@@ -455,9 +455,10 @@ def test_routed_rows_counts_the_eight_expert_layers(built):
 # one. (`tests/test_sparse_seq_tower.py` holds the four other sequence
 # towers' digests, which the same commit gives.) A later change that
 # alters this program on purpose records its own digest here.
-# Recorded anew with `dispatch_pairs`' loops (see
+# Recorded anew with `dispatch_pairs`' loops, and with the grouped
+# product's transposed call at its own tiling (see
 # `tests/test_sparse_seq_tower.py`).
-KEYE = {"keye-vl-2.0-30b-a3b.device-histories8k": "f60b3305a446aa37"}
+KEYE = {"keye-vl-2.0-30b-a3b.device-histories8k": "f6d98ad9614e829d"}
 
 
 @pytest.mark.parametrize("cell_name", sorted(KEYE))

@@ -38,7 +38,8 @@ def main() -> int:
     found = kernel_timing.chip(args, "expert_rows_times")
     if found is None:
         return 2
-    dev, hbm = found
+    dev, peaks = found
+    hbm = peaks["hbm_bytes_per_s"]
 
     import jax
     import jax.numpy as jnp

@@ -1,11 +1,15 @@
 """What a tool that times kernels alone on the chip needs besides its
 shapes: the checkout to import (``--tree``), the chip or a refusal, the
-HBM peak, a timed loop that ends in ``block_until_ready``, and the lines
-written to ``--out``. ``tools/expert_rows_times.py`` is built on it; the
-older ``*_times.py`` tools still carry their own copies.
+peaks, a timed loop that ends in ``block_until_ready``, and the lines
+written to ``--out``; and :func:`expert_layers`, the sequence cells'
+expert layers as the benchmark runs them. ``tools/expert_rows_times.py``
+and ``tools/gmm_times.py`` are built on it; the older ``*_times.py``
+tools still carry their own copies.
 """
 
 import argparse
+import glob
+import importlib
 import json
 import os
 import sys
@@ -27,7 +31,7 @@ def parser(doc, shapes):
 
 def chip(args, name):
     """Puts ``args.tree`` and its benchmark first on the import path and
-    returns ``(device, hbm bytes a second)``, or ``None`` with a line on
+    returns ``(device, its row of peaks.json)``, or ``None`` with a line on
     stderr where the first device is no TPU: a CPU time says nothing
     about the chip."""
     tree = os.path.abspath(args.tree)
@@ -43,7 +47,7 @@ def chip(args, name):
         return None
     peaks = costs.peaks_for(os.path.join(tree, "benchmarks", "chip"),
                             dev.device_kind)
-    return dev, peaks["hbm_bytes_per_s"]
+    return dev, peaks
 
 
 def timed(fn, *xs, iters):
@@ -67,3 +71,36 @@ def emit(lines, out=None):
         os.makedirs(os.path.dirname(os.path.abspath(out)), exist_ok=True)
         with open(out, "a") as f:
             f.writelines(json.dumps(line) + "\n" for line in lines)
+
+
+def expert_layers(tree=REPO):
+    """{configuration: (tokens, pair-buffer rows, hidden width, expert
+    width, activation, experts a token, routed experts, held experts)}
+    of the expert layers of each cell the benchmark under ``tree`` runs on
+    a sequence placement, read from the cell, its configuration and the
+    tower its placement builds."""
+    from persia_tpu.models.hybrid_seq import pair_buffer_rows
+
+    chip_dir = os.path.join(tree, "benchmarks", "chip")
+    if chip_dir not in sys.path:
+        sys.path.insert(0, chip_dir)
+    layers = {}
+    for path in sorted(glob.glob(os.path.join(chip_dir, "cells", "*.json"))):
+        with open(path) as f:
+            cell = json.load(f)
+        if not cell["placement"].startswith("device_seq"):
+            continue
+        with open(os.path.join(chip_dir, "configs",
+                               cell["config"] + ".json")) as f:
+            config = json.load(f)
+        placement = importlib.import_module(
+            "placements." + cell["placement"])
+        sz = placement.weights.sizes_of(config)
+        tokens, held = cell["sizes"]["batch"], len(sz["experts_held"])
+        per_token, routed = sz["experts_per_token"], sz["experts_routed"]
+        layers[cell["config"]] = (
+            tokens, pair_buffer_rows(tokens, per_token, held, routed),
+            sz["hidden"], sz["expert_width"],
+            placement.build_tower(sz).expert_activation, per_token, routed,
+            held)
+    return layers
